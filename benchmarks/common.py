@@ -1,5 +1,6 @@
-"""Shared benchmark utilities. All timings are CPU wall-clock (relative
-claims only; TPU projections come from the roofline model — DESIGN.md §9).
+"""Shared benchmark utilities. All timings are host wall-clock on the
+backend JAX runs on; CPU timings are relative claims only (DESIGN.md §9).
+Device numbers come only from chip runs (``chip_smoke.py``, PERF.md).
 
 Every suite's ``emit()`` rows are also accumulated into a per-suite
 record (``begin_suite``/``end_suite``, driven by ``benchmarks.run``);

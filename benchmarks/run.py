@@ -1,8 +1,10 @@
 """Benchmark runner: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV lines. CPU wall-clock timings are
-relative claims only (DESIGN.md §9); the TPU performance story lives in
-EXPERIMENTS.md §Roofline/§Perf (from the compiled dry-run).
+Prints ``name,us_per_call,derived`` CSV lines. Timings are wall-clock on
+whatever backend JAX runs on; on the CPU they are relative claims only
+(DESIGN.md §9). Device numbers come only from runs on the chip: the
+engine's first one is ``chip_smoke.py`` at the repository root, and
+PERF.md records what was measured there.
 
 Usage:
     python -m benchmarks.run [--help] [--emit-json] [--small] [filter]

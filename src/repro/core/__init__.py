@@ -7,11 +7,7 @@ from repro.core.edge_store import (
     stack_batches,
     store_from_arrays,
 )
-from repro.core.temporal_index import (
-    TemporalIndex,
-    build_index,
-    build_index_donated,
-)
+from repro.core.temporal_index import TemporalIndex, build_index
 from repro.core.walk_engine import (
     LaneParams,
     WalkBuffers,
@@ -31,8 +27,8 @@ from repro.core.window import (
 
 __all__ = [
     "EdgeBatch", "EdgeStore", "empty_store", "make_batch", "stack_batches",
-    "store_from_arrays", "TemporalIndex", "build_index",
-    "build_index_donated", "LaneParams", "WalkBuffers", "WalkResult",
+    "store_from_arrays", "TemporalIndex", "build_index", "LaneParams",
+    "WalkBuffers", "WalkResult",
     "alloc_walk_buffers", "generate_walk_lanes", "generate_walks",
     "generate_walks_donated", "WindowState", "ingest", "ingest_nodonate",
     "ingest_sort", "init_window",

@@ -42,7 +42,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import SamplerConfig
@@ -311,7 +311,7 @@ def make_distributed_walker(mesh: Mesh, axis: str, index_stacked,
 
     fn = shard_map(walker, mesh=mesh,
                    in_specs=(pspec_idx, pspec_state),
-                   out_specs=pspec_state, check_rep=False)
+                   out_specs=pspec_state, check_vma=False)
 
     def run(state: ShardedWalkState) -> ShardedWalkState:
         return jax.jit(fn)(index_stacked, state)

@@ -81,10 +81,16 @@ def index_exponential(u: jax.Array, n: jax.Array) -> jax.Array:
     For n above the float32 overflow threshold, e^n−1 → e^n and
     log(u·e^n + 1) → n + log(u) (since u·e^n ≫ 1 for any representable u>0),
     recovering the paper's eq. (3) asymptotic ⌊n + ln u − 1⌋ up to rounding.
+
+    e^n − 1 is written ``exp(n) - 1`` rather than ``expm1``: n is an integer
+    count, so the cancellation ``expm1`` guards against never occurs
+    (n = 0 gives exactly 0), and the Pallas TPU lowering has no ``expm1``.
+    The fused kernel evaluates this same expression, which keeps its walks
+    byte-identical to the jnp paths.
     """
     nf = n.astype(jnp.float32)
     u = jnp.clip(u, 1e-30, 1.0)
-    exact = jnp.ceil(jnp.log(u * jnp.expm1(nf) + 1.0)) - 1.0
+    exact = jnp.ceil(jnp.log(u * (jnp.exp(nf) - 1.0) + 1.0)) - 1.0
     asymptotic = jnp.ceil(nf + jnp.log(u)) - 1.0
     i = jnp.where(nf <= _EXP_EXACT_MAX_N, exact, asymptotic).astype(jnp.int32)
     return jnp.clip(i, 0, jnp.maximum(n - 1, 0))

@@ -20,7 +20,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import SchedulerConfig
 from repro.core.temporal_index import TemporalIndex
@@ -101,103 +100,54 @@ def dispatch_stats(index: TemporalIndex, cur_node: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# O(W) bucketed per-hop regrouping (DESIGN.md §10)
+# Per-hop regrouping by node (DESIGN.md §10)
 # ---------------------------------------------------------------------------
 
-_RADIX_BITS = 4           # bucket bits per counting pass
-_RADIX = 1 << _RADIX_BITS
 _TIME_SUBSORT_BITS = 16   # quantized relative-time subsort resolution
-
-
-def _counting_pass(digit: jax.Array) -> jax.Array:
-    """One stable counting-sort pass over ``_RADIX``-valued keys.
-
-    Returns the permutation ``perm`` (output position -> input lane) that
-    groups lanes by ``digit`` while preserving input order inside each
-    bucket. Segment offsets come from the bucket counts (a segment-sum in
-    one-hot form) + an exclusive cumsum; the within-bucket rank is the
-    running occurrence count — a dense [W, _RADIX] compare + cumsum, which
-    is the same VPU-friendly shape as the tiled kernel's cutoff trick
-    (DESIGN.md §9) and costs O(W) for the fixed radix, vs the O(W log W)
-    lexsort it replaces. The narrow radix keeps the one-hot panel cheap;
-    more (but much smaller) passes win on both VPU and CPU.
-    """
-    W = digit.shape[0]
-    buckets = jnp.arange(_RADIX, dtype=jnp.int32)
-    onehot = (digit[:, None] == buckets[None, :]).astype(jnp.int32)
-    running = jnp.cumsum(onehot, axis=0)                 # inclusive per bucket
-    rank = jnp.take_along_axis(running, digit[:, None], axis=1)[:, 0] - 1
-    counts = running[-1]
-    starts = jnp.cumsum(counts) - counts                 # exclusive offsets
-    pos = starts[digit] + rank
-    return jnp.zeros((W,), jnp.int32).at[pos].set(
-        jnp.arange(W, dtype=jnp.int32))
-
-
-def _radix_passes(perm: jax.Array, key: jax.Array, num_bits: int):
-    """Compose LSD counting passes until ``num_bits`` of ``key`` are sorted."""
-    k = key[perm]
-    for shift in range(0, num_bits, _RADIX_BITS):
-        pp = _counting_pass((k >> shift) & (_RADIX - 1))
-        perm = perm[pp]
-        k = k[pp]
-    return perm
 
 
 def bucket_regroup(node_key: jax.Array, time_key: jax.Array,
                    node_capacity: int, *, time_subsort: bool = True
                    ) -> jax.Array:
-    """O(W) replacement for the per-hop ``jnp.lexsort`` (DESIGN.md §10).
+    """Per-hop regroup of the walk lanes (DESIGN.md §10).
 
     Returns a permutation (output position -> input lane) grouping lanes by
-    ``node_key`` (exact LSD counting sort over the node-id digits; dead
-    lanes keyed ``node_capacity + 1`` land in the trailing bucket). When
-    ``time_subsort`` is set, lanes are first ordered by a span-scaled
-    16-bit quantized relative time (equal times always share a key, so
-    grouping coarsens with the window span instead of saturating away)
-    so equal-(node, time) lanes coalesce into single segments
-    — but only when some occupied node actually carries mixed times; the
-    check is a segment min/max and the passes sit behind a ``lax.cond``, so
-    the common near-sorted steady state pays nothing. The permutation is
-    purely an execution layout: any grouping is correct (segment heads are
-    re-derived from the materialized order), so the quantization never
-    affects emitted walks.
+    ``node_key`` (a stable sort; dead lanes keyed ``node_capacity + 1``
+    land in the trailing bucket). When ``time_subsort`` is set and some
+    occupied node carries lanes of different times, lanes of one node are
+    further ordered by a span-scaled 16-bit quantized relative time (equal
+    times always share a key, so grouping coarsens with the window span
+    instead of saturating away), so equal-(node, time) lanes coalesce into
+    single segments; the second sort sits behind a ``lax.cond``, so the
+    common near-sorted steady state pays one sort. Each is one stable XLA
+    sort with the lane ids as payload: on a TPU that is far cheaper than
+    radix passes, whose scatters and gathers are single-element accesses.
+    The permutation is purely an execution layout: any grouping is correct
+    (segment heads are re-derived from the materialized order), so the
+    quantization never affects emitted walks.
     """
-    W = node_key.shape[0]
-    perm = jnp.arange(W, dtype=jnp.int32)
+    lanes = jnp.arange(node_key.shape[0], dtype=jnp.int32)
+    by_node, perm = jax.lax.sort((node_key, lanes), num_keys=1,
+                                 is_stable=True)
+    if not time_subsort:
+        return perm
 
-    if time_subsort:
-        nseg = node_capacity + 2
-        seg = jnp.clip(node_key, 0, nseg - 1)
-        occupied = seg <= node_capacity - 1
-        big = jnp.int32(np.iinfo(np.int32).max)
-        tmin = jax.ops.segment_min(jnp.where(occupied, time_key, big), seg,
-                                   num_segments=nseg)
-        tmax = jax.ops.segment_max(jnp.where(occupied, time_key, -big), seg,
-                                   num_segments=nseg)
-        mixed = jnp.any(tmin[:node_capacity] < tmax[:node_capacity])
+    t = time_key[perm]
+    mixed = jnp.any((by_node[1:] == by_node[:-1])
+                    & (by_node[1:] <= node_capacity - 1) & (t[1:] != t[:-1]))
 
-        def with_time(p):
-            # span-scaled 16-bit quantization: shift the relative time so
-            # the whole observed span fits the subsort bits — a hard clip
-            # would saturate (and stop grouping anything) once the window
-            # spans more than 2^16 ticks. The shift is monotone and maps
-            # equal times to equal keys, so grouping only coarsens.
-            tlo = jnp.min(time_key)
-            span = jnp.maximum(jnp.max(time_key) - tlo, 1)
-            shift = jnp.maximum(
-                jnp.floor(jnp.log2(span.astype(jnp.float32))).astype(
-                    jnp.int32) - (_TIME_SUBSORT_BITS - 1), 0)
-            rel = jnp.clip((time_key - tlo) >> shift, 0,
-                           (1 << _TIME_SUBSORT_BITS) - 1).astype(jnp.int32)
-            return _radix_passes(p, rel, _TIME_SUBSORT_BITS)
+    def with_time(_):
+        tlo = jnp.min(time_key)
+        span = jnp.maximum(jnp.max(time_key) - tlo, 1)
+        shift = jnp.maximum(
+            jnp.floor(jnp.log2(span.astype(jnp.float32))).astype(
+                jnp.int32) - (_TIME_SUBSORT_BITS - 1), 0)
+        rel = jnp.clip((time_key - tlo) >> shift, 0,
+                       (1 << _TIME_SUBSORT_BITS) - 1).astype(jnp.int32)
+        return jax.lax.sort((node_key, rel, lanes), num_keys=2,
+                            is_stable=True)[2]
 
-        perm = jax.lax.cond(mixed, with_time, lambda p: p, perm)
-
-    node_bits = max(_RADIX_BITS,
-                    int(np.ceil(np.log2(node_capacity + 2) / _RADIX_BITS))
-                    * _RADIX_BITS)
-    return _radix_passes(perm, node_key, node_bits)
+    return jax.lax.cond(mixed, with_time, lambda _: perm, None)
 
 
 class TaskTable(NamedTuple):

@@ -39,15 +39,13 @@ hop suffix [c, b):
 """
 from __future__ import annotations
 
-import math
-
 from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.edge_store import TS_PAD, EdgeStore
+from repro.core.edge_store import EdgeStore
 
 
 class TemporalIndex(NamedTuple):
@@ -85,55 +83,70 @@ class TemporalIndex(NamedTuple):
         return self.ns_order.shape[0]
 
 
+def _spread(node_vals: jax.Array, lo: jax.Array, nonempty: jax.Array,
+            E: int) -> jax.Array:
+    """int32[E]: each nonempty node's value over its region [lo, hi) of the
+    node-ts view (other positions: unspecified). Regions are contiguous and
+    in node order, so the value is a running sum of per-region steps placed
+    at the region starts — one node-sized scatter and an edge-sized cumsum
+    instead of an edge-sized gather (int32 wraparound keeps it exact)."""
+    V = node_vals.shape[0]
+    last = jax.lax.cummax(jnp.where(nonempty, jnp.arange(V, dtype=jnp.int32),
+                                    -1))
+    prev_node = jnp.concatenate([jnp.full((1,), -1, jnp.int32), last[:-1]])
+    prev = jnp.where(prev_node >= 0, node_vals[jnp.maximum(prev_node, 0)], 0)
+    steps = jnp.where(nonempty, node_vals - prev, 0)
+    return jnp.cumsum(jnp.zeros((E,), jnp.int32).at[lo].add(steps))
+
+
 def _build_index_impl(store: EdgeStore, node_capacity: int,
                       bias_scale: float = 1.0) -> TemporalIndex:
     """Bulk dual-index reconstruction (paper §2.6: two sorts + linear passes)."""
     E = store.capacity
     n_valid = store.num_edges
-    valid = jnp.arange(E, dtype=jnp.int32) < n_valid
+    iota = jnp.arange(E, dtype=jnp.int32)
+    valid = iota < n_valid
 
     # ---- sort 1: (src, ts) — the node-and-timestamp-grouped view --------
+    # The store is ts-sorted, so a stable sort by src alone yields the
+    # stable (src, ts) order. The view's columns ride the sort as payloads
+    # instead of being gathered through the permutation: on a TPU a sort
+    # moves them far faster than edge-capacity random gathers would.
     # Padding edges have src == node_capacity, ts == TS_PAD -> sort last.
-    ns_order = jnp.lexsort((store.ts, store.src)).astype(jnp.int32)
-    ns_src = store.src[ns_order]
-    ns_dst = store.dst[ns_order]
-    ns_ts = store.ts[ns_order]
+    ns_src, ns_dst, ns_ts, ns_order = jax.lax.sort(
+        (store.src, store.dst, store.ts, iota), num_keys=1, is_stable=True)
 
     # node regions: node_starts[v] = first position with ns_src >= v.
     # one extra bucket (node_capacity) holds the padding edges.
-    node_starts = jnp.searchsorted(
-        ns_src, jnp.arange(node_capacity + 2, dtype=jnp.int32), side="left"
-    ).astype(jnp.int32)
+    nodes = jnp.arange(node_capacity + 2, dtype=jnp.int32)
+    node_starts = ranged_search(ns_src, jnp.zeros_like(nodes),
+                                jnp.full_like(nodes, E), nodes, strict=False)
+    lo = node_starts[:node_capacity]
+    hi = node_starts[1:node_capacity + 1]
 
     # G axis: distinct timestamps per node region. A timestamp group starts
-    # wherever either the src or the ts changes in the (src, ts)-sorted order.
+    # wherever either the src or the ts changes in the (src, ts)-sorted
+    # order; a region's count is a difference of the running group count.
     prev_src = jnp.concatenate([jnp.full((1,), -1, jnp.int32), ns_src[:-1]])
     prev_ts = jnp.concatenate([jnp.full((1,), -1, jnp.int32), ns_ts[:-1]])
     group_start = (ns_src != prev_src) | (ns_ts != prev_ts)
-    node_group_counts = jax.ops.segment_sum(
-        (group_start & (ns_src < node_capacity)).astype(jnp.int32),
-        jnp.clip(ns_src, 0, node_capacity - 1),
-        num_segments=node_capacity,
-    ).astype(jnp.int32)
+    groups = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(
+        (group_start & (ns_src < node_capacity)).astype(jnp.int32))])
+    node_group_counts = groups[hi] - groups[lo]
 
-    # per-node ts extrema (references for stable weights)
-    big = jnp.int32(TS_PAD)
-    ns_ts_masked_min = jnp.where(ns_src < node_capacity, ns_ts, big)
-    ns_ts_masked_max = jnp.where(ns_src < node_capacity, ns_ts, -big)
-    node_tbase = jax.ops.segment_min(
-        ns_ts_masked_min, jnp.clip(ns_src, 0, node_capacity - 1),
-        num_segments=node_capacity).astype(jnp.int32)
-    node_tref = jax.ops.segment_max(
-        ns_ts_masked_max, jnp.clip(ns_src, 0, node_capacity - 1),
-        num_segments=node_capacity).astype(jnp.int32)
-    node_tbase = jnp.where(node_tbase == big, 0, node_tbase)
-    node_tref = jnp.where(node_tref == -big, 0, node_tref)
+    # per-node ts extrema (references for stable weights): a region is
+    # ts-sorted, so they are its first and last timestamps; 0 when empty
+    nonempty = hi > lo
+    node_tbase = jnp.where(nonempty, ns_ts[jnp.clip(lo, 0, E - 1)], 0)
+    node_tref = jnp.where(nonempty, ns_ts[jnp.clip(hi - 1, 0, E - 1)], 0)
 
     # ---- weight prefix arrays (linear passes) ----------------------------
     in_range = ns_src < node_capacity
-    dt_exp = (ns_ts - node_tref[jnp.clip(ns_src, 0, node_capacity - 1)]).astype(jnp.float32)
+    dt_exp = (ns_ts - _spread(node_tref, lo, nonempty, E)).astype(
+        jnp.float32)
     w_exp = jnp.where(in_range, jnp.exp(bias_scale * dt_exp), 0.0)
-    elem_lin = (ns_ts - node_tbase[jnp.clip(ns_src, 0, node_capacity - 1)] + 1).astype(jnp.float32)
+    elem_lin = (ns_ts - _spread(node_tbase, lo, nonempty, E) + 1).astype(
+        jnp.float32)
     w_lin = jnp.where(in_range, elem_lin, 0.0)
     zero = jnp.zeros((1,), jnp.float32)
     pexp = jnp.concatenate([zero, jnp.cumsum(w_exp)])
@@ -148,8 +161,13 @@ def _build_index_impl(store: EdgeStore, node_capacity: int,
     plin_store = jnp.concatenate([zero, jnp.cumsum(w_lin_s)])
 
     # ---- sort 2: (src, dst, ts) — adjacency view -------------------------
-    adj_order = jnp.lexsort((store.ts, store.dst, store.src)).astype(jnp.int32)
-    adj_dst = store.dst[adj_order]
+    # A stable (src, dst) sort of the ns view: equal (src, dst) runs keep the
+    # ns view's (ts, store position) order, so this is the stable
+    # (src, dst, ts) order of the store. Two keys, not three: a three-key
+    # sort costs the TPU compiler ~1 min more per program that rebuilds
+    # the index.
+    _, adj_dst, adj_order = jax.lax.sort((ns_src, ns_dst, ns_order),
+                                         num_keys=2, is_stable=True)
 
     return TemporalIndex(
         store=store,
@@ -162,49 +180,90 @@ def _build_index_impl(store: EdgeStore, node_capacity: int,
     )
 
 
-# ``build_index`` leaves the caller's store valid (tests and static pipelines
-# read the raw store after indexing). ``build_index_donated`` donates the
-# store buffers for standalone rebuild-in-place callers (init_window; any
-# re-index of a store the caller is done with). Inside the already-jitted
-# window advance the inner jit's donation annotation is inert — there, buffer
-# reuse comes from ``ingest``'s own donate_argnums (DESIGN.md §4).
 build_index = partial(jax.jit, static_argnames=("node_capacity",
                                                 "bias_scale"))(
     _build_index_impl)
-build_index_donated = partial(jax.jit,
-                              static_argnames=("node_capacity", "bias_scale"),
-                              donate_argnums=(0,))(_build_index_impl)
+
+
+def empty_index(edge_capacity: int, node_capacity: int) -> TemporalIndex:
+    """``build_index`` of an empty store, written out: every edge slot is
+    padding, so each view is the identity order and every prefix is 0. No
+    sort is compiled (at a 2^27-edge capacity they take a v5e's compiler
+    about a minute)."""
+    from repro.core.edge_store import empty_store
+
+    E, nc = edge_capacity, node_capacity
+    iota = jnp.arange(E, dtype=jnp.int32)
+    store = empty_store(E, nc)
+    zeros_v = jnp.zeros((nc,), jnp.int32)
+    zeros_p = jnp.zeros((E + 1,), jnp.float32)
+    return TemporalIndex(
+        store=store, ns_order=iota, ns_src=store.src, ns_dst=store.dst,
+        ns_ts=store.ts,
+        node_starts=jnp.zeros((nc + 2,), jnp.int32).at[nc + 1].set(E),
+        node_group_counts=zeros_v, pexp=zeros_p, plin=zeros_p,
+        node_tref=zeros_v, node_tbase=zeros_v, pexp_store=zeros_p,
+        plin_store=zeros_p, adj_order=iota, adj_dst=store.dst)
 
 
 # ---------------------------------------------------------------------------
-# Ranged binary searches (branch-free, fixed trip count — TPU friendly)
+# Ranged searches (branch-free, fixed trip count)
 # ---------------------------------------------------------------------------
+
+_ROW = 128        # elements per row of the blocked search (one vreg row)
 
 
 def ranged_search(arr: jax.Array, lo: jax.Array, hi: jax.Array,
                   target: jax.Array, *, strict: bool) -> jax.Array:
     """First index k in [lo, hi) with arr[k] > target (strict) or >= target.
 
-    Vectorized over lo/hi/target (same shape); ``arr`` is 1-D. Returns hi if
-    no such k. Fixed ceil(log2(len(arr)))+1 iterations.
+    Vectorized over lo/hi/target (same shape); ``arr`` is 1-D and sorted
+    within every queried range. Returns hi if no such k (lo if lo >= hi).
+
+    A blocked search. Level j views every 128^j-th element of ``arr`` as
+    rows of 128; the top level is one row. Each level counts, in one row,
+    the elements of the current bracket that lie below the target, which
+    narrows the bracket to the stretch between two consecutive elements
+    of the next level down — a stretch that one row of that level holds.
+    A query thus reads ceil(log_128 len(arr)) rows, where a binary search
+    makes log2(len(arr)) dependent single-element reads — the access a
+    TPU serves most slowly. The answer is exactly the binary search's.
     """
-    n = arr.shape[0]
-    steps = max(1, math.ceil(math.log2(max(n, 2))) + 1)
+    R = _ROW
     lo = lo.astype(jnp.int32)
     hi = hi.astype(jnp.int32)
+    t = target[..., None]
 
-    def body(_, state):
-        lo_, hi_ = state
-        mid = (lo_ + hi_) >> 1
-        v = arr[jnp.clip(mid, 0, n - 1)]
-        pred = (v > target) if strict else (v >= target)
-        open_ = lo_ < hi_
-        hi2 = jnp.where(pred, mid, hi_)
-        lo2 = jnp.where(pred, lo_, mid + 1)
-        return (jnp.where(open_, lo2, lo_), jnp.where(open_, hi2, hi_))
+    def below(v):
+        return (v <= t) if strict else (v < t)
 
-    lo_f, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    return lo_f
+    levels = []                           # (stride, entries, rows)
+    level, stride = arr, 1
+    while True:
+        n = level.shape[0]
+        pad = jnp.zeros((-n % R,), arr.dtype)
+        levels.append((stride, n, jnp.concatenate([level, pad]).reshape(
+            -1, R)))
+        if n <= R:
+            break
+        level, stride = level[::R], stride * R
+
+    lane = jnp.arange(R, dtype=jnp.int32)
+    L, U = lo, hi                         # answer in [L, U]
+    for stride, n, rows in reversed(levels):
+        # [L, U) lies in one row of this level (all of it at the top)
+        r = jnp.clip(L // (stride * R), 0, rows.shape[0] - 1)[..., None]
+        entry = r * R + lane
+        pos = entry * stride              # wraps only where entry >= n
+        inside = (entry < n) & (pos >= L[..., None]) & (pos < U[..., None])
+        m = jnp.sum((inside & below(rows[r[..., 0]])).astype(jnp.int32),
+                    axis=-1)
+        if stride == 1:
+            return L + m
+        first = (L + stride - 1) // stride * stride
+        none = first >= U
+        L, U = (jnp.where(none | (m == 0), L, first + (m - 1) * stride),
+                jnp.where(none, U, jnp.minimum(first + m * stride, U)))
 
 
 def node_range(index: TemporalIndex, node: jax.Array):

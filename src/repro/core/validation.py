@@ -58,7 +58,10 @@ def validate_walks(index: TemporalIndex, result: WalkResult) -> ValidityReport:
     t = times[:, 1:]
     is_hop = (pos[None, :] + 1) < lengths[:, None]
 
-    exists = _edge_exists(index, u, v, t)
+    # in walk-row batches of ~2^20 hops: each search reads a 128-wide row
+    # per query, so one batch of every hop could need gigabytes
+    exists = jax.lax.map(lambda row: _edge_exists(index, *row), (u, v, t),
+                         batch_size=max(1, (1 << 20) // max(Lp1 - 1, 1)))
     # strictly increasing except the first hop in edges-start mode, where
     # position 0 records the start edge's own timestamp on both endpoints.
     increasing = (t > t_prev) | (pos[None, :] == 0) & (t == t_prev)

@@ -27,11 +27,12 @@ Execution paths (the TPU mapping of the paper's dispatch plane):
   ``LaneParams`` batches (unlike ``tiled``, which compiles one bias).
 
 The per-hop regrouping itself comes in two flavors
-(``SchedulerConfig.regroup``, DESIGN.md §10): ``bucket`` (default) is an
-O(W) counting regroup (core/scheduler.py::bucket_regroup) whose permutation
-is **carried across hops** in the walk state — lanes stay in grouped order
-and only the lane→walk map is tracked, so neither a fresh O(W log W) sort
-nor a scatter-built inverse permutation is paid per hop. ``lexsort`` keeps
+(``SchedulerConfig.regroup``, DESIGN.md §10): ``bucket`` (default) is a
+stable node sort of the current lane layout
+(core/scheduler.py::bucket_regroup) whose permutation is **carried across
+hops** in the walk state — lanes stay in grouped order and only the
+lane→walk map is tracked, so no scatter-built inverse permutation is paid
+per hop. ``lexsort`` keeps
 the seed's per-hop ``jnp.lexsort`` + inverse scatter as the
 equivalence/benchmark reference.
 
@@ -89,6 +90,7 @@ from repro.core.samplers import (
 from repro.core.temporal_index import (
     TemporalIndex,
     node_range,
+    ranged_search,
     temporal_cutoff,
 )
 
@@ -407,7 +409,8 @@ def start_walks(index: TemporalIndex, wcfg: WalkConfig, scfg: SamplerConfig,
         u = jax.random.uniform(key, (W,))
         j = jnp.floor(u * num_active.astype(jnp.float32)).astype(jnp.int32)
         j = jnp.clip(j, 0, jnp.maximum(num_active - 1, 0))
-        cur = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
+        cur = ranged_search(cum, jnp.zeros_like(j), jnp.full_like(j, nc), j,
+                            strict=True)
         alive = jnp.broadcast_to(num_active > 0, (W,))
         cur_time = jnp.full((W,), 1, jnp.int32) * t_floor
     elif wcfg.start_mode == "edges":
@@ -572,22 +575,12 @@ def _hop_fullwalk(index, scfg, carry: _Carry, step: jax.Array,
 def _segment_cutoff(index: TemporalIndex, s_node, s_time):
     """(b, c) for lanes grouped by (node, time): Γ_t(v) = [c, b) per lane.
 
-    Segment heads are re-derived from the materialized order — contiguous
-    equal (node, time) runs share one cutoff — so *any* lane permutation is
-    correct; better grouping only improves dedup and gather locality.
+    Every lane computes its own cutoff (a vectorized search), so *any* lane
+    permutation is correct; lanes of one (node, time) segment compute the
+    same value, and grouping them only improves gather locality.
     """
-    W = s_node.shape[0]
-    p_node = jnp.concatenate([jnp.full((1,), -2, jnp.int32), s_node[:-1]])
-    p_time = jnp.concatenate([jnp.full((1,), -2, jnp.int32), s_time[:-1]])
-    head = (s_node != p_node) | (s_time != p_time)
-    seg_id = jnp.cumsum(head.astype(jnp.int32)) - 1
-
     a, b = node_range(index, s_node)
-    # cutoff computed once per segment head, broadcast to members.
-    c_head = temporal_cutoff(index, a, b, s_time)
-    c = jax.ops.segment_max(jnp.where(head, c_head, 0), seg_id,
-                            num_segments=W)[seg_id]
-    return b, c
+    return b, temporal_cutoff(index, a, b, s_time)
 
 
 def _bucket_prologue(index: TemporalIndex, sched_cfg, carry: _Carry):
@@ -731,8 +724,8 @@ def _hop_tiled_bucket(index, scfg, sched_cfg, carry: _Carry, step,
                       hop_key) -> _Carry:
     """Bucket-regrouped layout feeding the Pallas kernel (DESIGN.md §10).
 
-    The counting regroup yields an exact node sort (LSD passes over the
-    full node id), which is all the tile/task-table construction needs.
+    The regroup yields an exact node sort, which is all the tile/task-table
+    construction needs.
     """
     from repro.kernels import ops as kops
     lane, s_node, s_time, s_prev, s_alive = _bucket_prologue(
@@ -895,7 +888,7 @@ def _generate_walks_impl(index: TemporalIndex, key: jax.Array,
     pass_tables = tables if scfg.bias == "table" or lanes is not None \
         else None
 
-    def body(carry, step):
+    def hop(carry, step):
         hop_key = jax.random.fold_in(walk_key, step)
         write_pos = step + (1 if wcfg.start_mode == "edges" else 0)
         if lanes is not None:
@@ -954,9 +947,26 @@ def _generate_walks_impl(index: TemporalIndex, key: jax.Array,
             raise ValueError(f"unknown scheduler path {path!r}")
         return carry, st
 
-    carry, stats = jax.lax.scan(body, carry0,
-                                jnp.arange(hops, dtype=jnp.int32))
-    return WalkResult(nodes=carry.nodes, times=carry.times,
+    # Hops run while any lane is alive: a dead lane stays dead, so every
+    # later hop would only write NODE_PAD (and all-zero dispatch stats) —
+    # the fill below. Temporal walks die fast (each hop moves forward in
+    # time), so this skips most of the max_length hops.
+    def cond(state):
+        step, carry, _ = state
+        return (step < hops) & jnp.any(carry.alive)
+
+    def body(state):
+        step, carry, stats = state
+        carry, st = hop(carry, step)
+        return step + 1, carry, stats.at[step].set(st, mode="drop")
+
+    stats0 = jnp.zeros((hops, sched.NUM_STATS), jnp.float32)
+    step, carry, stats = jax.lax.while_loop(
+        cond, body, (jnp.asarray(0, jnp.int32), carry0, stats0))
+    first_unwritten = step + (2 if wcfg.start_mode == "edges" else 1)
+    unwritten = jnp.arange(L + 1, dtype=jnp.int32) >= first_unwritten
+    return WalkResult(nodes=jnp.where(unwritten, NODE_PAD, carry.nodes),
+                      times=jnp.where(unwritten, NODE_PAD, carry.times),
                       lengths=carry.lengths,
                       stats=stats if collect_stats else None)
 

@@ -2,23 +2,23 @@
 
 The active window W(t) = {e : t − Δ ≤ t_e ≤ t}. Each incoming batch:
 
-1. is sorted by timestamp (GPU radix sort in the paper; XLA sort here —
-   the batch is small, so this is the O(b log b) part),
+1. is ordered by timestamp (GPU radix sort in the paper),
 2. advances t to max(t, batch max ts),
 3. drops batch edges older than t − Δ ("too late", no retraction),
 4. evicts the store prefix older than t − Δ (prefix drop — the payoff of the
    timestamp-sorted shared store),
-5. merges the two **already-sorted runs** into the new store and
+5. merges the surviving store and the batch into the new store and
    bulk-rebuilds the dual index (paper: reconstruction over incremental
    mutation).
 
-Step (5) is merge-based (DESIGN.md §4): the surviving store suffix and the
-sorted batch are two sorted runs, so each element's output position is its
-own index plus a ``searchsorted`` rank into the *other* run — O(m·log b +
-b·log m) vectorized searches and one scatter, replacing the seed's global
-concat+argsort (O((m+b)·log(m+b))). The seed path is kept as
-``ingest_sort`` as the equivalence reference; both produce byte-identical
-``WindowState``s (tested in tests/test_streaming_merge.py).
+Steps (1)-(5) run as one stable sort of store ++ batch keyed by
+timestamp, with evicted, late and padding edges keyed last (DESIGN.md §4):
+store edges come first in the input, so equal timestamps keep the two-run
+merge order — surviving store edges first, then batch edges in arrival
+order. The seed path (sort the batch, shift out the evicted prefix, then a
+global argsort plus gathers) is kept as ``ingest_sort``, the equivalence
+reference; both produce byte-identical ``WindowState``s (tested in
+tests/test_streaming_merge.py).
 
 The public ``ingest`` donates the incoming ``WindowState`` (``jax.jit``
 ``donate_argnums``), so the window advances in place: XLA aliases the old
@@ -36,13 +36,13 @@ it per shard under ``shard_map`` against each shard's slice of the store,
 passing the globally agreed ``watermark`` so eviction stays causally
 consistent across shards.
 
-The pipeline is factored into store-level stages (``_prepare_runs`` →
-``_merge_runs`` → ``_clip_to_capacity``) so the same math can advance a
-**bare store without a dual index**: ``TsView`` / ``advance_view`` keep a
-replicated timestamp-view of the *global* window — just the (src, dst, ts)
-columns, byte-identical to the single-device store — which the sharded
-serving layer (DESIGN.md §13) uses as its start directory for global
-start-edge draws while the dual indexes stay node-partitioned.
+The advance is a store-level stage (``_advance_store``), so the same math
+can advance a **bare store without a dual index**: ``TsView`` /
+``advance_view`` keep a replicated timestamp-view of the *global* window —
+just the (src, dst, ts) columns, byte-identical to the single-device store
+— which the sharded serving layer (DESIGN.md §13) uses as its start
+directory for global start-edge draws while the dual indexes stay
+node-partitioned.
 """
 from __future__ import annotations
 
@@ -54,7 +54,7 @@ import jax.numpy as jnp
 
 from repro.core.alias import AliasTables, TableSpec, build_tables, update_tables
 from repro.core.edge_store import TS_PAD, EdgeBatch, EdgeStore
-from repro.core.temporal_index import TemporalIndex, build_index, build_index_donated
+from repro.core.temporal_index import TemporalIndex, build_index, empty_index
 
 
 class WindowState(NamedTuple):
@@ -70,12 +70,14 @@ class WindowState(NamedTuple):
     tables: Optional[AliasTables] = None
 
 
+@partial(jax.jit, static_argnames=("edge_capacity", "node_capacity",
+                                   "window", "bias_scale", "table"))
 def init_window(edge_capacity: int, node_capacity: int, window: int,
                 bias_scale: float = 1.0,
                 table: Optional[TableSpec] = None) -> WindowState:
-    from repro.core.edge_store import empty_store
-    store = empty_store(edge_capacity, node_capacity)
-    index = build_index_donated(store, node_capacity, bias_scale)
+    """An empty window (the index of an empty store is written out, so no
+    sort is compiled)."""
+    index = empty_index(edge_capacity, node_capacity)
     tables = build_tables(index, table) if table is not None else None
     # distinct scalar buffers: donation (ingest donate_argnums) rejects a
     # state whose fields alias one another
@@ -88,19 +90,33 @@ def init_window(edge_capacity: int, node_capacity: int, window: int,
 
 
 # ---------------------------------------------------------------------------
-# Shared pipeline stages (steps 1-4): batch sort, time advance, late drop,
-# prefix eviction. Both the merge and the reference sort path run these.
+# The window advance (steps 1-5 of the module docstring) on a bare store
 # ---------------------------------------------------------------------------
 
 
-def _prepare_runs(store: EdgeStore, t_prev, window, batch: EdgeBatch,
-                  node_capacity: int, watermark=None):
-    """Return the two ts-sorted runs to merge plus bookkeeping scalars.
+class _Advance(NamedTuple):
+    store: EdgeStore        # the advanced, capacity-clipped store
+    t_now: jax.Array
+    late: jax.Array         # batch edges dropped as older than t − Δ
+    overflow: jax.Array     # oldest merged edges clipped to fit capacity
+    evict_to: jax.Array     # the old store's prefix [0, evict_to) left
+    bkeep: jax.Array        # bool[B]: batch edges that entered the window
+    merged_src: jax.Array   # int32[E+B]: src of the merged run, pre-clip
 
-    Run S: the surviving store suffix, compacted to the front of length-E
-    arrays (TS_PAD / virtual-node padding beyond ``keep_n``).
-    Run B: the kept batch edges, ts-sorted and compacted to the front of
-    length-B arrays (TS_PAD padding beyond ``bn``).
+
+def _advance_store(store: EdgeStore, t_prev, window, batch: EdgeBatch,
+                   node_capacity: int, watermark=None) -> _Advance:
+    """Advance a ts-sorted store by one batch.
+
+    Steps (1)-(5) are one stable sort: store ++ batch, keyed by timestamp,
+    with evicted store edges, late batch edges and padding keyed TS_PAD so
+    they sort last. Store edges precede batch edges in the input, so ties
+    within a timestamp keep the two-run merge rule — store first, then the
+    batch in arrival order — and the first ``keep_n + bn`` positions are
+    exactly that merge. The clip to capacity keeps the newest E of them.
+    On a TPU this sort (src and dst ride as payloads) is far cheaper than
+    rank searches and edge-capacity scatters: both move data with random
+    single-element accesses, which the chip serves slowly.
 
     ``watermark`` (optional int32 scalar) is an externally agreed lower
     bound on the new ``t_now``. A node-partitioned window (DESIGN.md §12)
@@ -110,78 +126,58 @@ def _prepare_runs(store: EdgeStore, t_prev, window, batch: EdgeBatch,
     protocol that keeps sharded windows causally consistent.
 
     Store-level on purpose (no ``WindowState``): the replicated ts-view
-    advance (``advance_view``) runs the same stages with no dual index.
+    advance (``advance_view``) runs it with no dual index.
     """
     E = store.capacity
     B = batch.src.shape[0]
 
-    # (1) sort the batch by timestamp; mark invalid slots with TS_PAD
+    # (1)-(2) advance time to the newest valid batch edge
     bvalid = jnp.arange(B, dtype=jnp.int32) < batch.count
-    bts = jnp.where(bvalid, batch.ts, TS_PAD)
-    border = jnp.argsort(bts).astype(jnp.int32)
-    bsrc = batch.src[border]
-    bdst = batch.dst[border]
-    bts = bts[border]
-
-    # (2) advance time
-    last = jnp.where(batch.count > 0,
-                     bts[jnp.clip(batch.count - 1, 0, B - 1)], -TS_PAD)
-    t_now = jnp.maximum(t_prev, last)
+    t_now = jnp.maximum(t_prev, jnp.max(jnp.where(bvalid, batch.ts,
+                                                  -TS_PAD)))
     if watermark is not None:
         t_now = jnp.maximum(t_now, watermark)
     cutoff = t_now - window
 
-    # (3) late drops in the batch
-    blate = bvalid & (bts < cutoff)
-    bkeep = bvalid & ~blate
-    late = jnp.sum(blate.astype(jnp.int32))
-    # compact kept batch edges to the front (stable sort by drop flag)
-    bperm = jnp.argsort(jnp.where(bkeep, 0, 1), stable=True).astype(jnp.int32)
-    bsrc, bdst, bts = bsrc[bperm], bdst[bperm], bts[bperm]
-    bts = jnp.where(jnp.arange(B) < jnp.sum(bkeep), bts, TS_PAD)
+    # (3) late batch edges; (4) the store prefix older than the cutoff
+    bkeep = bvalid & (batch.ts >= cutoff)
+    late = jnp.sum((bvalid & ~bkeep).astype(jnp.int32))
+    iota = jnp.arange(E, dtype=jnp.int32)
+    skeep = (iota < store.num_edges) & (store.ts >= cutoff)
+    keep_n = jnp.sum(skeep.astype(jnp.int32))
     bn = jnp.sum(bkeep.astype(jnp.int32))
 
-    # (4) evict the store prefix older than the cutoff (prefix drop)
-    evict_to = jnp.searchsorted(store.ts, cutoff, side="left").astype(jnp.int32)
-    evict_to = jnp.minimum(evict_to, store.num_edges)
-    keep_n = store.num_edges - evict_to
-    idx = jnp.arange(E, dtype=jnp.int32) + evict_to
-    live = jnp.arange(E, dtype=jnp.int32) < keep_n
-    ssrc = jnp.where(live, store.src[jnp.clip(idx, 0, E - 1)], node_capacity)
-    sdst = jnp.where(live, store.dst[jnp.clip(idx, 0, E - 1)], 0)
-    sts = jnp.where(live, store.ts[jnp.clip(idx, 0, E - 1)], TS_PAD)
+    # (5) merge the survivors with the kept batch edges
+    mts, msrc, mdst = jax.lax.sort(
+        (jnp.concatenate([jnp.where(skeep, store.ts, TS_PAD),
+                          jnp.where(bkeep, batch.ts, TS_PAD)]),
+         jnp.concatenate([store.src, batch.src]),
+         jnp.concatenate([store.dst, batch.dst])),
+        num_keys=1, is_stable=True)
 
-    # evict_to rides along for the alias-table dirty rule: the sources of
-    # the evicted prefix store.src[:evict_to] lose edges this advance
-    return ((ssrc, sdst, sts, keep_n), (bsrc, bdst, bts, bn), t_now, late,
-            evict_to)
-
-
-def _clip_to_capacity(merged, keep_n, bn, E: int, node_capacity: int):
-    """Overflow-clip the merged run to an E-capacity ts-sorted store."""
-    msrc, mdst, mts = merged
-    EM = msrc.shape[0]
-
+    # on overflow keep the NEWEST E edges: shift the run left by `overflow`
     total = keep_n + bn
     overflow = jnp.maximum(total - E, 0)
-    # on overflow keep the NEWEST E edges: shift window right by `overflow`
-    idx2 = jnp.arange(E, dtype=jnp.int32) + overflow
     n_after = jnp.minimum(total, E)
-    live2 = jnp.arange(E, dtype=jnp.int32) < n_after
+    live = iota < n_after
+
+    def clip(x):
+        return jax.lax.dynamic_slice(x, (overflow,), (E,))
+
     new_store = EdgeStore(
-        src=jnp.where(live2, msrc[jnp.clip(idx2, 0, EM - 1)], node_capacity),
-        dst=jnp.where(live2, mdst[jnp.clip(idx2, 0, EM - 1)], 0),
-        ts=jnp.where(live2, mts[jnp.clip(idx2, 0, EM - 1)], TS_PAD),
-        num_edges=n_after.astype(jnp.int32),
-    )
-    return new_store, overflow
+        src=jnp.where(live, clip(msrc), node_capacity),
+        dst=jnp.where(live, clip(mdst), 0),
+        ts=jnp.where(live, clip(mts), TS_PAD),
+        num_edges=n_after.astype(jnp.int32))
+    return _Advance(store=new_store, t_now=t_now, late=late,
+                    overflow=overflow, evict_to=store.num_edges - keep_n,
+                    bkeep=bkeep, merged_src=msrc)
 
 
-def _finalize(state: WindowState, merged, keep_n, bn, t_now, late,
-              batch_count, node_capacity: int, bias_scale: float):
-    """Overflow-clip the merged run to capacity and rebuild the dual index."""
-    new_store, overflow = _clip_to_capacity(
-        merged, keep_n, bn, state.index.store.capacity, node_capacity)
+def _finalize(state: WindowState, new_store: EdgeStore, t_now, late,
+              overflow, batch_count, node_capacity: int,
+              bias_scale: float) -> WindowState:
+    """Rebuild the dual index over the advanced store; bump the counters."""
     index = build_index(new_store, node_capacity, bias_scale)
     return WindowState(
         index=index, t_now=t_now, window=state.window,
@@ -191,42 +187,12 @@ def _finalize(state: WindowState, merged, keep_n, bn, t_now, late,
     )
 
 
-# ---------------------------------------------------------------------------
-# Step 5, merge path (default): rank-based two-run merge, O(m+b) data
-# movement + O(m log b + b log m) vectorized binary searches. No global sort.
-# ---------------------------------------------------------------------------
-
-
-def _merge_runs(run_s, run_b):
-    """Stable two-run merge by rank: an element's output position is its own
-    run index plus the count of other-run elements that precede it. Ties
-    break store-first (side="left" for store elems, side="right" for batch
-    elems), exactly matching a stable argsort over [store ++ batch] — which
-    is what the reference path computes — so the two paths are bit-equal.
-    """
-    ssrc, sdst, sts, _ = run_s
-    bsrc, bdst, bts, _ = run_b
-    E = sts.shape[0]
-    B = bts.shape[0]
-
-    rank_s = jnp.searchsorted(bts, sts, side="left").astype(jnp.int32)
-    rank_b = jnp.searchsorted(sts, bts, side="right").astype(jnp.int32)
-    pos_s = jnp.arange(E, dtype=jnp.int32) + rank_s
-    pos_b = jnp.arange(B, dtype=jnp.int32) + rank_b
-
-    EM = E + B
-    msrc = jnp.zeros((EM,), jnp.int32).at[pos_s].set(ssrc).at[pos_b].set(bsrc)
-    mdst = jnp.zeros((EM,), jnp.int32).at[pos_s].set(sdst).at[pos_b].set(bdst)
-    mts = jnp.full((EM,), TS_PAD, jnp.int32).at[pos_s].set(sts).at[pos_b].set(bts)
-    return msrc, mdst, mts
-
-
-def _dirty_nodes(state: WindowState, run_b, merged, keep_n, bn, evict_to,
+def _dirty_nodes(state: WindowState, batch: EdgeBatch, adv: _Advance,
                  node_capacity: int) -> jax.Array:
     """bool[N] mask of nodes whose neighborhood region changed this advance.
 
     Exactly three ways a node's region content can change (the stable
-    merge + stable lexsort keep every untouched node's region sequence
+    merge + stable src sort keep every untouched node's region sequence
     identical, merely shifted): it gained a kept batch edge, it lost an
     edge to prefix eviction, or it lost an edge to the overflow clip of
     the merged run. The alias-table incremental update rebuilds precisely
@@ -236,20 +202,15 @@ def _dirty_nodes(state: WindowState, run_b, merged, keep_n, bn, evict_to,
     nc = node_capacity
     E = state.index.store.capacity
     dirty = jnp.zeros((nc,), bool)
-
-    bsrc = run_b[0]
-    B = bsrc.shape[0]
-    bkept = jnp.arange(B, dtype=jnp.int32) < bn
-    dirty = dirty.at[jnp.where(bkept, bsrc, nc)].set(True, mode="drop")
+    dirty = dirty.at[jnp.where(adv.bkeep, batch.src, nc)].set(
+        True, mode="drop")
 
     old_src = state.index.store.src
-    evicted = jnp.arange(E, dtype=jnp.int32) < evict_to
+    evicted = jnp.arange(E, dtype=jnp.int32) < adv.evict_to
     dirty = dirty.at[jnp.where(evicted, old_src, nc)].set(True, mode="drop")
 
-    msrc = merged[0]
-    EM = msrc.shape[0]
-    overflow = jnp.maximum(keep_n + bn - E, 0)
-    clipped = jnp.arange(EM, dtype=jnp.int32) < overflow
+    msrc = adv.merged_src
+    clipped = jnp.arange(msrc.shape[0], dtype=jnp.int32) < adv.overflow
     dirty = dirty.at[jnp.where(clipped, msrc, nc)].set(True, mode="drop")
     return dirty
 
@@ -257,10 +218,10 @@ def _dirty_nodes(state: WindowState, run_b, merged, keep_n, bn, evict_to,
 def ingest_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
                 bias_scale: float = 1.0, watermark=None,
                 table: Optional[TableSpec] = None) -> WindowState:
-    """Merge-based window advance (unjitted body; see ``ingest``).
+    """Window advance + index rebuild (unjitted body; see ``ingest``).
 
     ``watermark`` is the sharded-window eviction hook (see
-    ``_prepare_runs``); single-device callers leave it ``None``.
+    ``_advance_store``); single-device callers leave it ``None``.
 
     ``table`` (static TableSpec) switches on alias-table maintenance:
     only the dirty nodes (see ``_dirty_nodes``) are rebuilt against the
@@ -268,19 +229,16 @@ def ingest_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
     The spec must be passed on *every* ingest of a table-carrying state —
     omitting it drops the tables from the returned state.
     """
-    run_s, run_b, t_now, late, evict_to = _prepare_runs(
-        state.index.store, state.t_now, state.window, batch, node_capacity,
-        watermark=watermark)
-    merged = _merge_runs(run_s, run_b)
-    new = _finalize(state, merged, run_s[3], run_b[3], t_now, late,
+    adv = _advance_store(state.index.store, state.t_now, state.window,
+                         batch, node_capacity, watermark=watermark)
+    new = _finalize(state, adv.store, adv.t_now, adv.late, adv.overflow,
                     batch.count, node_capacity, bias_scale)
     if table is None:
         return new
     if state.tables is None:
         tables = build_tables(new.index, table)
     else:
-        dirty = _dirty_nodes(state, run_b, merged, run_s[3], run_b[3],
-                             evict_to, node_capacity)
+        dirty = _dirty_nodes(state, batch, adv, node_capacity)
         tables = update_tables(new.index, table,
                                old_starts=state.index.node_starts,
                                old_tables=state.tables, dirty=dirty)
@@ -289,11 +247,37 @@ def ingest_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
 
 def _ingest_sort_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
                       bias_scale: float = 1.0) -> WindowState:
-    """Seed reference path: concat + global stable argsort (O((m+b) log))."""
-    run_s, run_b, t_now, late, _ = _prepare_runs(
-        state.index.store, state.t_now, state.window, batch, node_capacity)
-    ssrc, sdst, sts, keep_n = run_s
-    bsrc, bdst, bts, bn = run_b
+    """Seed reference path, written independently of ``_advance_store``:
+    sort the batch, drop late edges, shift out the evicted store prefix,
+    then concat + global stable argsort + gathers, and clip to capacity."""
+    store = state.index.store
+    E = store.capacity
+    B = batch.src.shape[0]
+
+    bvalid = jnp.arange(B, dtype=jnp.int32) < batch.count
+    bts = jnp.where(bvalid, batch.ts, TS_PAD)
+    border = jnp.argsort(bts).astype(jnp.int32)
+    bsrc, bdst, bts = batch.src[border], batch.dst[border], bts[border]
+    last = jnp.where(batch.count > 0,
+                     bts[jnp.clip(batch.count - 1, 0, B - 1)], -TS_PAD)
+    t_now = jnp.maximum(state.t_now, last)
+    cutoff = t_now - state.window
+
+    blate = bvalid & (bts < cutoff)
+    bkeep = bvalid & ~blate
+    bperm = jnp.argsort(jnp.where(bkeep, 0, 1), stable=True).astype(jnp.int32)
+    bsrc, bdst, bts = bsrc[bperm], bdst[bperm], bts[bperm]
+    bn = jnp.sum(bkeep.astype(jnp.int32))
+    bts = jnp.where(jnp.arange(B) < bn, bts, TS_PAD)
+
+    evict_to = jnp.searchsorted(store.ts, cutoff, side="left").astype(jnp.int32)
+    evict_to = jnp.minimum(evict_to, store.num_edges)
+    keep_n = store.num_edges - evict_to
+    idx = jnp.clip(jnp.arange(E, dtype=jnp.int32) + evict_to, 0, E - 1)
+    live = jnp.arange(E, dtype=jnp.int32) < keep_n
+    ssrc = jnp.where(live, store.src[idx], node_capacity)
+    sdst = jnp.where(live, store.dst[idx], 0)
+    sts = jnp.where(live, store.ts[idx], TS_PAD)
 
     msrc = jnp.concatenate([ssrc, bsrc])
     mdst = jnp.concatenate([sdst, bdst])
@@ -301,8 +285,17 @@ def _ingest_sort_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
     morder = jnp.argsort(mts).astype(jnp.int32)
     msrc, mdst, mts = msrc[morder], mdst[morder], mts[morder]
 
-    return _finalize(state, (msrc, mdst, mts), keep_n, bn, t_now, late,
-                     batch.count, node_capacity, bias_scale)
+    total = keep_n + bn
+    overflow = jnp.maximum(total - E, 0)
+    idx2 = jnp.clip(jnp.arange(E, dtype=jnp.int32) + overflow, 0, E + B - 1)
+    live2 = jnp.arange(E, dtype=jnp.int32) < jnp.minimum(total, E)
+    new_store = EdgeStore(
+        src=jnp.where(live2, msrc[idx2], node_capacity),
+        dst=jnp.where(live2, mdst[idx2], 0),
+        ts=jnp.where(live2, mts[idx2], TS_PAD),
+        num_edges=jnp.minimum(total, E).astype(jnp.int32))
+    return _finalize(state, new_store, t_now, jnp.sum(blate.astype(jnp.int32)),
+                     overflow, batch.count, node_capacity, bias_scale)
 
 
 # Public entry points. ``ingest`` (merge path) donates the old WindowState so
@@ -361,13 +354,9 @@ def advance_view_impl(view: TsView, batch: EdgeBatch, node_capacity: int,
                       watermark=None) -> TsView:
     """Advance a ts-view by one batch: the window pipeline minus the index
     build. Bit-identical store/t_now trajectory to ``ingest_impl``."""
-    run_s, run_b, t_now, _, _ = _prepare_runs(
-        view.store, view.t_now, view.window, batch, node_capacity,
-        watermark=watermark)
-    merged = _merge_runs(run_s, run_b)
-    new_store, _ = _clip_to_capacity(merged, run_s[3], run_b[3],
-                                     view.store.capacity, node_capacity)
-    return TsView(store=new_store, t_now=t_now, window=view.window)
+    adv = _advance_store(view.store, view.t_now, view.window, batch,
+                         node_capacity, watermark=watermark)
+    return TsView(store=adv.store, t_now=adv.t_now, window=view.window)
 
 
 # Non-donating on purpose: the serving snapshot double-buffer keeps the old

@@ -21,7 +21,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -79,7 +79,7 @@ def gpipe_forward(mesh: Mesh, axis: str, stage_fn: Callable,
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
     fn = shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(axis), check_rep=False)
+                   out_specs=P(axis), check_vma=False)
     out = fn(stage_params, x_microbatches)
     # post-psum every stage holds identical outputs; take one replica
     return out[0]

@@ -79,7 +79,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import (
@@ -185,16 +185,19 @@ def init_sharded_window(num_shards: int, edge_capacity_per_shard: int,
     Sharded *sampling* under bias='table' stays refused — a migrating
     walk's draw would need its owner's table — but the maintenance
     itself shards cleanly because regions are node-local."""
-    one = init_window(edge_capacity_per_shard, node_capacity, window,
-                      bias_scale, table=table)
-    stacked = jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (num_shards,) + x.shape), one)
-    state = ShardedWindowState(
-        window=stacked,
-        exchange_drops=jnp.zeros((num_shards,), jnp.int32))
-    if mesh is not None:
-        state = jax.device_put(state, NamedSharding(mesh, P(axis_name)))
-    return state
+    def build() -> ShardedWindowState:
+        one = init_window(edge_capacity_per_shard, node_capacity, window,
+                          bias_scale, table=table)
+        return ShardedWindowState(
+            window=jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (num_shards,) + x.shape), one),
+            exchange_drops=jnp.zeros((num_shards,), jnp.int32))
+
+    if mesh is None:
+        return build()
+    # built under the output sharding, so each device materializes only
+    # its own slice (stacking first would hold all D slices on one device)
+    return jax.jit(build, out_shardings=NamedSharding(mesh, P(axis_name)))()
 
 
 # ---------------------------------------------------------------------------
@@ -514,7 +517,7 @@ def _ingest_sharded_impl(state: ShardedWindowState, bsrc, bdst, bts, count, *,
         exchange_drops=sharded)
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(state_spec, sharded, sharded, sharded, P()),
-                   out_specs=state_spec, check_rep=False)
+                   out_specs=state_spec, check_vma=False)
     return fn(state, bsrc, bdst, bts, count)
 
 
@@ -639,7 +642,7 @@ def serve_lanes_sharded(state: ShardedWindowState, view: TsView,
     out_specs = (sharded,) * (6 if with_probes else 5)
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(state_spec, view_spec, P(), lane_spec),
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     return fn(state, view, key, lanes)
 
 
@@ -768,7 +771,7 @@ def _replay_scan_sharded(state: ShardedWindowState, bsrc, bdst, bts, bcount,
         in_specs=(state_spec, P(None, axis_name), P(None, axis_name),
                   P(None, axis_name), P(), P()),
         out_specs=out_specs,
-        check_rep=False)
+        check_vma=False)
     return fn(state, bsrc, bdst, bts, bcount, key)
 
 
@@ -1002,7 +1005,7 @@ def _reshard_impl(state: ShardedWindowState, *, mesh: Mesh, axis_name: str,
     guarantee), one stable ts-argsort (ties therefore break by (old
     shard, position) — for edges of one source node that is their
     original relative order, which is all walk bit-identity needs), then
-    an overflow clip keeping the NEWEST E edges (``_clip_to_capacity``'s
+    an overflow clip keeping the NEWEST E edges (``_advance_store``'s
     rule) with the loss counted in ``exchange_drops``.
 
     Counters: per-shard ``ingested``/``late_drops``/``overflow_drops``/
@@ -1061,7 +1064,7 @@ def _reshard_impl(state: ShardedWindowState, *, mesh: Mesh, axis_name: str,
         window=jax.tree.map(lambda _: sharded, state.window),
         exchange_drops=sharded)
     fn = shard_map(shard_fn, mesh=mesh, in_specs=(state_spec,),
-                   out_specs=state_spec, check_rep=False)
+                   out_specs=state_spec, check_vma=False)
     return fn(state)
 
 

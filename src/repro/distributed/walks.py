@@ -25,7 +25,7 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import SamplerConfig, SchedulerConfig, WalkConfig
@@ -59,7 +59,7 @@ def _sharded_walk_fn(mesh: Mesh, axis_name: str, wcfg: WalkConfig,
     fn = shard_map(shard_fn, mesh=mesh,
                    in_specs=(P(), P()),              # index + key replicated
                    out_specs=(P(axis_name), P(axis_name), P(axis_name)),
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)
 
 

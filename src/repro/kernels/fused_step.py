@@ -65,20 +65,32 @@ class FusedStepResult(NamedTuple):
 
 
 def _count_true(mask: jax.Array) -> jax.Array:
-    return jnp.sum(mask.astype(jnp.int32), axis=1)
+    return jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
 
 
 def _onehot_i32(values_row: jax.Array, pos: jax.Array,
                 k: jax.Array) -> jax.Array:
     """Exact int32 gather-by-one-hot: sum(where(pos == k, values, 0))."""
-    sel = jnp.where(pos == k[:, None], values_row[None, :], 0)
-    return jnp.sum(sel, axis=1)
+    sel = jnp.where(pos == k, values_row, 0)
+    return jnp.sum(sel, axis=1, keepdims=True)
 
 
 def _onehot_f32(values_row: jax.Array, pos: jax.Array,
                 k: jax.Array) -> jax.Array:
-    sel = jnp.where(pos == k[:, None], values_row[None, :], 0.0)
-    return jnp.sum(sel, axis=1)
+    sel = jnp.where(pos == k, values_row, 0.0)
+    return jnp.sum(sel, axis=1, keepdims=True)
+
+
+def _row(*refs) -> jax.Array:
+    """Concatenate staged 1-D edge blocks into one (1, n) lane row."""
+    return jnp.concatenate([r[...][None, :] for r in refs], axis=1)
+
+
+# Layout: per-walk arrays enter the kernels as (W, 1) columns in (TW, 1)
+# blocks, so every lane quantity is a (TW, 1) column that broadcasts
+# against the (1, n) edge rows without a relayout. Edge blocks stay 1-D
+# (tile_edges is a multiple of 1024, the chip's 1-D tiling); a 1-D walk
+# block of tile_walks = 256 would not match it.
 
 
 # ---------------------------------------------------------------------------
@@ -98,28 +110,27 @@ def _finalize(k, n, pos, dst, ts, kmax, k_ref, n_ref, dst_out_ref,
 
 def _cutoff(time_ref, lo_ref, hi_ref, ts):
     """Dense compare-and-reduce temporal cutoff (DESIGN.md §2)."""
-    t = time_ref[...][:, None]
-    lo = lo_ref[...][:, None]
-    hi = hi_ref[...][:, None]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, ts.shape[0]), 1)
+    lo = lo_ref[...]
+    hi = hi_ref[...]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, ts.shape[1]), 1)
     in_region = (pos >= lo) & (pos < hi)
-    c = lo[:, 0] + _count_true(in_region & (ts[None, :] <= t))
-    n = hi[:, 0] - c
+    c = lo + _count_true(in_region & (ts <= time_ref[...]))
+    n = hi - c
     return pos, hi, c, n
 
 
 def _small_kernel_index(
         # scalar prefetch
         base_ref,
-        # per-walk tile inputs [TW]
+        # per-walk tile inputs [TW, 1]
         time_ref, lo_ref, hi_ref, u_ref, code_ref,
         # staged edge-view windows, two consecutive blocks each [TE]
         ts0_ref, ts1_ref, dst0_ref, dst1_ref,
-        # outputs [TW]
+        # outputs [TW, 1]
         k_ref, n_ref, dst_out_ref, ts_out_ref):
     te = ts0_ref.shape[0]
-    ts = jnp.concatenate([ts0_ref[...], ts1_ref[...]])        # [2TE]
-    dst = jnp.concatenate([dst0_ref[...], dst1_ref[...]])
+    ts = _row(ts0_ref, ts1_ref)                               # [1, 2TE]
+    dst = _row(dst0_ref, dst1_ref)
     pos, _, c, n = _cutoff(time_ref, lo_ref, hi_ref, ts)
     # branchless per-lane closed-form dispatch (paper eqs 1-3, §2.5)
     k = c + index_pick_lanes(code_ref[...], u_ref[...], n)
@@ -136,12 +147,12 @@ def _small_kernel_weight(
         pl0_ref, pl1_ref, pls0_ref, pls1_ref,
         k_ref, n_ref, dst_out_ref, ts_out_ref):
     te = ts0_ref.shape[0]
-    ts = jnp.concatenate([ts0_ref[...], ts1_ref[...]])
-    dst = jnp.concatenate([dst0_ref[...], dst1_ref[...]])
-    pe = jnp.concatenate([pe0_ref[...], pe1_ref[...]])
-    pes = jnp.concatenate([pes0_ref[...], pes1_ref[...]])
-    pl_ = jnp.concatenate([pl0_ref[...], pl1_ref[...]])
-    pls = jnp.concatenate([pls0_ref[...], pls1_ref[...]])
+    ts = _row(ts0_ref, ts1_ref)
+    dst = _row(dst0_ref, dst1_ref)
+    pe = _row(pe0_ref, pe1_ref)
+    pes = _row(pes0_ref, pes1_ref)
+    pl_ = _row(pl0_ref, pl1_ref)
+    pls = _row(pls0_ref, pls1_ref)
 
     pos, hi, c, n = _cutoff(time_ref, lo_ref, hi_ref, ts)
     u = u_ref[...]
@@ -151,25 +162,20 @@ def _small_kernel_weight(
     # over the shifted row. P(hi) must come from the shifted row (ps[hi-1]):
     # reading pe[hi] yields 0 when hi == 2·TE (exact-fit region, §2.4.3).
     pe_c = _onehot_f32(pe, pos, c)
-    pe_hi = jnp.sum(jnp.where(pos == hi - 1, pes[None, :], 0.0), axis=1)
+    pe_hi = _onehot_f32(pes, pos, hi - 1)
     total_e = pe_hi - pe_c
     target_e = pe_c + u * total_e
-    below_e = (pos >= c[:, None]) & (pos < hi) \
-        & (pes[None, :] < target_e[:, None])
+    below_e = (pos >= c) & (pos < hi) & (pes < target_e)
     k_exp = jnp.where(total_e > 0, c + _count_true(below_e), fb)
 
     # linear: S(j) = (PL(j+1) − PL(c)) − (j+1−c)·δ, δ = ts_c − t_base(v)
     ts_c = _onehot_i32(ts, pos, c)
-    delta = (ts_c - tbase_ref[...]).astype(jnp.float32)[:, None]
-    pl_c = _onehot_f32(pl_, pos, c)[:, None]
-    pl_hi = jnp.sum(jnp.where(pos == hi - 1, pls[None, :], 0.0), axis=1)
-    s = (pls[None, :] - pl_c) \
-        - (pos + 1 - c[:, None]).astype(jnp.float32) * delta
-    s_hi = (pl_hi[:, None] - pl_c) \
-        - (hi - c[:, None]).astype(jnp.float32) * delta
-    total_l = s_hi[:, 0]
-    below_l = (pos >= c[:, None]) & (pos < hi) \
-        & (s < (u * total_l)[:, None])
+    delta = (ts_c - tbase_ref[...]).astype(jnp.float32)
+    pl_c = _onehot_f32(pl_, pos, c)
+    pl_hi = _onehot_f32(pls, pos, hi - 1)
+    s = (pls - pl_c) - (pos + 1 - c).astype(jnp.float32) * delta
+    total_l = (pl_hi - pl_c) - (hi - c).astype(jnp.float32) * delta
+    below_l = (pos >= c) & (pos < hi) & (s < u * total_l)
     k_lin = jnp.where(total_l > 0, c + _count_true(below_l), fb)
 
     code = code_ref[...]
@@ -217,14 +223,14 @@ def _zero_refs(*refs):
 
 def _big_kernel_index(
         blo_ref, bhi_ref,
-        # per-walk inputs [TW]; a/b are global region bounds (0 for tier-S
-        # lanes sharing the tile — their garbage is merged out)
+        # per-walk inputs [TW, 1]; a/b are global region bounds (0 for
+        # tier-S lanes sharing the tile — their garbage is merged out)
         a_ref, b_ref, time_ref, u_ref, code_ref,
         # one staged edge block [TE]
         ts_ref, dst_ref,
-        # outputs [TW]
+        # outputs [TW, 1]
         k_ref, n_ref, dst_out_ref, ts_out_ref,
-        # scratch [TW]
+        # scratch [TW, 1]
         cnt_ref):
     te = ts_ref.shape[0]
     j, live, pos = _big_prologue(blo_ref, bhi_ref, te)
@@ -237,18 +243,16 @@ def _big_kernel_index(
     def _step():
         a = a_ref[...]
         b = b_ref[...]
-        ts = ts_ref[...][None, :]
-        in_region = (pos >= a[:, None]) & (pos < b[:, None])
+        ts = _row(ts_ref)
+        in_region = (pos >= a) & (pos < b)
         cnt_ref[...] = cnt_ref[...] + _count_true(
-            in_region & (ts <= time_ref[...][:, None]))
+            in_region & (ts <= time_ref[...]))
         c = a + cnt_ref[...]
         n = b - c
         k = c + index_pick_lanes(code_ref[...], u_ref[...], n)
-        hit = pos == k[:, None]
-        dst_out_ref[...] = dst_out_ref[...] + jnp.sum(
-            jnp.where(hit, dst_ref[...][None, :], 0), axis=1)
-        ts_out_ref[...] = ts_out_ref[...] + jnp.sum(
-            jnp.where(hit, ts, 0), axis=1)
+        dst_out_ref[...] = dst_out_ref[...] + _onehot_i32(
+            _row(dst_ref), pos, k)
+        ts_out_ref[...] = ts_out_ref[...] + _onehot_i32(ts, pos, k)
         k_ref[...] = k
         n_ref[...] = n
 
@@ -259,7 +263,7 @@ def _big_kernel_weight(
         pbe_ref, pbl_ref,                 # P(b): pexp[b], plin[b] per lane
         ts_ref, dst_ref, pe_ref, pes_ref, pl_ref, pls_ref,
         k_ref, n_ref, dst_out_ref, ts_out_ref,
-        # scratch [TW]: cutoff count, P(c) captures, ts_c, pick counts
+        # scratch [TW, 1]: cutoff count, P(c) captures, ts_c, pick counts
         cnt_ref, pce_ref, pcl_ref, tsc_ref, pke_ref, pkl_ref):
     te = ts_ref.shape[0]
     j, live, pos = _big_prologue(blo_ref, bhi_ref, te)
@@ -274,37 +278,33 @@ def _big_kernel_weight(
         a = a_ref[...]
         b = b_ref[...]
         u = u_ref[...]
-        ts = ts_ref[...][None, :]
-        in_region = (pos >= a[:, None]) & (pos < b[:, None])
+        ts = _row(ts_ref)
+        in_region = (pos >= a) & (pos < b)
         cnt_ref[...] = cnt_ref[...] + _count_true(
-            in_region & (ts <= time_ref[...][:, None]))
+            in_region & (ts <= time_ref[...]))
         c = a + cnt_ref[...]
         n = b - c
 
         # capture P(c)/ts_c in the block where c finalizes (self-masking:
         # until then c sits at/past the end of the seen range)
-        hit_c = pos == c[:, None]
-        pce_ref[...] = pce_ref[...] + jnp.sum(
-            jnp.where(hit_c, pe_ref[...][None, :], 0.0), axis=1)
-        pcl_ref[...] = pcl_ref[...] + jnp.sum(
-            jnp.where(hit_c, pl_ref[...][None, :], 0.0), axis=1)
-        tsc_ref[...] = tsc_ref[...] + jnp.sum(jnp.where(hit_c, ts, 0),
-                                              axis=1)
+        pce_ref[...] = pce_ref[...] + _onehot_f32(_row(pe_ref), pos, c)
+        pcl_ref[...] = pcl_ref[...] + _onehot_f32(_row(pl_ref), pos, c)
+        tsc_ref[...] = tsc_ref[...] + _onehot_i32(ts, pos, c)
 
-        pick_region = (pos >= c[:, None]) & (pos < b[:, None])
+        pick_region = (pos >= c) & (pos < b)
         # exponential: count P(j+1) < target over [c, b)
         total_e = pbe_ref[...] - pce_ref[...]
         target_e = pce_ref[...] + u * total_e
         pke_ref[...] = pke_ref[...] + _count_true(
-            pick_region & (pes_ref[...][None, :] < target_e[:, None]))
+            pick_region & (_row(pes_ref) < target_e))
         # linear: count S(j) < u·total over [c, b)
         delta = (tsc_ref[...] - tbase_ref[...]).astype(jnp.float32)
-        s = (pls_ref[...][None, :] - pcl_ref[...][:, None]) \
-            - (pos + 1 - c[:, None]).astype(jnp.float32) * delta[:, None]
+        s = (_row(pls_ref) - pcl_ref[...]) \
+            - (pos + 1 - c).astype(jnp.float32) * delta
         total_l = (pbl_ref[...] - pcl_ref[...]) \
             - n.astype(jnp.float32) * delta
         pkl_ref[...] = pkl_ref[...] + _count_true(
-            pick_region & (s < (u * total_l)[:, None]))
+            pick_region & (s < u * total_l))
 
         # per-lane k, matching samplers.py expression order + clip exactly
         fb = c + index_uniform(u, n)
@@ -315,11 +315,9 @@ def _big_kernel_weight(
                       jnp.where(code == BIAS_LINEAR, k_lin, k_exp))
         k = jnp.clip(k, c, jnp.maximum(b - 1, c))
 
-        hit_k = pos == k[:, None]
-        dst_out_ref[...] = dst_out_ref[...] + jnp.sum(
-            jnp.where(hit_k, dst_ref[...][None, :], 0), axis=1)
-        ts_out_ref[...] = ts_out_ref[...] + jnp.sum(
-            jnp.where(hit_k, ts, 0), axis=1)
+        dst_out_ref[...] = dst_out_ref[...] + _onehot_i32(
+            _row(dst_ref), pos, k)
+        ts_out_ref[...] = ts_out_ref[...] + _onehot_i32(ts, pos, k)
         k_ref[...] = k
         n_ref[...] = n
 
@@ -374,10 +372,13 @@ def fused_walk_step(index: TemporalIndex, s_node: jax.Array,
     tbase = index.node_tbase[jnp.clip(s_node, 0, nc - 1)]
     base_blocks = base_blocks.astype(jnp.int32)
 
-    walk_spec = pl.BlockSpec((TW,), lambda i, base_: (i,))
+    def col(x):
+        return x.reshape(W, 1)
+
+    walk_spec = pl.BlockSpec((TW, 1), lambda i, base_: (i, 0))
     edge_spec0 = pl.BlockSpec((TE,), lambda i, base_: (base_[i],))
     edge_spec1 = pl.BlockSpec((TE,), lambda i, base_: (base_[i] + 1,))
-    out_shape = [jax.ShapeDtypeStruct((W,), jnp.int32) for _ in range(4)]
+    out_shape = [jax.ShapeDtypeStruct((W, 1), jnp.int32) for _ in range(4)]
 
     # --- tier S: one staged pass ----------------------------------------
     if mode == "index":
@@ -403,9 +404,9 @@ def fused_walk_step(index: TemporalIndex, s_node: jax.Array,
         + [edge_spec0, edge_spec1] * n_edge_s,
         out_specs=[walk_spec] * 4,
     )
-    k_s, n_s, dst_s, ts_s = pl.pallas_call(
+    k_s, n_s, dst_s, ts_s = (o.reshape(W) for o in pl.pallas_call(
         kernel_s, grid_spec=grid_s, out_shape=out_shape,
-        interpret=interpret)(base_blocks, *walk_in_s, *edge_in_s)
+        interpret=interpret)(base_blocks, *map(col, walk_in_s), *edge_in_s))
 
     # --- tier L: edge-window sweep ---------------------------------------
     ab_blk = (a // TE).reshape(T, TW)
@@ -420,11 +421,11 @@ def fused_walk_step(index: TemporalIndex, s_node: jax.Array,
     a_big = jnp.where(big, a, 0)
     b_big = jnp.where(big, b, 0)
 
-    walk_spec_l = pl.BlockSpec((TW,), lambda t, j, blo_, bhi_: (t,))
+    walk_spec_l = pl.BlockSpec((TW, 1), lambda t, j, blo_, bhi_: (t, 0))
     edge_spec_l = pl.BlockSpec(
         (TE,), lambda t, j, blo_, bhi_: (jnp.minimum(blo_[t] + j, bhi_[t]),))
-    scratch_i32 = pltpu.VMEM((TW,), jnp.int32)
-    scratch_f32 = pltpu.VMEM((TW,), jnp.float32)
+    scratch_i32 = pltpu.VMEM((TW, 1), jnp.int32)
+    scratch_f32 = pltpu.VMEM((TW, 1), jnp.float32)
     if mode == "index":
         kernel_l = _big_kernel_index
         walk_in_l = (a_big, b_big, s_time, u, code)
@@ -447,9 +448,9 @@ def fused_walk_step(index: TemporalIndex, s_node: jax.Array,
         out_specs=[walk_spec_l] * 4,
         scratch_shapes=scratch_l,
     )
-    k_l, n_l, dst_l, ts_l = pl.pallas_call(
+    k_l, n_l, dst_l, ts_l = (o.reshape(W) for o in pl.pallas_call(
         kernel_l, grid_spec=grid_l, out_shape=out_shape,
-        interpret=interpret)(blo, bhi, *walk_in_l, *edge_in_l)
+        interpret=interpret)(blo, bhi, *map(col, walk_in_l), *edge_in_l))
 
     # --- merge ------------------------------------------------------------
     tile_of_walk = jnp.arange(W, dtype=jnp.int32) // TW

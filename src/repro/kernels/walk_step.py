@@ -34,39 +34,44 @@ from repro.kernels.runtime import resolve_interpret
 
 
 def _count_true(mask: jax.Array) -> jax.Array:
-    return jnp.sum(mask.astype(jnp.int32), axis=1)
+    return jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
 
 
-def _onehot_pick_i32(values_row: jax.Array, pos: jax.Array,
-                     k: jax.Array) -> jax.Array:
-    """Exact int32 gather-by-one-hot: sum(where(pos == k, values, 0))."""
-    sel = jnp.where(pos == k[:, None], values_row[None, :], 0)
-    return jnp.sum(sel, axis=1)
+def _onehot_pick(values_row: jax.Array, pos: jax.Array,
+                 k: jax.Array) -> jax.Array:
+    """Exact gather-by-one-hot: sum(where(pos == k, values, 0))."""
+    sel = jnp.where(pos == k, values_row, jnp.zeros((), values_row.dtype))
+    return jnp.sum(sel, axis=1, keepdims=True)
+
+
+def _row(ref0, ref1) -> jax.Array:
+    """Two staged 1-D edge blocks as one (1, 2·TE) lane row."""
+    return jnp.concatenate([ref0[...][None, :], ref1[...][None, :]], axis=1)
 
 
 def _kernel(mode: str, bias: str,
             # scalar prefetch
             base_ref,
-            # per-walk tile inputs [TW]
+            # per-walk tile inputs [TW, 1] (columns: see walk_step_tiled)
             time_ref, lo_ref, hi_ref, u_ref, tbase_ref,
             # staged edge-view windows, two consecutive blocks each [TE]
             ts0_ref, ts1_ref, dst0_ref, dst1_ref,
             px0_ref, px1_ref, ps0_ref, ps1_ref,
-            # outputs [TW]
+            # outputs [TW, 1]
             k_ref, n_ref, dst_out_ref, ts_out_ref):
     te = ts0_ref.shape[0]
-    ts = jnp.concatenate([ts0_ref[...], ts1_ref[...]])        # [2TE]
-    dst = jnp.concatenate([dst0_ref[...], dst1_ref[...]])
+    ts = _row(ts0_ref, ts1_ref)                               # [1, 2TE]
+    dst = _row(dst0_ref, dst1_ref)
 
-    t = time_ref[...][:, None]                                # [TW, 1]
-    lo = lo_ref[...][:, None]
-    hi = hi_ref[...][:, None]
+    t = time_ref[...]                                         # [TW, 1]
+    lo = lo_ref[...]
+    hi = hi_ref[...]
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * te), 1)  # [1, 2TE]
     in_region = (pos >= lo) & (pos < hi)
 
     # temporal cutoff by dense count (ts ascending within [lo, hi))
-    c = lo[:, 0] + _count_true(in_region & (ts[None, :] <= t))
-    n = hi[:, 0] - c
+    c = lo + _count_true(in_region & (ts <= t))
+    n = hi - c
     u = u_ref[...]
 
     if mode == "index":
@@ -80,20 +85,19 @@ def _kernel(mode: str, bias: str,
             raise ValueError(bias)
         k = c + i
     elif mode == "weight":
-        px = jnp.concatenate([px0_ref[...], px1_ref[...]])    # P(base+j)
-        ps = jnp.concatenate([ps0_ref[...], ps1_ref[...]])    # P(base+j+1)
-        p_c = jnp.sum(jnp.where(pos == c[:, None], px[None, :], 0.0), axis=1)
+        px = _row(px0_ref, px1_ref)                           # P(base+j)
+        ps = _row(ps0_ref, ps1_ref)                           # P(base+j+1)
+        p_c = _onehot_pick(px, pos, c)
         # P(hi) comes from the shifted row: ps[j] = P(base+j+1), so
         # P(hi) = ps[hi-1]. Reading px[hi] silently yields 0 when hi == 2·TE
         # (a region ending exactly at the staged window's edge — a legal
         # in-tile task), which would zero the neighborhood's weight mass.
-        p_hi = jnp.sum(jnp.where(pos == hi - 1, ps[None, :], 0.0), axis=1)
+        p_hi = _onehot_pick(ps, pos, hi - 1)
         if bias == "exponential":
             total = p_hi - p_c
             target = p_c + u * total
             # smallest j in [c, hi) with P(j+1) >= target, via counting
-            below = (pos >= c[:, None]) & (pos < hi) \
-                & (ps[None, :] < target[:, None])
+            below = (pos >= c) & (pos < hi) & (ps < target)
             k = c + _count_true(below)
             # underflowed mass -> uniform fallback (matches samplers.py)
             k = jnp.where(total > 0, k, c + index_uniform(u, n))
@@ -101,17 +105,13 @@ def _kernel(mode: str, bias: str,
             # S(j) = (PL(j+1) - PL(c)) - (j+1-c)·δ, δ = ts_c − t_base(v);
             # px/ps here carry the *linear* prefix rows; t_base(v) arrives
             # per walk in tbase_ref (a cheap node-level gather done outside).
-            ts_c = _onehot_pick_i32(ts, pos, c)
-            delta = (ts_c - tbase_ref[...]).astype(jnp.float32)[:, None]
-            pl_c = jnp.sum(jnp.where(pos == c[:, None], px[None, :], 0.0),
-                           axis=1)[:, None]
-            s = (ps[None, :] - pl_c) \
-                - (pos + 1 - c[:, None]).astype(jnp.float32) * delta
-            s_hi = (p_hi[:, None] - pl_c) \
-                - (hi - c[:, None]).astype(jnp.float32) * delta
-            total = s_hi[:, 0]
+            ts_c = _onehot_pick(ts, pos, c)
+            delta = (ts_c - tbase_ref[...]).astype(jnp.float32)
+            pl_c = p_c
+            s = (ps - pl_c) - (pos + 1 - c).astype(jnp.float32) * delta
+            total = (p_hi - pl_c) - (hi - c).astype(jnp.float32) * delta
             target = u * total
-            below = (pos >= c[:, None]) & (pos < hi) & (s < target[:, None])
+            below = (pos >= c) & (pos < hi) & (s < target)
             k = c + _count_true(below)
             k = jnp.where(total > 0, k, c + index_uniform(u, n))
         elif bias == "uniform":
@@ -125,8 +125,8 @@ def _kernel(mode: str, bias: str,
     has = n > 0
     k_ref[...] = jnp.where(has, k, 0)
     n_ref[...] = n
-    dst_out_ref[...] = jnp.where(has, _onehot_pick_i32(dst, pos, k), 0)
-    ts_out_ref[...] = jnp.where(has, _onehot_pick_i32(ts, pos, k), 0)
+    dst_out_ref[...] = jnp.where(has, _onehot_pick(dst, pos, k), 0)
+    ts_out_ref[...] = jnp.where(has, _onehot_pick(ts, pos, k), 0)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -160,12 +160,14 @@ def walk_step_tiled(ns_ts, ns_dst, pfx, pfx_shift,
 
     from jax.experimental.pallas import tpu as pltpu
 
-    walk_spec = pl.BlockSpec((TW,), lambda i, base: (i,))
+    # per-walk arrays travel as (W, 1) columns in (TW, 1) blocks: a 1-D
+    # block of tile_walks = 256 does not match the chip's 1-D tiling (1024)
+    walk_spec = pl.BlockSpec((TW, 1), lambda i, base: (i, 0))
     edge_spec0 = pl.BlockSpec((TE,), lambda i, base: (base[i],))
     edge_spec1 = pl.BlockSpec((TE,), lambda i, base: (base[i] + 1,))
 
     kernel = functools.partial(_kernel, mode, bias)
-    out_shape = [jax.ShapeDtypeStruct((W,), jnp.int32) for _ in range(4)]
+    out_shape = [jax.ShapeDtypeStruct((W, 1), jnp.int32) for _ in range(4)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(T,),
@@ -174,7 +176,8 @@ def walk_step_tiled(ns_ts, ns_dst, pfx, pfx_shift,
     )
     fn = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
                         interpret=interpret)
-    k, n, dpick, tpick = fn(base_blocks, time, lo, hi, u, tbase,
-                            ns_ts, ns_ts, ns_dst, ns_dst,
-                            pfx, pfx, pfx_shift, pfx_shift)
+    cols = (x.reshape(W, 1) for x in (time, lo, hi, u, tbase))
+    outs = fn(base_blocks, *cols, ns_ts, ns_ts, ns_dst, ns_dst,
+              pfx, pfx, pfx_shift, pfx_shift)
+    k, n, dpick, tpick = (o.reshape(W) for o in outs)
     return k, n, dpick, tpick

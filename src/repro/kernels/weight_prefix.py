@@ -21,6 +21,24 @@ from jax.experimental import pallas as pl
 from repro.kernels.runtime import resolve_interpret
 
 
+def _lane_cumsum(w: jax.Array) -> jax.Array:
+    """Inclusive prefix sum along the lane axis of a (1, tile) block.
+
+    Hillis-Steele doubling: log2(tile) rounds of a lane rotate and a masked
+    add. Mosaic has no ``cumsum`` lowering; rotates and selects it has.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = w.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, w.shape, w.ndim - 1)
+    shift = 1
+    while shift < n:
+        w = w + jnp.where(lane >= shift, pltpu.roll(w, shift, w.ndim - 1),
+                          0.0)
+        shift *= 2
+    return w
+
+
 def _kernel(scale, dt_ref, valid_ref, out_ref, carry_ref):
     i = pl.program_id(0)
 
@@ -28,11 +46,12 @@ def _kernel(scale, dt_ref, valid_ref, out_ref, carry_ref):
     def _init():
         carry_ref[0] = 0.0
 
-    w = jnp.where(valid_ref[...],
+    w = jnp.where(valid_ref[...] != 0,
                   jnp.exp(scale * dt_ref[...].astype(jnp.float32)), 0.0)
-    c = jnp.cumsum(w, axis=-1)
+    c = _lane_cumsum(w)
     out_ref[...] = c + carry_ref[0]
-    carry_ref[0] = carry_ref[0] + c[0, -1]
+    # w >= 0, so the block's last prefix value is its maximum
+    carry_ref[0] = carry_ref[0] + jnp.max(c)
 
 
 @functools.partial(jax.jit,
@@ -60,5 +79,5 @@ def weight_prefix(dt: jax.Array, valid: jax.Array, *, scale: float = 1.0,
         out_shape=jax.ShapeDtypeStruct((1, E), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
-    )(dt[None, :], valid[None, :])
+    )(dt[None, :], valid.astype(jnp.int32)[None, :])
     return jnp.concatenate([jnp.zeros((1,), jnp.float32), inc[0]])

@@ -25,18 +25,8 @@ from typing import Iterator, Optional
 
 from repro.obs.registry import MetricsRegistry, get_registry
 
-try:                                    # profiler import is best-effort:
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except ImportError:                     # pragma: no cover - old jaxlib
-    _TraceAnnotation = None
-
-try:
-    from jax import named_scope         # noqa: F401  (re-export)
-except ImportError:                     # pragma: no cover - old jax
-    from contextlib import nullcontext
-
-    def named_scope(name):              # type: ignore[misc]
-        return nullcontext()
+from jax import named_scope             # noqa: F401  (re-export)
+from jax.profiler import TraceAnnotation
 
 STAGE_METRIC = "stage_seconds"
 STAGE_CALLS_METRIC = "stage_calls_total"
@@ -69,8 +59,7 @@ def span(stage: str, registry: Optional[MetricsRegistry] = None,
     lab = {"stage": stage}
     if labels:
         lab.update(labels)
-    ann = (_TraceAnnotation(f"obs:{stage}")
-           if annotate and _TraceAnnotation is not None else None)
+    ann = TraceAnnotation(f"obs:{stage}") if annotate else None
     t0 = time.perf_counter()
     try:
         if ann is not None:

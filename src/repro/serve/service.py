@@ -641,11 +641,8 @@ class WalkService:
 
     @staticmethod
     def _batch_ready(fl: _InFlight) -> bool:
-        """Non-blocking readiness probe on one in-flight batch. Older
-        runtimes without ``jax.Array.is_ready`` degrade to "always ready"
-        — harvest then blocks, which is correct, just overlap-free."""
-        is_ready = getattr(fl.probe, "is_ready", None)
-        return True if is_ready is None else bool(is_ready())
+        """Non-blocking readiness probe on one in-flight batch."""
+        return bool(fl.probe.is_ready())
 
     def _harvest(self, fl: _InFlight) -> int:
         """Materialize one in-flight batch and deliver its results."""

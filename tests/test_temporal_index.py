@@ -44,6 +44,24 @@ def test_ns_view_sorted_by_node_then_ts(small_index):
     assert np.all(np.diff(key) >= 0)
 
 
+def test_adjacency_view_is_stable_src_dst_ts_order():
+    """adj_order is the stable (src, dst, ts) sort of the store, ties (equal
+    triples) in store order — on a store dense in repeated edges."""
+    rng = np.random.default_rng(3)
+    n = 3000
+    src = rng.integers(0, 12, n)
+    dst = rng.integers(0, 12, n)
+    ts = rng.integers(0, 40, n)
+    store = store_from_arrays(src, dst, ts, edge_capacity=4096,
+                              node_capacity=16)
+    idx = build_index(store, 16)
+    s_src, s_dst, s_ts = (np.asarray(a) for a in (store.src, store.dst,
+                                                  store.ts))
+    want = np.lexsort((s_ts, s_dst, s_src))       # numpy's is stable
+    np.testing.assert_array_equal(np.asarray(idx.adj_order), want)
+    np.testing.assert_array_equal(np.asarray(idx.adj_dst), s_dst[want])
+
+
 def test_node_ranges_match_numpy(small_index, small_graph):
     idx = small_index
     g = small_graph
@@ -112,6 +130,33 @@ def test_ranged_search_is_searchsorted(values, target):
     got_ge = int(ranged_search(arr_p, lo, hi, t, strict=False)[0])
     assert got_strict == int(np.searchsorted(arr, target, side="right"))
     assert got_ge == int(np.searchsorted(arr, target, side="left"))
+
+
+@pytest.mark.parametrize("n", [1, 127, 129, 16_384, 16_513, (1 << 21) + 5])
+def test_ranged_search_on_region_sorted_array(n):
+    """Arbitrary sub-ranges of an array sorted only within regions, at
+    lengths that give the row search one to four levels, against numpy."""
+    rng = np.random.default_rng(n)
+    cuts = np.sort(rng.choice(np.arange(1, n + 1), min(n, 40), replace=False))
+    bounds = np.unique(np.concatenate([[0], cuts, [n]]))
+    arr = np.concatenate([np.sort(rng.integers(0, 500, b - a))
+                          for a, b in zip(bounds[:-1], bounds[1:])])
+    reg = rng.integers(0, len(bounds) - 1, 2000)
+    lo = bounds[reg] + (rng.random(2000) * (bounds[reg + 1] - bounds[reg])
+                        * (rng.random(2000) < 0.3)).astype(np.int64)
+    hi = np.maximum(lo, bounds[reg + 1]
+                    - (rng.random(2000) * (bounds[reg + 1] - lo)
+                       * (rng.random(2000) < 0.3)).astype(np.int64))
+    t = rng.integers(-2, 503, 2000)
+    for strict in (True, False):
+        got = np.asarray(ranged_search(
+            jnp.asarray(arr, jnp.int32), jnp.asarray(lo, jnp.int32),
+            jnp.asarray(hi, jnp.int32), jnp.asarray(t, jnp.int32),
+            strict=strict))
+        side = "right" if strict else "left"
+        want = [a + np.searchsorted(arr[a:b], x, side=side)
+                for a, b, x in zip(lo, hi, t)]
+        np.testing.assert_array_equal(got, want)
 
 
 @settings(max_examples=20, deadline=None)

@@ -6,17 +6,24 @@ exactly these tier counts. The values are checked in; any change to the
 tier rules (solo/group/mega thresholds, the fused tier-S/tier-L split of
 DESIGN.md §14, or the block-sweep count model) shows up here as an
 integer diff and must be re-baselined deliberately.
+
+Last re-baselined for JAX 0.9: ``jax_threefry_partitionable`` defaults to
+True since JAX 0.5.0, which changes the seeded draws that build the golden
+graph and its walk starts. The tier rule did not change; with the flag
+set back to False the previous counts (solo 93, group_smem 162,
+group_global 4, fused_small 3064, fused_big 600, fused_blocks 2400) are
+reproduced exactly.
 """
 from benchmarks.tier_distribution import golden_counts
 
 EXPECTED = {
-    "solo": 93,
-    "group_smem": 162,
-    "group_global": 4,
+    "solo": 97,
+    "group_smem": 156,
+    "group_global": 3,
     "mega": 0,
-    "fused_small": 3064,
-    "fused_big": 600,
-    "fused_blocks": 2400,
+    "fused_small": 3088,
+    "fused_big": 591,
+    "fused_blocks": 2364,
 }
 
 
