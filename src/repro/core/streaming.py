@@ -62,7 +62,7 @@ from repro.obs.probes import (
     replay_probe_zeros,
 )
 from repro.obs.registry import MetricsRegistry, get_registry
-from repro.obs.tracing import span
+from repro.obs.tracing import scope, span
 
 
 # sample_walks_sharded replicates the index per device; past this size a
@@ -75,7 +75,6 @@ class StreamStats:
     ingest_s: List[float] = field(default_factory=list)
     sample_s: List[float] = field(default_factory=list)
     edges_active: List[int] = field(default_factory=list)
-    walks_valid: List[float] = field(default_factory=list)
 
     @property
     def cumulative_ingest(self):
@@ -191,7 +190,7 @@ def _replay_scan_impl(state: WindowState, batches: EdgeBatch, key: jax.Array,
                 ingested_delta=st2.ingested - st.ingested,
                 late_delta=st2.late_drops - st.late_drops,
                 overflow_delta=st2.overflow_drops - st.overflow_drops,
-                lengths=res.lengths)
+                lengths=res.lengths, loop_steps=res.steps)
             return (st2, k, nbufs, res.lengths, pv), stats
         return (st2, k, nbufs, res.lengths), stats
 
@@ -199,7 +198,8 @@ def _replay_scan_impl(state: WindowState, batches: EdgeBatch, key: jax.Array,
     carry0 = [state, key, alloc_walk_buffers(wcfg), lengths0]
     if with_probes:
         carry0.append(replay_probe_zeros())
-    carry, stats = jax.lax.scan(step, tuple(carry0), batches)
+    with scope("replay"):
+        carry, stats = jax.lax.scan(step, tuple(carry0), batches)
     walks = WalkResult(nodes=carry[2].nodes, times=carry[2].times,
                        lengths=carry[3], stats=None)
     if with_probes:
@@ -298,6 +298,9 @@ class StreamingEngine:
         self._rebuilt_seen = 0
         # walk-buffer pool for sample_walks_donated, keyed by (W, L)
         self._walk_bufs: dict = {}
+        # sequence number of the last replay_device / sample_walks* call,
+        # carried by each stage span's profiler annotation
+        self._seq = 0
         self._warned_replicated_index = False
 
     def _publish_window(self) -> None:
@@ -381,15 +384,22 @@ class StreamingEngine:
                                "drivers")
         self._publish_window()
 
+    def _call_args(self) -> dict:
+        """Profiler-annotation arguments of the next call's stage spans."""
+        self._seq += 1
+        return {"seq": self._seq}
+
     def sample_walks(self, wcfg: WalkConfig,
                      collect_stats: bool = False):
-        self.key, sub = jax.random.split(self.key)
-        t0 = time.perf_counter()
-        res = generate_walks(self.state.index, sub, wcfg,
-                             self.cfg.sampler, self.cfg.scheduler,
-                             collect_stats=collect_stats,
-                             tables=self.state.tables)
-        self._finish_sample(res, t0, path="host")
+        args = self._call_args()
+        with span("walks.dispatch", self.registry, args=args):
+            self.key, sub = jax.random.split(self.key)
+            t0 = time.perf_counter()
+            res = generate_walks(self.state.index, sub, wcfg,
+                                 self.cfg.sampler, self.cfg.scheduler,
+                                 collect_stats=collect_stats,
+                                 tables=self.state.tables)
+        self._finish_sample(res, t0, path="host", args=args)
         return res
 
     def sample_walks_donated(self, wcfg: WalkConfig):
@@ -402,15 +412,18 @@ class StreamingEngine:
         (``np.asarray``) first if it must outlive the next round.
         """
         shape_key = (wcfg.num_walks, wcfg.max_length)
-        bufs = self._walk_bufs.pop(shape_key, None)
-        if bufs is None:
-            bufs = alloc_walk_buffers(wcfg)
-        self.key, sub = jax.random.split(self.key)
-        t0 = time.perf_counter()
-        res = generate_walks_donated(self.state.index, sub, bufs, wcfg,
-                                     self.cfg.sampler, self.cfg.scheduler,
-                                     tables=self.state.tables)
-        self._finish_sample(res, t0, path="donated")
+        args = self._call_args()
+        with span("walks.dispatch", self.registry, args=args):
+            bufs = self._walk_bufs.pop(shape_key, None)
+            if bufs is None:
+                bufs = alloc_walk_buffers(wcfg)
+            self.key, sub = jax.random.split(self.key)
+            t0 = time.perf_counter()
+            res = generate_walks_donated(self.state.index, sub, bufs, wcfg,
+                                         self.cfg.sampler,
+                                         self.cfg.scheduler,
+                                         tables=self.state.tables)
+        self._finish_sample(res, t0, path="donated", args=args)
         self._walk_bufs[shape_key] = WalkBuffers(res.nodes, res.times)
         return res
 
@@ -432,12 +445,14 @@ class StreamingEngine:
         """
         from repro.distributed.walks import generate_walks_sharded
         self._warn_replicated_index()
-        self.key, sub = jax.random.split(self.key)
-        t0 = time.perf_counter()
-        res = generate_walks_sharded(self.state.index, sub, wcfg,
-                                     self.cfg.sampler, self.cfg.scheduler,
-                                     mesh=mesh)
-        self._finish_sample(res, t0, path="sharded")
+        args = self._call_args()
+        with span("walks.dispatch", self.registry, args=args):
+            self.key, sub = jax.random.split(self.key)
+            t0 = time.perf_counter()
+            res = generate_walks_sharded(self.state.index, sub, wcfg,
+                                         self.cfg.sampler,
+                                         self.cfg.scheduler, mesh=mesh)
+        self._finish_sample(res, t0, path="sharded", args=args)
         return res
 
     def _warn_replicated_index(self) -> None:
@@ -460,28 +475,34 @@ class StreamingEngine:
                 stacklevel=3)
             self._warned_replicated_index = True
 
-    def _finish_sample(self, res, t0: float, path: str = "host") -> float:
+    def _finish_sample(self, res, t0: float, path: str,
+                       args: dict) -> float:
         """Shared stats tail of every sample_walks* entry point: sync,
-        record wall time + valid-walk fraction, publish into the registry,
-        return the elapsed seconds."""
-        jax.block_until_ready(res.nodes)
-        elapsed = time.perf_counter() - t0
-        self.stats.sample_s.append(elapsed)
-        lengths = np.asarray(res.lengths)
-        frac = float(np.mean(lengths >= 2)) if lengths.size else 0.0
-        self.stats.walks_valid.append(frac)
+        fetch the lengths and the hop loop's iteration count in one
+        transfer, record wall time, publish into the registry, return the
+        elapsed seconds."""
         reg = self.registry
-        reg.inc("walks_dispatched_total", int(lengths.size),
-                labels={"path": path},
-                help="walk slots dispatched, by sampling path")
-        reg.inc("walks_emitted_total", int(np.sum(lengths >= 2)),
-                labels={"driver": "host"},
-                help="walks with at least one hop")
-        reg.inc("walk_hops_total",
-                int(np.sum(np.maximum(lengths.astype(np.int64) - 1, 0))),
-                labels={"source": "replay"}, help="hop cells executed")
-        reg.observe("walk_sample_seconds", elapsed, labels={"path": path},
-                    help="wall time per sample_walks dispatch")
+        with span("walks.sync", reg, args=args):
+            jax.block_until_ready(res.nodes)
+        elapsed = time.perf_counter() - t0
+        with span("walks.fetch", reg, args=args):
+            lengths, steps = jax.device_get((res.lengths, res.steps))
+        with span("walks.publish", reg, args=args):
+            self.stats.sample_s.append(elapsed)
+            emitted = int(np.sum(lengths >= 2))
+            reg.inc("walks_dispatched_total", int(lengths.size),
+                    labels={"path": path},
+                    help="walk slots dispatched, by sampling path")
+            reg.inc("walks_emitted_total", emitted,
+                    labels={"driver": "host"},
+                    help="walks with at least one hop")
+            reg.inc("walk_hops_total",
+                    int(np.sum(np.maximum(lengths.astype(np.int64) - 1, 0))),
+                    labels={"source": path}, help="hop cells executed")
+            if steps is not None:
+                reg.inc("walk_lane_steps_total", lengths.size * int(steps),
+                        labels={"source": path},
+                        help="lanes processed by the hop loop, live or not")
         return elapsed
 
     def replay(self, batches: Iterable, wcfg: WalkConfig,
@@ -502,37 +523,43 @@ class StreamingEngine:
         — the reference trajectory the sharded replay
         (DistributedStreamingEngine) is tested bit-identical against.
         """
-        stacked = stack_batches(batches, self.batch_capacity)
-        self.key, sub = jax.random.split(self.key)
-        t0 = time.perf_counter()
-        if self.probes:
-            self.state, stats, walks, pv = replay_scan_probed(
-                self.state, stacked, sub, self.cfg.window.node_capacity,
-                wcfg, self.cfg.sampler, self.cfg.scheduler,
-                table=self._table)
+        reg = self.registry
+        args = self._call_args()
+        with span("replay.stage", reg, args=args):
+            # stacked on the host, sent in one transfer
+            stacked = stack_batches(batches, self.batch_capacity)
+        with span("replay.dispatch", reg, args=args):
+            self.key, sub = jax.random.split(self.key)
+            t0 = time.perf_counter()
+            if self.probes:
+                self.state, stats, walks, pv = replay_scan_probed(
+                    self.state, stacked, sub, self.cfg.window.node_capacity,
+                    wcfg, self.cfg.sampler, self.cfg.scheduler,
+                    table=self._table)
+            else:
+                self.state, stats, walks = replay_scan(
+                    self.state, stacked, sub, self.cfg.window.node_capacity,
+                    wcfg, self.cfg.sampler, self.cfg.scheduler,
+                    table=self._table)
+                pv = None
+        with span("replay.sync", reg, args=args):
             # the single sync point — probes ride the same materialization
             jax.block_until_ready((stats, pv))
-        else:
-            self.state, stats, walks = replay_scan(
-                self.state, stacked, sub, self.cfg.window.node_capacity,
-                wcfg, self.cfg.sampler, self.cfg.scheduler,
-                table=self._table)
-            jax.block_until_ready(stats)       # the single sync point
         elapsed = time.perf_counter() - t0
-        if self.probes:
-            flush_replay_probes(self.registry, pv, driver="device")
-            self.registry.observe("replay_seconds", elapsed,
-                                  labels={"driver": "device"},
-                                  help="wall time per replay_device call")
-            self._publish_window_from_replay(stats)
+        with span("replay.fetch", reg, args=args):
+            host_stats, pv, host_walks = jax.device_get(
+                (stats, pv, walks if return_walks else None))
+        with span("replay.publish", reg, args=args):
+            if self.probes:
+                flush_replay_probes(reg, pv, driver="device",
+                                    lanes=wcfg.num_walks)
+                reg.observe("replay_seconds", elapsed,
+                            labels={"driver": "device"},
+                            help="wall time per replay_device call")
+                self._publish_window_from_replay(host_stats)
         # NOTE: self.stats is left untouched — StreamStats' lists are
         # parallel per host-loop batch, and this driver has no per-batch
         # host timings to pair with. Everything lives in the return value.
-        host_stats = ReplayStats(*(np.asarray(a) for a in stats))
         if return_walks:
-            host_walks = WalkResult(nodes=np.asarray(walks.nodes),
-                                    times=np.asarray(walks.times),
-                                    lengths=np.asarray(walks.lengths),
-                                    stats=None)
             return host_stats, host_walks, elapsed
         return host_stats, elapsed

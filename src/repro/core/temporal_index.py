@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.edge_store import EdgeStore
+from repro.obs.tracing import scope
 
 
 class TemporalIndex(NamedTuple):
@@ -101,83 +102,89 @@ def _spread(node_vals: jax.Array, lo: jax.Array, nonempty: jax.Array,
 
 def _build_index_impl(store: EdgeStore, node_capacity: int,
                       bias_scale: float = 1.0) -> TemporalIndex:
-    """Bulk dual-index reconstruction (paper §2.6: two sorts + linear passes)."""
-    E = store.capacity
-    n_valid = store.num_edges
-    iota = jnp.arange(E, dtype=jnp.int32)
-    valid = iota < n_valid
+    """Bulk dual-index reconstruction (paper §2.6: two sorts + linear
+    passes); its device work carries the ``index`` scope."""
+    with scope("index"):
+        E = store.capacity
+        n_valid = store.num_edges
+        iota = jnp.arange(E, dtype=jnp.int32)
+        valid = iota < n_valid
 
-    # ---- sort 1: (src, ts) — the node-and-timestamp-grouped view --------
-    # The store is ts-sorted, so a stable sort by src alone yields the
-    # stable (src, ts) order. The view's columns ride the sort as payloads
-    # instead of being gathered through the permutation: on a TPU a sort
-    # moves them far faster than edge-capacity random gathers would.
-    # Padding edges have src == node_capacity, ts == TS_PAD -> sort last.
-    ns_src, ns_dst, ns_ts, ns_order = jax.lax.sort(
-        (store.src, store.dst, store.ts, iota), num_keys=1, is_stable=True)
+        # ---- sort 1: (src, ts) — the node-and-timestamp-grouped view ----
+        # The store is ts-sorted, so a stable sort by src alone yields the
+        # stable (src, ts) order. The view's columns ride the sort as payloads
+        # instead of being gathered through the permutation: on a TPU a sort
+        # moves them far faster than edge-capacity random gathers would.
+        # Padding edges have src == node_capacity, ts == TS_PAD -> sort last.
+        ns_src, ns_dst, ns_ts, ns_order = jax.lax.sort(
+            (store.src, store.dst, store.ts, iota), num_keys=1, is_stable=True)
 
-    # node regions: node_starts[v] = first position with ns_src >= v.
-    # one extra bucket (node_capacity) holds the padding edges.
-    nodes = jnp.arange(node_capacity + 2, dtype=jnp.int32)
-    node_starts = ranged_search(ns_src, jnp.zeros_like(nodes),
-                                jnp.full_like(nodes, E), nodes, strict=False)
-    lo = node_starts[:node_capacity]
-    hi = node_starts[1:node_capacity + 1]
+        # node regions: node_starts[v] = first position with ns_src >= v.
+        # one extra bucket (node_capacity) holds the padding edges.
+        nodes = jnp.arange(node_capacity + 2, dtype=jnp.int32)
+        node_starts = ranged_search(ns_src, jnp.zeros_like(nodes),
+                                    jnp.full_like(nodes, E), nodes,
+                                    strict=False)
+        lo = node_starts[:node_capacity]
+        hi = node_starts[1:node_capacity + 1]
 
-    # G axis: distinct timestamps per node region. A timestamp group starts
-    # wherever either the src or the ts changes in the (src, ts)-sorted
-    # order; a region's count is a difference of the running group count.
-    prev_src = jnp.concatenate([jnp.full((1,), -1, jnp.int32), ns_src[:-1]])
-    prev_ts = jnp.concatenate([jnp.full((1,), -1, jnp.int32), ns_ts[:-1]])
-    group_start = (ns_src != prev_src) | (ns_ts != prev_ts)
-    groups = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(
-        (group_start & (ns_src < node_capacity)).astype(jnp.int32))])
-    node_group_counts = groups[hi] - groups[lo]
+        # G axis: distinct timestamps per node region. A timestamp group starts
+        # wherever either the src or the ts changes in the (src, ts)-sorted
+        # order; a region's count is a difference of the running group count.
+        prev_src = jnp.concatenate([jnp.full((1,), -1, jnp.int32),
+                                    ns_src[:-1]])
+        prev_ts = jnp.concatenate([jnp.full((1,), -1, jnp.int32), ns_ts[:-1]])
+        group_start = (ns_src != prev_src) | (ns_ts != prev_ts)
+        groups = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(
+            (group_start & (ns_src < node_capacity)).astype(jnp.int32))])
+        node_group_counts = groups[hi] - groups[lo]
 
-    # per-node ts extrema (references for stable weights): a region is
-    # ts-sorted, so they are its first and last timestamps; 0 when empty
-    nonempty = hi > lo
-    node_tbase = jnp.where(nonempty, ns_ts[jnp.clip(lo, 0, E - 1)], 0)
-    node_tref = jnp.where(nonempty, ns_ts[jnp.clip(hi - 1, 0, E - 1)], 0)
+        # per-node ts extrema (references for stable weights): a region is
+        # ts-sorted, so they are its first and last timestamps; 0 when empty
+        nonempty = hi > lo
+        node_tbase = jnp.where(nonempty, ns_ts[jnp.clip(lo, 0, E - 1)], 0)
+        node_tref = jnp.where(nonempty, ns_ts[jnp.clip(hi - 1, 0, E - 1)], 0)
 
-    # ---- weight prefix arrays (linear passes) ----------------------------
-    in_range = ns_src < node_capacity
-    dt_exp = (ns_ts - _spread(node_tref, lo, nonempty, E)).astype(
-        jnp.float32)
-    w_exp = jnp.where(in_range, jnp.exp(bias_scale * dt_exp), 0.0)
-    elem_lin = (ns_ts - _spread(node_tbase, lo, nonempty, E) + 1).astype(
-        jnp.float32)
-    w_lin = jnp.where(in_range, elem_lin, 0.0)
-    zero = jnp.zeros((1,), jnp.float32)
-    pexp = jnp.concatenate([zero, jnp.cumsum(w_exp)])
-    plin = jnp.concatenate([zero, jnp.cumsum(w_lin)])
+        # ---- weight prefix arrays (linear passes) ------------------------
+        in_range = ns_src < node_capacity
+        dt_exp = (ns_ts - _spread(node_tref, lo, nonempty, E)).astype(
+            jnp.float32)
+        w_exp = jnp.where(in_range, jnp.exp(bias_scale * dt_exp), 0.0)
+        elem_lin = (ns_ts - _spread(node_tbase, lo, nonempty, E) + 1).astype(
+            jnp.float32)
+        w_lin = jnp.where(in_range, elem_lin, 0.0)
+        zero = jnp.zeros((1,), jnp.float32)
+        pexp = jnp.concatenate([zero, jnp.cumsum(w_exp)])
+        plin = jnp.concatenate([zero, jnp.cumsum(w_lin)])
 
-    # store-level prefixes (start-edge selection over the whole window)
-    t_hi = jnp.where(n_valid > 0, store.ts[jnp.maximum(n_valid - 1, 0)], 0)
-    t_lo = store.ts[0]
-    w_exp_s = jnp.where(valid, jnp.exp(bias_scale * (store.ts - t_hi).astype(jnp.float32)), 0.0)
-    w_lin_s = jnp.where(valid, (store.ts - t_lo + 1).astype(jnp.float32), 0.0)
-    pexp_store = jnp.concatenate([zero, jnp.cumsum(w_exp_s)])
-    plin_store = jnp.concatenate([zero, jnp.cumsum(w_lin_s)])
+        # store-level prefixes (start-edge selection over the whole window)
+        t_hi = jnp.where(n_valid > 0, store.ts[jnp.maximum(n_valid - 1, 0)], 0)
+        t_lo = store.ts[0]
+        w_exp_s = jnp.where(valid, jnp.exp(
+            bias_scale * (store.ts - t_hi).astype(jnp.float32)), 0.0)
+        w_lin_s = jnp.where(valid, (store.ts - t_lo + 1).astype(jnp.float32),
+                            0.0)
+        pexp_store = jnp.concatenate([zero, jnp.cumsum(w_exp_s)])
+        plin_store = jnp.concatenate([zero, jnp.cumsum(w_lin_s)])
 
-    # ---- sort 2: (src, dst, ts) — adjacency view -------------------------
-    # A stable (src, dst) sort of the ns view: equal (src, dst) runs keep the
-    # ns view's (ts, store position) order, so this is the stable
-    # (src, dst, ts) order of the store. Two keys, not three: a three-key
-    # sort costs the TPU compiler ~1 min more per program that rebuilds
-    # the index.
-    _, adj_dst, adj_order = jax.lax.sort((ns_src, ns_dst, ns_order),
-                                         num_keys=2, is_stable=True)
+        # ---- sort 2: (src, dst, ts) — adjacency view ---------------------
+        # A stable (src, dst) sort of the ns view: equal (src, dst) runs
+        # keep the ns view's (ts, store position) order, so this is the
+        # stable (src, dst, ts) order of the store. Two keys, not three: a
+        # three-key sort costs the TPU compiler ~1 min more per program
+        # that rebuilds the index.
+        _, adj_dst, adj_order = jax.lax.sort((ns_src, ns_dst, ns_order),
+                                             num_keys=2, is_stable=True)
 
-    return TemporalIndex(
-        store=store,
-        ns_order=ns_order, ns_src=ns_src, ns_dst=ns_dst, ns_ts=ns_ts,
-        node_starts=node_starts, node_group_counts=node_group_counts,
-        pexp=pexp, plin=plin,
-        node_tref=node_tref, node_tbase=node_tbase,
-        pexp_store=pexp_store, plin_store=plin_store,
-        adj_order=adj_order, adj_dst=adj_dst,
-    )
+        return TemporalIndex(
+            store=store,
+            ns_order=ns_order, ns_src=ns_src, ns_dst=ns_dst, ns_ts=ns_ts,
+            node_starts=node_starts, node_group_counts=node_group_counts,
+            pexp=pexp, plin=plin,
+            node_tref=node_tref, node_tbase=node_tbase,
+            pexp_store=pexp_store, plin_store=plin_store,
+            adj_order=adj_order, adj_dst=adj_dst,
+        )
 
 
 build_index = partial(jax.jit, static_argnames=("node_capacity",

@@ -93,6 +93,7 @@ from repro.core.temporal_index import (
     ranged_search,
     temporal_cutoff,
 )
+from repro.obs.tracing import scope
 
 NODE_PAD = -1          # sentinel in emitted walks beyond walk length
 N2V_ROUNDS = 8         # rejection-sampling rounds per hop (vectorized)
@@ -245,6 +246,9 @@ class WalkResult(NamedTuple):
     times: jax.Array     # int32[W, L+1]
     lengths: jax.Array   # int32[W] number of nodes recorded (>=1)
     stats: Optional[jax.Array]   # float32[L, sched.NUM_STATS] or None
+    # int32 scalar: iterations the hop loop ran (None where the producer
+    # does not report it); every iteration processes all W lanes
+    steps: Optional[jax.Array] = None
 
 
 class WalkBuffers(NamedTuple):
@@ -558,13 +562,14 @@ def _sample_hop(index: TemporalIndex, scfg: SamplerConfig,
 def _hop_fullwalk(index, scfg, carry: _Carry, step: jax.Array,
                   hop_key, lane_bias=None, lane_u=None,
                   lane_limit=None, tables=None, lane_n2v=None) -> _Carry:
-    nn, nt, has_next, _ = _sample_hop(
-        index, scfg, carry.cur_node, carry.cur_time, carry.prev_node,
-        carry.alive, hop_key, lane_bias=lane_bias, lane_u=lane_u,
-        tables=tables, lane_n2v=lane_n2v)
-    if lane_limit is not None:
-        has_next = has_next & lane_limit
-    return _advance(carry, step, nn, nt, has_next)
+    with scope("pick"):
+        nn, nt, has_next, _ = _sample_hop(
+            index, scfg, carry.cur_node, carry.cur_time, carry.prev_node,
+            carry.alive, hop_key, lane_bias=lane_bias, lane_u=lane_u,
+            tables=tables, lane_n2v=lane_n2v)
+        if lane_limit is not None:
+            has_next = has_next & lane_limit
+        return _advance(carry, step, nn, nt, has_next)
 
 
 # ---------------------------------------------------------------------------
@@ -588,11 +593,12 @@ def _bucket_prologue(index: TemporalIndex, sched_cfg, carry: _Carry):
     state; shared by the grouped and tiled bucket hops. Returns the
     composed lane→walk map plus the permuted per-lane state."""
     nc = index.node_capacity
-    node_key = jnp.where(carry.alive, carry.cur_node, nc + 1)
-    pp = sched.bucket_regroup(node_key, carry.cur_time, nc,
-                              time_subsort=sched_cfg.regroup_time)
-    return (carry.lane[pp], carry.cur_node[pp], carry.cur_time[pp],
-            carry.prev_node[pp], carry.alive[pp])
+    with scope("regroup"):
+        node_key = jnp.where(carry.alive, carry.cur_node, nc + 1)
+        pp = sched.bucket_regroup(node_key, carry.cur_time, nc,
+                                  time_subsort=sched_cfg.regroup_time)
+        return (carry.lane[pp], carry.cur_node[pp], carry.cur_time[pp],
+                carry.prev_node[pp], carry.alive[pp])
 
 
 def _draw_pick(index, scfg, hop_key, c, b, s_node, s_prev, order,
@@ -649,29 +655,31 @@ def _hop_grouped(index, scfg, carry: _Carry, step: jax.Array,
     """Reference regroup: fresh lexsort by (node, time) + inverse scatter."""
     W = carry.cur_node.shape[0]
     nc = index.node_capacity
-    node_key = jnp.where(carry.alive, carry.cur_node, nc + 1)
-    perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
+    with scope("regroup"):
+        node_key = jnp.where(carry.alive, carry.cur_node, nc + 1)
+        perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
 
-    s_node = carry.cur_node[perm]
-    s_time = carry.cur_time[perm]
-    s_prev = carry.prev_node[perm]
-    s_alive = carry.alive[perm]
+        s_node = carry.cur_node[perm]
+        s_time = carry.cur_time[perm]
+        s_prev = carry.prev_node[perm]
+        s_alive = carry.alive[perm]
 
-    b, c = _segment_cutoff(index, s_node, s_time)
-    has_next_s = s_alive & (b - c > 0)
-    if lane_limit is not None:
-        has_next_s = has_next_s & lane_limit[perm]
+    with scope("pick"):
+        b, c = _segment_cutoff(index, s_node, s_time)
+        has_next_s = s_alive & (b - c > 0)
+        if lane_limit is not None:
+            has_next_s = has_next_s & lane_limit[perm]
 
-    k = _draw_pick(index, scfg, hop_key, c, b, s_node, s_prev, perm,
-                   lane_bias=lane_bias, lane_u=lane_u, tables=tables,
-                   lane_n2v=lane_n2v)
-    nn_s = index.ns_dst[k]
-    nt_s = index.ns_ts[k]
+        k = _draw_pick(index, scfg, hop_key, c, b, s_node, s_prev, perm,
+                       lane_bias=lane_bias, lane_u=lane_u, tables=tables,
+                       lane_n2v=lane_n2v)
+        nn_s = index.ns_dst[k]
+        nt_s = index.ns_ts[k]
 
-    # unsort back to original walk order
-    inv = jnp.zeros((W,), jnp.int32).at[perm].set(
-        jnp.arange(W, dtype=jnp.int32))
-    return _advance(carry, step, nn_s[inv], nt_s[inv], has_next_s[inv])
+        # unsort back to original walk order
+        inv = jnp.zeros((W,), jnp.int32).at[perm].set(
+            jnp.arange(W, dtype=jnp.int32))
+        return _advance(carry, step, nn_s[inv], nt_s[inv], has_next_s[inv])
 
 
 def _hop_grouped_bucket(index, scfg, sched_cfg, carry: _Carry,
@@ -688,36 +696,41 @@ def _hop_grouped_bucket(index, scfg, sched_cfg, carry: _Carry,
     lane, s_node, s_time, s_prev, s_alive = _bucket_prologue(
         index, sched_cfg, carry)
 
-    b, c = _segment_cutoff(index, s_node, s_time)
-    has_next_s = s_alive & (b - c > 0)
-    if lane_limit is not None:
-        has_next_s = has_next_s & lane_limit[lane]
+    with scope("pick"):
+        b, c = _segment_cutoff(index, s_node, s_time)
+        has_next_s = s_alive & (b - c > 0)
+        if lane_limit is not None:
+            has_next_s = has_next_s & lane_limit[lane]
 
-    k = _draw_pick(index, scfg, hop_key, c, b, s_node, s_prev, lane,
-                   lane_bias=lane_bias, lane_u=lane_u, tables=tables,
-                   lane_n2v=lane_n2v)
-    return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
-                          index.ns_dst[k], index.ns_ts[k], has_next_s)
+        k = _draw_pick(index, scfg, hop_key, c, b, s_node, s_prev, lane,
+                       lane_bias=lane_bias, lane_u=lane_u, tables=tables,
+                       lane_n2v=lane_n2v)
+        return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
+                              index.ns_dst[k], index.ns_ts[k], has_next_s)
 
 
 def _hop_tiled(index, scfg, sched_cfg, carry: _Carry, step, hop_key) -> _Carry:
     """Lexsort layout with the Pallas kernel executing search+sample."""
     from repro.kernels import ops as kops
     W = carry.cur_node.shape[0]
-    node_key = jnp.where(carry.alive, carry.cur_node, index.node_capacity + 1)
-    perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
-    s_node = carry.cur_node[perm]
-    s_time = carry.cur_time[perm]
-    s_alive = carry.alive[perm]
-    u = jax.random.uniform(hop_key, (W,))[perm]
+    with scope("regroup"):
+        node_key = jnp.where(carry.alive, carry.cur_node,
+                             index.node_capacity + 1)
+        perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
+        s_node = carry.cur_node[perm]
+        s_time = carry.cur_time[perm]
+        s_alive = carry.alive[perm]
 
-    k, n = kops.walk_step(index, s_node, s_time, u, scfg, sched_cfg)
-    has_next_s = s_alive & (n > 0)
-    k = jnp.clip(k, 0, index.edge_capacity - 1)
-    nn_s = index.ns_dst[k]
-    nt_s = index.ns_ts[k]
-    inv = jnp.zeros((W,), jnp.int32).at[perm].set(jnp.arange(W, dtype=jnp.int32))
-    return _advance(carry, step, nn_s[inv], nt_s[inv], has_next_s[inv])
+    with scope("pick"):
+        u = jax.random.uniform(hop_key, (W,))[perm]
+        k, n = kops.walk_step(index, s_node, s_time, u, scfg, sched_cfg)
+        has_next_s = s_alive & (n > 0)
+        k = jnp.clip(k, 0, index.edge_capacity - 1)
+        nn_s = index.ns_dst[k]
+        nt_s = index.ns_ts[k]
+        inv = jnp.zeros((W,), jnp.int32).at[perm].set(
+            jnp.arange(W, dtype=jnp.int32))
+        return _advance(carry, step, nn_s[inv], nt_s[inv], has_next_s[inv])
 
 
 def _hop_tiled_bucket(index, scfg, sched_cfg, carry: _Carry, step,
@@ -730,13 +743,13 @@ def _hop_tiled_bucket(index, scfg, sched_cfg, carry: _Carry, step,
     from repro.kernels import ops as kops
     lane, s_node, s_time, s_prev, s_alive = _bucket_prologue(
         index, sched_cfg, carry)
-    u = jax.random.uniform(hop_key, (carry.cur_node.shape[0],))[lane]
-
-    k, n = kops.walk_step(index, s_node, s_time, u, scfg, sched_cfg)
-    has_next_s = s_alive & (n > 0)
-    k = jnp.clip(k, 0, index.edge_capacity - 1)
-    return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
-                          index.ns_dst[k], index.ns_ts[k], has_next_s)
+    with scope("pick"):
+        u = jax.random.uniform(hop_key, (carry.cur_node.shape[0],))[lane]
+        k, n = kops.walk_step(index, s_node, s_time, u, scfg, sched_cfg)
+        has_next_s = s_alive & (n > 0)
+        k = jnp.clip(k, 0, index.edge_capacity - 1)
+        return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
+                              index.ns_dst[k], index.ns_ts[k], has_next_s)
 
 
 def _fused_draws(index, scfg, hop_key, order, lane_bias, lane_u):
@@ -763,21 +776,25 @@ def _hop_fused(index, scfg, sched_cfg, carry: _Carry, step, hop_key,
     """
     from repro.kernels import fused_step as kfused
     W = carry.cur_node.shape[0]
-    node_key = jnp.where(carry.alive, carry.cur_node, index.node_capacity + 1)
-    perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
-    s_node = carry.cur_node[perm]
-    s_time = carry.cur_time[perm]
-    s_alive = carry.alive[perm]
-    code, u = _fused_draws(index, scfg, hop_key, perm, lane_bias, lane_u)
+    with scope("regroup"):
+        node_key = jnp.where(carry.alive, carry.cur_node,
+                             index.node_capacity + 1)
+        perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
+        s_node = carry.cur_node[perm]
+        s_time = carry.cur_time[perm]
+        s_alive = carry.alive[perm]
 
-    out = kfused.fused_walk_step(index, s_node, s_time, code, u,
-                                 scfg.mode, sched_cfg)
-    has_next_s = s_alive & (out.n > 0)
-    if lane_limit is not None:
-        has_next_s = has_next_s & lane_limit[perm]
-    inv = jnp.zeros((W,), jnp.int32).at[perm].set(
-        jnp.arange(W, dtype=jnp.int32))
-    return _advance(carry, step, out.dst[inv], out.ts[inv], has_next_s[inv])
+    with scope("pick"):
+        code, u = _fused_draws(index, scfg, hop_key, perm, lane_bias, lane_u)
+        out = kfused.fused_walk_step(index, s_node, s_time, code, u,
+                                     scfg.mode, sched_cfg)
+        has_next_s = s_alive & (out.n > 0)
+        if lane_limit is not None:
+            has_next_s = has_next_s & lane_limit[perm]
+        inv = jnp.zeros((W,), jnp.int32).at[perm].set(
+            jnp.arange(W, dtype=jnp.int32))
+        return _advance(carry, step, out.dst[inv], out.ts[inv],
+                        has_next_s[inv])
 
 
 def _hop_fused_bucket(index, scfg, sched_cfg, carry: _Carry, step, hop_key,
@@ -792,15 +809,15 @@ def _hop_fused_bucket(index, scfg, sched_cfg, carry: _Carry, step, hop_key,
     from repro.kernels import fused_step as kfused
     lane, s_node, s_time, s_prev, s_alive = _bucket_prologue(
         index, sched_cfg, carry)
-    code, u = _fused_draws(index, scfg, hop_key, lane, lane_bias, lane_u)
-
-    out = kfused.fused_walk_step(index, s_node, s_time, code, u,
-                                 scfg.mode, sched_cfg)
-    has_next_s = s_alive & (out.n > 0)
-    if lane_limit is not None:
-        has_next_s = has_next_s & lane_limit[lane]
-    return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
-                          out.dst, out.ts, has_next_s)
+    with scope("pick"):
+        code, u = _fused_draws(index, scfg, hop_key, lane, lane_bias, lane_u)
+        out = kfused.fused_walk_step(index, s_node, s_time, code, u,
+                                     scfg.mode, sched_cfg)
+        has_next_s = s_alive & (out.n > 0)
+        if lane_limit is not None:
+            has_next_s = has_next_s & lane_limit[lane]
+        return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
+                              out.dst, out.ts, has_next_s)
 
 
 def _advance(carry: _Carry, step, next_node, next_time, has_next) -> _Carry:
@@ -862,113 +879,123 @@ def _generate_walks_impl(index: TemporalIndex, key: jax.Array,
     ``tables`` threads the window's alias tables (bias='table' configs or
     table-coded lanes, DESIGN.md §17); ``second_order`` (static) compiles
     the per-lane node2vec rejection machinery into the lane dispatch.
+    Its device work carries the ``walks`` scope (obs/tracing.py), the
+    start ``start`` and each hop ``hop``.
     """
-    path = sched_cfg.path
-    if lanes is not None:
-        _check_lane_support(wcfg, scfg, sched_cfg, lanes,
-                            tables=tables, second_order=second_order)
-        # one base key; lane streams are derived by fold_in, no split —
-        # the split would make draws depend on batch composition
-        lane_keys = _lane_keys(key, lanes)
-        start_key = walk_key = key
-    else:
-        check_capabilities(scfg, path, have_tables=tables is not None)
-        lane_keys = None
-        start_key, walk_key = jax.random.split(key)
-    carry0 = start_walks(index, wcfg, scfg, start_key,
-                         walk_offset=walk_offset, buffers=buffers,
-                         lanes=lanes, lane_keys=lane_keys)
-    L = wcfg.max_length
-    # number of remaining hops: start already consumed 1 edge in edges-mode
-    hops = L - 1 if wcfg.start_mode == "edges" else L
-
-    bucket = sched_cfg.regroup == "bucket"
-    if sched_cfg.regroup not in ("bucket", "lexsort"):
-        raise ValueError(f"unknown regroup {sched_cfg.regroup!r}")
-    pass_tables = tables if scfg.bias == "table" or lanes is not None \
-        else None
-
-    def hop(carry, step):
-        hop_key = jax.random.fold_in(walk_key, step)
-        write_pos = step + (1 if wcfg.start_mode == "edges" else 0)
+    with scope("walks"):
+        path = sched_cfg.path
         if lanes is not None:
-            # per-lane draw for this hop (tag s+1; tag 0 is the start draw)
-            # and the per-lane budget: column write_pos+1 is written only
-            # while it stays within the lane's own max_len
-            lane_kw = dict(
-                lane_bias=lanes.bias,
-                lane_u=_lane_uniform(lane_keys, step + 1),
-                lane_limit=(write_pos + 1) <= lanes.max_len,
-                tables=pass_tables,
-            )
-            if second_order:
-                # second-order rejection uniforms from the dedicated tag
-                # block (see N2V_TAG_BASE): 2 per round per lane
-                base = N2V_TAG_BASE + step * (2 * N2V_ROUNDS)
-                us2 = jnp.stack([
-                    jnp.stack([_lane_uniform(lane_keys, base + 2 * r),
-                               _lane_uniform(lane_keys, base + 2 * r + 1)])
-                    for r in range(N2V_ROUNDS)])
-                lane_kw["lane_n2v"] = (lanes.n2v_p, lanes.n2v_q, us2)
-        elif scfg.bias == "table":
-            lane_kw = dict(tables=pass_tables)
+            _check_lane_support(wcfg, scfg, sched_cfg, lanes,
+                                tables=tables, second_order=second_order)
+            # one base key; lane streams are derived by fold_in, no split —
+            # the split would make draws depend on batch composition
+            lane_keys = _lane_keys(key, lanes)
+            start_key = walk_key = key
         else:
-            lane_kw = {}
-        if collect_stats:
-            st = sched.dispatch_stats(index, carry.cur_node, carry.alive,
-                                      sched_cfg)
-        else:
-            st = jnp.zeros((sched.NUM_STATS,), jnp.float32)
-        if path == "fullwalk":
-            carry = _hop_fullwalk(index, scfg, carry, write_pos, hop_key,
-                                  **lane_kw)
-        elif path == "grouped":
-            if bucket:
-                carry = _hop_grouped_bucket(index, scfg, sched_cfg, carry,
-                                            write_pos, hop_key, **lane_kw)
-            else:
-                carry = _hop_grouped(index, scfg, carry, write_pos, hop_key,
-                                     **lane_kw)
-        elif path == "tiled":
-            if bucket:
-                carry = _hop_tiled_bucket(index, scfg, sched_cfg, carry,
-                                          write_pos, hop_key)
-            else:
-                carry = _hop_tiled(index, scfg, sched_cfg, carry, write_pos,
-                                   hop_key)
-        elif path == "fused":
-            if bucket:
-                carry = _hop_fused_bucket(index, scfg, sched_cfg, carry,
-                                          write_pos, hop_key, **lane_kw)
-            else:
-                carry = _hop_fused(index, scfg, sched_cfg, carry, write_pos,
-                                   hop_key, **lane_kw)
-        else:
-            raise ValueError(f"unknown scheduler path {path!r}")
-        return carry, st
+            check_capabilities(scfg, path, have_tables=tables is not None)
+            lane_keys = None
+            start_key, walk_key = jax.random.split(key)
+        with scope("start"):
+            carry0 = start_walks(index, wcfg, scfg, start_key,
+                                 walk_offset=walk_offset, buffers=buffers,
+                                 lanes=lanes, lane_keys=lane_keys)
+        L = wcfg.max_length
+        # number of remaining hops: start already consumed 1 edge in edges-mode
+        hops = L - 1 if wcfg.start_mode == "edges" else L
 
-    # Hops run while any lane is alive: a dead lane stays dead, so every
-    # later hop would only write NODE_PAD (and all-zero dispatch stats) —
-    # the fill below. Temporal walks die fast (each hop moves forward in
-    # time), so this skips most of the max_length hops.
-    def cond(state):
-        step, carry, _ = state
-        return (step < hops) & jnp.any(carry.alive)
+        bucket = sched_cfg.regroup == "bucket"
+        if sched_cfg.regroup not in ("bucket", "lexsort"):
+            raise ValueError(f"unknown regroup {sched_cfg.regroup!r}")
+        pass_tables = tables if scfg.bias == "table" or lanes is not None \
+            else None
 
-    def body(state):
-        step, carry, stats = state
-        carry, st = hop(carry, step)
-        return step + 1, carry, stats.at[step].set(st, mode="drop")
+        def hop(carry, step):
+            hop_key = jax.random.fold_in(walk_key, step)
+            write_pos = step + (1 if wcfg.start_mode == "edges" else 0)
+            if lanes is not None:
+                # per-lane draw for this hop (tag s+1; tag 0 is the start draw)
+                # and the per-lane budget: column write_pos+1 is written only
+                # while it stays within the lane's own max_len
+                lane_kw = dict(
+                    lane_bias=lanes.bias,
+                    lane_u=_lane_uniform(lane_keys, step + 1),
+                    lane_limit=(write_pos + 1) <= lanes.max_len,
+                    tables=pass_tables,
+                )
+                if second_order:
+                    # second-order rejection uniforms from the dedicated tag
+                    # block (see N2V_TAG_BASE): 2 per round per lane
+                    base = N2V_TAG_BASE + step * (2 * N2V_ROUNDS)
+                    us2 = jnp.stack([
+                        jnp.stack([_lane_uniform(lane_keys, base + 2 * r),
+                                   _lane_uniform(lane_keys, base + 2 * r + 1)])
+                        for r in range(N2V_ROUNDS)])
+                    lane_kw["lane_n2v"] = (lanes.n2v_p, lanes.n2v_q, us2)
+            elif scfg.bias == "table":
+                lane_kw = dict(tables=pass_tables)
+            else:
+                lane_kw = {}
+            if collect_stats:
+                st = sched.dispatch_stats(index, carry.cur_node, carry.alive,
+                                          sched_cfg)
+            else:
+                st = jnp.zeros((sched.NUM_STATS,), jnp.float32)
+            if path == "fullwalk":
+                carry = _hop_fullwalk(index, scfg, carry, write_pos, hop_key,
+                                      **lane_kw)
+            elif path == "grouped":
+                if bucket:
+                    carry = _hop_grouped_bucket(index, scfg, sched_cfg, carry,
+                                                write_pos, hop_key, **lane_kw)
+                else:
+                    carry = _hop_grouped(index, scfg, carry, write_pos,
+                                         hop_key, **lane_kw)
+            elif path == "tiled":
+                if bucket:
+                    carry = _hop_tiled_bucket(index, scfg, sched_cfg, carry,
+                                              write_pos, hop_key)
+                else:
+                    carry = _hop_tiled(index, scfg, sched_cfg, carry,
+                                       write_pos, hop_key)
+            elif path == "fused":
+                if bucket:
+                    carry = _hop_fused_bucket(index, scfg, sched_cfg, carry,
+                                              write_pos, hop_key, **lane_kw)
+                else:
+                    carry = _hop_fused(index, scfg, sched_cfg, carry,
+                                       write_pos, hop_key, **lane_kw)
+            else:
+                raise ValueError(f"unknown scheduler path {path!r}")
+            return carry, st
 
-    stats0 = jnp.zeros((hops, sched.NUM_STATS), jnp.float32)
-    step, carry, stats = jax.lax.while_loop(
-        cond, body, (jnp.asarray(0, jnp.int32), carry0, stats0))
-    first_unwritten = step + (2 if wcfg.start_mode == "edges" else 1)
-    unwritten = jnp.arange(L + 1, dtype=jnp.int32) >= first_unwritten
-    return WalkResult(nodes=jnp.where(unwritten, NODE_PAD, carry.nodes),
-                      times=jnp.where(unwritten, NODE_PAD, carry.times),
-                      lengths=carry.lengths,
-                      stats=stats if collect_stats else None)
+        # Hops run while any lane is alive: a dead lane stays dead, so every
+        # later hop would only write NODE_PAD (and all-zero dispatch stats) —
+        # the fill below. Temporal walks die fast (each hop moves forward in
+        # time), so this skips most of the max_length hops.
+        def cond(state):
+            step, carry, _ = state
+            return (step < hops) & jnp.any(carry.alive)
+
+        def body(state):
+            step, carry, stats = state
+            with scope("hop"):
+                carry, st = hop(carry, step)
+            return step + 1, carry, stats.at[step].set(st, mode="drop")
+
+        stats0 = jnp.zeros((hops, sched.NUM_STATS), jnp.float32)
+        step, carry, stats = jax.lax.while_loop(
+            cond, body, (jnp.asarray(0, jnp.int32), carry0, stats0))
+        first_unwritten = step + (2 if wcfg.start_mode == "edges" else 1)
+        unwritten = jnp.arange(L + 1, dtype=jnp.int32) >= first_unwritten
+        # Every iteration processes all W lanes, live or not, so the loop's
+        # lane-steps are exactly W × steps and cost nothing to count. A loop
+        # that processes fewer lanes (one that compacts live lanes, say) must
+        # count the lanes it processes instead.
+        return WalkResult(nodes=jnp.where(unwritten, NODE_PAD, carry.nodes),
+                          times=jnp.where(unwritten, NODE_PAD, carry.times),
+                          lengths=carry.lengths,
+                          stats=stats if collect_stats else None,
+                          steps=step)
 
 
 def _check_lane_support(wcfg: WalkConfig, scfg: SamplerConfig,

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from repro.core.alias import AliasTables, TableSpec, build_tables, update_tables
 from repro.core.edge_store import TS_PAD, EdgeBatch, EdgeStore
 from repro.core.temporal_index import TemporalIndex, build_index, empty_index
+from repro.obs.tracing import scope
 
 
 class WindowState(NamedTuple):
@@ -228,20 +229,25 @@ def ingest_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
     new index; clean nodes copy their old table content positionally.
     The spec must be passed on *every* ingest of a table-carrying state —
     omitting it drops the tables from the returned state.
+
+    The store advance and the table update carry the ``advance`` scope,
+    the rebuild ``index`` (obs/tracing.py).
     """
-    adv = _advance_store(state.index.store, state.t_now, state.window,
-                         batch, node_capacity, watermark=watermark)
+    with scope("advance"):
+        adv = _advance_store(state.index.store, state.t_now, state.window,
+                             batch, node_capacity, watermark=watermark)
     new = _finalize(state, adv.store, adv.t_now, adv.late, adv.overflow,
                     batch.count, node_capacity, bias_scale)
     if table is None:
         return new
-    if state.tables is None:
-        tables = build_tables(new.index, table)
-    else:
-        dirty = _dirty_nodes(state, batch, adv, node_capacity)
-        tables = update_tables(new.index, table,
-                               old_starts=state.index.node_starts,
-                               old_tables=state.tables, dirty=dirty)
+    with scope("advance"):
+        if state.tables is None:
+            tables = build_tables(new.index, table)
+        else:
+            dirty = _dirty_nodes(state, batch, adv, node_capacity)
+            tables = update_tables(new.index, table,
+                                   old_starts=state.index.node_starts,
+                                   old_tables=state.tables, dirty=dirty)
     return new._replace(tables=tables)
 
 

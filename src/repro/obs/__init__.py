@@ -9,7 +9,10 @@ Four pieces, one substrate:
   scan carries and ``shard_map`` bodies; flushed to the registry only at
   existing host sync points (zero extra device→host transfers).
 * ``tracing`` — ``span(stage)`` context managers around host pipeline
-  stages, mirrored into XLA profiles via ``TraceAnnotation``.
+  stages, mirrored into XLA profiles via ``TraceAnnotation``; the fixed
+  set of device scopes (``SCOPES``, ``scope``) that name the HLO of the
+  replay, advance, index and walk stages; and the compile listener
+  (``jit_compiles_total``), installed on import.
 * ``export`` — Prometheus text exposition, ``tempest-obs/v1`` JSON
   snapshots, ``tempest-health/v1`` streaming-health dumps, and the
   ``tempest-bench/v1`` schema every ``BENCH_*.json`` artifact shares.
@@ -37,6 +40,7 @@ from repro.obs.probes import (  # noqa: F401
     RP_EXCHANGE_DROPS,
     RP_HOPS,
     RP_LATE_DROPS,
+    RP_LOOP_STEPS,
     RP_OVERFLOW_DROPS,
     RP_WALK_DROPS,
     RP_WALKS_EMITTED,
@@ -49,7 +53,13 @@ from repro.obs.probes import (  # noqa: F401
     replay_probe_zeros,
     serve_probe_zeros,
 )
-from repro.obs.tracing import Span, named_scope, span  # noqa: F401
+from repro.obs.tracing import (  # noqa: F401
+    SCOPES,
+    Span,
+    install_compile_listener,
+    scope,
+    span,
+)
 from repro.obs.export import (  # noqa: F401
     BENCH_SCHEMA,
     HEALTH_SCHEMA,
@@ -63,3 +73,5 @@ from repro.obs.export import (  # noqa: F401
     validate_health,
     validate_snapshot,
 )
+
+install_compile_listener()

@@ -68,7 +68,7 @@ class Reservoir:
     """Bounded ring-buffer sample reservoir (the histogram backing store).
 
     Keeps the most recent ``capacity`` observations in insertion order
-    (oldest first once wrapped); ``count``/``total`` are lifetime
+    (oldest first once wrapped); ``count``/``total``/``max`` are lifetime
     accumulators, unaffected by eviction. Deque-compatible surface
     (``append``/``__len__``/``__iter__``/``__array__``) so it can sit
     behind existing stats fields like ``ServeStats.latencies_s``.
@@ -79,7 +79,7 @@ class Reservoir:
     * q outside [0, 100] -> ``ValueError``
     """
 
-    __slots__ = ("capacity", "_buf", "_idx", "count", "total")
+    __slots__ = ("capacity", "_buf", "_idx", "count", "total", "max")
 
     def __init__(self, capacity: int = RESERVOIR_SIZE):
         if capacity <= 0:
@@ -89,6 +89,7 @@ class Reservoir:
         self._idx = 0
         self.count = 0          # lifetime observations
         self.total = 0.0        # lifetime sum
+        self.max = float("nan")  # lifetime maximum (nan while empty)
 
     def add(self, value: float) -> None:
         v = float(value)
@@ -99,6 +100,8 @@ class Reservoir:
             self._idx = (self._idx + 1) % self.capacity
         self.count += 1
         self.total += v
+        if not v <= self.max:       # also true while max is nan
+            self.max = v
 
     # deque-compatible alias: existing call sites do ``.append(x)``
     append = add
@@ -189,6 +192,12 @@ class Histogram:
     @property
     def sum(self) -> float:
         return self.reservoir.total
+
+    @property
+    def max(self) -> float:
+        """Largest observation since the histogram was created (the
+        registry's ``reset`` drops it); nan before the first."""
+        return self.reservoir.max
 
     def percentile(self, q: float) -> float:
         return self.reservoir.percentile(q)

@@ -31,6 +31,7 @@ from repro.core.walk_engine import (
 )
 from repro.core.window import ingest, ingest_sort, init_window
 from repro.data.synthetic import chronological_batches, powerlaw_temporal_graph
+from repro.obs import new_registry
 
 
 def _assert_states_equal(a, b):
@@ -105,7 +106,8 @@ def _engine(num_nodes=128, edge_capacity=4096, duration=2000, seed=0):
         scheduler=SchedulerConfig(path="grouped"),
         seed=seed,
     )
-    return StreamingEngine(cfg, batch_capacity=1024)
+    return StreamingEngine(cfg, batch_capacity=1024,
+                           registry=new_registry())
 
 
 def test_replay_scan_matches_host_loop():
@@ -212,7 +214,8 @@ def test_ingest_and_walk_donated_chain_matches_separate_dispatches():
 def test_engine_sample_walks_donated_pool():
     """StreamingEngine.sample_walks_donated: identical walks to
     sample_walks for the same seed, per-shape buffer reuse (the previous
-    same-shape result is consumed), and walks_valid recording."""
+    same-shape result is consumed), and the emitted-walk count recorded
+    once per call."""
     g = powerlaw_temporal_graph(64, 3000, seed=9)
     wcfg = WalkConfig(num_walks=128, max_length=6, start_mode="nodes")
     plain = _engine(num_nodes=64, edge_capacity=4096, duration=100_000)
@@ -230,8 +233,13 @@ def test_engine_sample_walks_donated_pool():
                                   np.asarray(b2.nodes))
     with pytest.raises(Exception):
         np.asarray(b1.nodes)
-    assert len(pool.stats.walks_valid) == 2
-    assert all(0.0 <= v <= 1.0 for v in pool.stats.walks_valid)
+    reg = pool.registry
+    assert reg.histogram("stage_seconds",
+                         labels={"stage": "walks.publish"}).count == 2
+    emitted = sum(int(np.sum(np.asarray(a.lengths) >= 2)) for a in (a1, a2))
+    assert 0 < emitted <= 2 * wcfg.num_walks
+    assert reg.value("walks_emitted_total",
+                     labels={"driver": "host"}) == emitted
 
 
 def test_engine_sample_walks_sharded():
@@ -244,7 +252,11 @@ def test_engine_sample_walks_sharded():
     assert res.nodes.shape == (128, 7)
     rep = validate_walks(eng.state.index, res)
     assert float(rep.walk_valid_frac) == 1.0
-    assert len(eng.stats.walks_valid) == 1
+    assert eng.registry.histogram(
+        "stage_seconds", labels={"stage": "walks.publish"}).count == 1
+    assert eng.registry.value("walks_emitted_total",
+                              labels={"driver": "host"}) == int(
+        np.sum(np.asarray(res.lengths) >= 2))
 
 
 def test_replay_scan_walk_lengths_sane():

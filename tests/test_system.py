@@ -17,6 +17,7 @@ from repro.core.streaming import StreamingEngine
 from repro.core.validation import validate_walks
 from repro.data.synthetic import chronological_batches, powerlaw_temporal_graph
 from repro.data.walk_dataset import skipgram_pairs, walks_to_lm_batch
+from repro.obs import new_registry
 from repro.train.embeddings import (
     init_skipgram,
     link_prediction_auc,
@@ -34,23 +35,29 @@ def test_streaming_end_to_end():
         sampler=SamplerConfig(bias="exponential", mode="weight"),
         scheduler=SchedulerConfig(path="grouped"),
     )
-    eng = StreamingEngine(cfg, batch_capacity=4096)
+    reg = new_registry()
+    eng = StreamingEngine(cfg, batch_capacity=4096, registry=reg)
     wcfg = WalkConfig(num_walks=1024, max_length=20, start_mode="nodes")
-    seen_valid = []
+    seen_valid, seen_emitted = [], []
 
     def on_batch(e, walks):
         rep = validate_walks(e.state.index, walks)
         seen_valid.append(float(rep.walk_valid_frac))
+        seen_emitted.append(int(np.sum(np.asarray(walks.lengths) >= 2)))
 
     stats = eng.replay(chronological_batches(g, 8), wcfg, on_batch=on_batch)
     assert len(stats.ingest_s) == 8
     assert all(v == 1.0 for v in seen_valid)           # paper §3.10
     assert int(eng.state.ingested) == 20_000
-    # walks_valid is populated per sampling round (fraction of walks that
-    # advanced at least one hop)
-    assert len(stats.walks_valid) == 8
-    assert all(0.0 <= v <= 1.0 for v in stats.walks_valid)
-    assert stats.walks_valid[-1] > 0.0
+    # every sampling round publishes its emitted walks (walks that advanced
+    # at least one hop) into the registry
+    assert len(seen_emitted) == 8
+    assert reg.histogram("stage_seconds",
+                         labels={"stage": "walks.publish"}).count == 8
+    assert reg.value("walks_emitted_total",
+                     labels={"driver": "host"}) == sum(seen_emitted)
+    assert all(0 <= v <= wcfg.num_walks for v in seen_emitted)
+    assert seen_emitted[-1] > 0
     # bounded memory: active edges never exceed capacity
     assert max(stats.edges_active) <= 1 << 15
 
