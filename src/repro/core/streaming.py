@@ -190,7 +190,7 @@ def _replay_scan_impl(state: WindowState, batches: EdgeBatch, key: jax.Array,
                 ingested_delta=st2.ingested - st.ingested,
                 late_delta=st2.late_drops - st.late_drops,
                 overflow_delta=st2.overflow_drops - st.overflow_drops,
-                lengths=res.lengths, loop_steps=res.steps)
+                lengths=res.lengths, lane_steps=res.lane_steps)
             return (st2, k, nbufs, res.lengths, pv), stats
         return (st2, k, nbufs, res.lengths), stats
 
@@ -478,7 +478,7 @@ class StreamingEngine:
     def _finish_sample(self, res, t0: float, path: str,
                        args: dict) -> float:
         """Shared stats tail of every sample_walks* entry point: sync,
-        fetch the lengths and the hop loop's iteration count in one
+        fetch the lengths and the hop loop's lane-steps in one
         transfer, record wall time, publish into the registry, return the
         elapsed seconds."""
         reg = self.registry
@@ -486,7 +486,8 @@ class StreamingEngine:
             jax.block_until_ready(res.nodes)
         elapsed = time.perf_counter() - t0
         with span("walks.fetch", reg, args=args):
-            lengths, steps = jax.device_get((res.lengths, res.steps))
+            lengths, lane_steps = jax.device_get(
+                (res.lengths, res.lane_steps))
         with span("walks.publish", reg, args=args):
             self.stats.sample_s.append(elapsed)
             emitted = int(np.sum(lengths >= 2))
@@ -499,8 +500,8 @@ class StreamingEngine:
             reg.inc("walk_hops_total",
                     int(np.sum(np.maximum(lengths.astype(np.int64) - 1, 0))),
                     labels={"source": path}, help="hop cells executed")
-            if steps is not None:
-                reg.inc("walk_lane_steps_total", lengths.size * int(steps),
+            if lane_steps is not None:
+                reg.inc("walk_lane_steps_total", int(lane_steps),
                         labels={"source": path},
                         help="lanes processed by the hop loop, live or not")
         return elapsed
@@ -551,8 +552,7 @@ class StreamingEngine:
                 (stats, pv, walks if return_walks else None))
         with span("replay.publish", reg, args=args):
             if self.probes:
-                flush_replay_probes(reg, pv, driver="device",
-                                    lanes=wcfg.num_walks)
+                flush_replay_probes(reg, pv, driver="device")
                 reg.observe("replay_seconds", elapsed,
                             labels={"driver": "device"},
                             help="wall time per replay_device call")

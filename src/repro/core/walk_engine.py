@@ -34,7 +34,9 @@ hops** in the walk state — lanes stay in grouped order and only the
 lane→walk map is tracked, so no scatter-built inverse permutation is paid
 per hop. ``lexsort`` keeps
 the seed's per-hop ``jnp.lexsort`` + inverse scatter as the
-equivalence/benchmark reference.
+equivalence/benchmark reference. Since the bucket regroup sorts dead lanes
+last, the grouped path's hop loop narrows to its live lanes in a static
+ladder of quartering widths as its walks end (``_tier_widths``).
 
 All paths and regroup modes produce **identical walks for identical keys**
 (tested): random draws are generated in original walk order and indexed
@@ -246,20 +248,24 @@ class WalkResult(NamedTuple):
     times: jax.Array     # int32[W, L+1]
     lengths: jax.Array   # int32[W] number of nodes recorded (>=1)
     stats: Optional[jax.Array]   # float32[L, sched.NUM_STATS] or None
-    # int32 scalar: iterations the hop loop ran (None where the producer
-    # does not report it); every iteration processes all W lanes
+    # int32 scalars (None where the producer does not report them): the
+    # iterations the hop loop ran, and the lanes it processed over them —
+    # the sum of each iteration's width, W × steps on a one-width loop
     steps: Optional[jax.Array] = None
+    lane_steps: Optional[jax.Array] = None
 
 
 class WalkBuffers(NamedTuple):
     """Reusable walk output buffers (donated through the jit boundary).
 
-    Holds the two O(W·L) arrays of a WalkResult. The walk loop overwrites
-    *every* cell (the start writes column 0, and each hop writes its column
-    for all W lanes, PAD for non-advancing walks), so the previous round's
-    contents are dead on entry: the donated storage flows straight into the
-    scan carry and XLA updates it in place — steady-state walk generation
-    allocates only the [W] lengths vector (DESIGN.md §10).
+    Holds the two O(W·L) arrays of a WalkResult. The previous round's
+    contents are dead on entry: the walk loop writes every cell a walk
+    records (the start writes its first columns, each hop its column for
+    the lanes the loop still processes), and the result masks every cell
+    at a column ≥ the walk's own length to NODE_PAD, stale ones included.
+    So the donated storage flows straight into the loop carry and XLA
+    updates it in place — steady-state walk generation allocates only the
+    [W] lengths vector (DESIGN.md §10).
     """
 
     nodes: jax.Array     # int32[W, L+1]
@@ -316,10 +322,12 @@ def _lane_uniform(lane_keys: jax.Array, tag) -> jax.Array:
 
 
 class _Carry(NamedTuple):
-    # cur_node/cur_time/prev_node/alive are in *lane* order; ``lane`` maps
-    # lane -> original walk id (identity for fullwalk/lexsort, the carried
-    # bucket-regroup permutation otherwise). nodes/times/lengths stay in
-    # walk order throughout.
+    # cur_node/cur_time/prev_node/alive are in *lane* order, one entry per
+    # lane the loop processes (fewer than W on the narrowed tiers of the
+    # grouped-bucket loop); ``lane`` maps lane -> original walk id
+    # (identity for fullwalk/lexsort, the carried bucket-regroup
+    # permutation otherwise). nodes/times/lengths stay in walk order, W
+    # rows, throughout.
     cur_node: jax.Array
     cur_time: jax.Array
     prev_node: jax.Array
@@ -504,94 +512,116 @@ def _lane_second_order(index, scfg, tables, lane_bias, a, c, b, prev,
     return jnp.where(is_n2v, k_rej, k_plain)
 
 
-def _sample_hop(index: TemporalIndex, scfg: SamplerConfig,
-                cur_node, cur_time, prev_node, alive, hop_key,
-                lane_bias=None, lane_u=None, tables=None, lane_n2v=None):
-    """Given per-walk (node, time), returns (next_node, next_time, has_next).
+class _Draws(NamedTuple):
+    """One hop's draw inputs for a set of lanes, in that lane order.
 
-    Pure sampling logic shared by every path; callers control the layout.
-    With ``lane_bias``/``lane_u`` (walk-order arrays, DESIGN.md §11) the
-    draw is the caller-supplied per-lane uniform and the bias dispatches
-    per lane; ``tables`` threads the alias tables for table-coded lanes
-    (or config bias='table'); ``lane_n2v`` carries per-lane second-order
-    parameters (see ``_lane_second_order``).
+    ``u`` is each lane's first-order uniform; ``us`` takes its place for
+    the config-level node2vec rounds ([N2V_ROUNDS, 2, lanes]). A lane batch
+    (DESIGN.md §11) adds its bias codes, ``limit`` (the lane's budget
+    allows this hop's column) and, with second-order lanes, ``n2v = (p, q,
+    us2)``. Every entry is a function of the lane's walk id alone (see
+    ``draws`` in ``_generate_walks_impl``), which is what makes every
+    layout and every loop width emit identical walks for identical keys.
     """
-    W = cur_node.shape[0]
-    a, b = node_range(index, cur_node)
-    c = temporal_cutoff(index, a, b, cur_time)
-    n = b - c
-    has_next = alive & (n > 0)
 
+    u: Optional[jax.Array] = None
+    us: Optional[jax.Array] = None
+    bias: Optional[jax.Array] = None
+    limit: Optional[jax.Array] = None
+    n2v: Optional[tuple] = None
+
+
+def _draw_pick(index, scfg, c, b, s_node, s_prev, d: _Draws, tables=None):
+    """Sample positions k ∈ [c, b) for lanes at ``s_node`` (previous node
+    ``s_prev``) from their draws ``d``, given in the same lane order.
+    ``tables`` threads the alias tables for table-coded lanes (or config
+    bias='table')."""
     use_n2v = (scfg.node2vec_p != 1.0) or (scfg.node2vec_q != 1.0)
-    if lane_u is not None:
-        k = _pick_lane_codes(index, scfg, tables, lane_bias, a, c, b,
-                             lane_u)
-        if lane_n2v is not None:
-            k = _lane_second_order(index, scfg, tables, lane_bias, a, c, b,
-                                   prev_node, k, lane_n2v)
+    if tables is not None or d.n2v is not None:
+        a, _ = node_range(index, s_node)
+    else:
+        a = None
+    if d.bias is not None:
+        k = _pick_lane_codes(index, scfg, tables, d.bias, a, c, b, d.u)
+        if d.n2v is not None:
+            k = _lane_second_order(index, scfg, tables, d.bias, a, c, b,
+                                   s_prev, k, d.n2v)
     elif not use_n2v:
-        u = jax.random.uniform(hop_key, (W,))
-        k = _pick_config(index, scfg, tables, a, c, b, u, cur_node)
+        k = _pick_config(index, scfg, tables, a, c, b, d.u, s_node)
     else:
         # rejection sampling on the first-order proposal (paper §2.5)
         beta_max = node2vec_max_beta(scfg.node2vec_p, scfg.node2vec_q)
-        us = jax.random.uniform(hop_key, (N2V_ROUNDS, 2, W))
 
-        def round_(carry, uv):
-            k_acc, accepted = carry
+        def round_(carry_, uv):
+            k_acc, accepted = carry_
             u_r, v_r = uv[0], uv[1]
-            k_r = _pick_config(index, scfg, tables, a, c, b, u_r, cur_node)
+            k_r = _pick_config(index, scfg, tables, a, c, b, u_r, s_node)
             cand = index.ns_dst[jnp.clip(k_r, 0, index.edge_capacity - 1)]
-            beta = node2vec_beta(index, prev_node, cand,
+            beta = node2vec_beta(index, s_prev, cand,
                                  scfg.node2vec_p, scfg.node2vec_q)
             # hops with no previous node accept unconditionally
-            ok = (v_r * beta_max <= beta) | (prev_node < 0)
+            ok = (v_r * beta_max <= beta) | (s_prev < 0)
             take = ok & ~accepted
             return (jnp.where(take, k_r, k_acc), accepted | ok), None
 
-        u0 = us[0, 0]
-        k0 = _pick_config(index, scfg, tables, a, c, b, u0, cur_node)
-        (k, _), _ = jax.lax.scan(round_, (k0, jnp.zeros((W,), bool)), us)
+        k0 = _pick_config(index, scfg, tables, a, c, b, d.us[0, 0], s_node)
+        (k, _), _ = jax.lax.scan(round_, (k0, jnp.zeros(k0.shape, bool)),
+                                 d.us)
 
-    k = jnp.clip(k, 0, index.edge_capacity - 1)
-    next_node = index.ns_dst[k]
-    next_time = index.ns_ts[k]
-    return next_node, next_time, has_next, (a, b, c)
+    return jnp.clip(k, 0, index.edge_capacity - 1)
 
 
-def _hop_fullwalk(index, scfg, carry: _Carry, step: jax.Array,
-                  hop_key, lane_bias=None, lane_u=None,
-                  lane_limit=None, tables=None, lane_n2v=None) -> _Carry:
+def _sample_hop(index: TemporalIndex, scfg: SamplerConfig,
+                cur_node, cur_time, prev_node, alive, d: _Draws,
+                tables=None):
+    """Given per-lane (node, time) and the lanes' draws, returns
+    (next_node, next_time, has_next).
+
+    Pure sampling logic shared by every jnp path; callers control the
+    layout. Every lane computes its own cutoff Γ_t(v) = [c, b) (a
+    vectorized search), so *any* lane permutation is correct; lanes of one
+    (node, time) segment compute the same value, and grouping them only
+    improves gather locality.
+    """
+    a, b = node_range(index, cur_node)
+    c = temporal_cutoff(index, a, b, cur_time)
+    has_next = alive & (b - c > 0)
+    if d.limit is not None:
+        has_next = has_next & d.limit
+    k = _draw_pick(index, scfg, c, b, cur_node, prev_node, d, tables)
+    return index.ns_dst[k], index.ns_ts[k], has_next
+
+
+def _hop_fullwalk(index, scfg, sched_cfg, carry: _Carry, step, draws,
+                  tables=None) -> _Carry:
     with scope("pick"):
-        nn, nt, has_next, _ = _sample_hop(
+        nn, nt, has_next = _sample_hop(
             index, scfg, carry.cur_node, carry.cur_time, carry.prev_node,
-            carry.alive, hop_key, lane_bias=lane_bias, lane_u=lane_u,
-            tables=tables, lane_n2v=lane_n2v)
-        if lane_limit is not None:
-            has_next = has_next & lane_limit
+            carry.alive, draws(None), tables)
         return _advance(carry, step, nn, nt, has_next)
 
 
 # ---------------------------------------------------------------------------
-# Grouped layouts: shared segment cutoff + draw/pick helpers
+# Grouped layouts: lanes regrouped by (node, time) each hop
 # ---------------------------------------------------------------------------
 
 
-def _segment_cutoff(index: TemporalIndex, s_node, s_time):
-    """(b, c) for lanes grouped by (node, time): Γ_t(v) = [c, b) per lane.
-
-    Every lane computes its own cutoff (a vectorized search), so *any* lane
-    permutation is correct; lanes of one (node, time) segment compute the
-    same value, and grouping them only improves gather locality.
-    """
-    a, b = node_range(index, s_node)
-    return b, temporal_cutoff(index, a, b, s_time)
+def _lexsort_prologue(index: TemporalIndex, carry: _Carry):
+    """Reference regroup: a fresh lexsort by (node, time), dead lanes last.
+    Returns the permutation (lane -> walk id) and the permuted state."""
+    with scope("regroup"):
+        node_key = jnp.where(carry.alive, carry.cur_node,
+                             index.node_capacity + 1)
+        perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
+        return (perm, carry.cur_node[perm], carry.cur_time[perm],
+                carry.prev_node[perm], carry.alive[perm])
 
 
 def _bucket_prologue(index: TemporalIndex, sched_cfg, carry: _Carry):
     """Regroup lanes by current node (DESIGN.md §10) and permute the walk
-    state; shared by the grouped and tiled bucket hops. Returns the
-    composed lane→walk map plus the permuted per-lane state."""
+    state; shared by the grouped, tiled and fused bucket hops. Dead lanes
+    sort last, so the live lanes are the prefix of the new order. Returns
+    the composed lane→walk map plus the permuted per-lane state."""
     nc = index.node_capacity
     with scope("regroup"):
         node_key = jnp.where(carry.alive, carry.cur_node, nc + 1)
@@ -601,140 +631,51 @@ def _bucket_prologue(index: TemporalIndex, sched_cfg, carry: _Carry):
                 carry.prev_node[pp], carry.alive[pp])
 
 
-def _draw_pick(index, scfg, hop_key, c, b, s_node, s_prev, order,
-               lane_bias=None, lane_u=None, tables=None, lane_n2v=None):
-    """Sample positions k ∈ [c, b) for grouped lanes.
-
-    ``order`` maps lane -> original walk id; draws are generated in walk-id
-    order and indexed through it, which is what makes every layout emit
-    identical walks for identical keys. ``lane_bias``/``lane_u`` and the
-    ``lane_n2v`` arrays are walk-order per-lane arrays (DESIGN.md §11),
-    indexed through ``order`` the same way.
-    """
-    W = s_node.shape[0]
-    use_n2v = (scfg.node2vec_p != 1.0) or (scfg.node2vec_q != 1.0)
-    if tables is not None or lane_n2v is not None:
-        a, _ = node_range(index, s_node)
-    else:
-        a = None
-    if lane_u is not None:
-        k = _pick_lane_codes(index, scfg, tables, lane_bias[order], a, c, b,
-                             lane_u[order])
-        if lane_n2v is not None:
-            p, q, us2 = lane_n2v
-            k = _lane_second_order(index, scfg, tables, lane_bias[order],
-                                   a, c, b, s_prev, k,
-                                   (p[order], q[order], us2[:, :, order]))
-    elif not use_n2v:
-        u = jax.random.uniform(hop_key, (W,))[order]
-        k = _pick_config(index, scfg, tables, a, c, b, u, s_node)
-    else:
-        beta_max = node2vec_max_beta(scfg.node2vec_p, scfg.node2vec_q)
-        us = jax.random.uniform(hop_key, (N2V_ROUNDS, 2, W))[:, :, order]
-
-        def round_(carry_, uv):
-            k_acc, accepted = carry_
-            u_r, v_r = uv[0], uv[1]
-            k_r = _pick_config(index, scfg, tables, a, c, b, u_r, s_node)
-            cand = index.ns_dst[jnp.clip(k_r, 0, index.edge_capacity - 1)]
-            beta = node2vec_beta(index, s_prev, cand,
-                                 scfg.node2vec_p, scfg.node2vec_q)
-            ok = (v_r * beta_max <= beta) | (s_prev < 0)
-            take = ok & ~accepted
-            return (jnp.where(take, k_r, k_acc), accepted | ok), None
-
-        k0 = _pick_config(index, scfg, tables, a, c, b, us[0, 0], s_node)
-        (k, _), _ = jax.lax.scan(round_, (k0, jnp.zeros((W,), bool)), us)
-
-    return jnp.clip(k, 0, index.edge_capacity - 1)
-
-
-def _hop_grouped(index, scfg, carry: _Carry, step: jax.Array,
-                 hop_key, lane_bias=None, lane_u=None,
-                 lane_limit=None, tables=None, lane_n2v=None) -> _Carry:
+def _hop_grouped(index, scfg, sched_cfg, carry: _Carry, step, draws,
+                 tables=None) -> _Carry:
     """Reference regroup: fresh lexsort by (node, time) + inverse scatter."""
-    W = carry.cur_node.shape[0]
-    nc = index.node_capacity
-    with scope("regroup"):
-        node_key = jnp.where(carry.alive, carry.cur_node, nc + 1)
-        perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
-
-        s_node = carry.cur_node[perm]
-        s_time = carry.cur_time[perm]
-        s_prev = carry.prev_node[perm]
-        s_alive = carry.alive[perm]
-
+    perm, s_node, s_time, s_prev, s_alive = _lexsort_prologue(index, carry)
     with scope("pick"):
-        b, c = _segment_cutoff(index, s_node, s_time)
-        has_next_s = s_alive & (b - c > 0)
-        if lane_limit is not None:
-            has_next_s = has_next_s & lane_limit[perm]
-
-        k = _draw_pick(index, scfg, hop_key, c, b, s_node, s_prev, perm,
-                       lane_bias=lane_bias, lane_u=lane_u, tables=tables,
-                       lane_n2v=lane_n2v)
-        nn_s = index.ns_dst[k]
-        nt_s = index.ns_ts[k]
-
-        # unsort back to original walk order
-        inv = jnp.zeros((W,), jnp.int32).at[perm].set(
-            jnp.arange(W, dtype=jnp.int32))
-        return _advance(carry, step, nn_s[inv], nt_s[inv], has_next_s[inv])
+        nn_s, nt_s, has_next_s = _sample_hop(
+            index, scfg, s_node, s_time, s_prev, s_alive, draws(perm), tables)
+        return _advance_unsorted(carry, step, perm, nn_s, nt_s, has_next_s)
 
 
-def _hop_grouped_bucket(index, scfg, sched_cfg, carry: _Carry,
-                        step: jax.Array, hop_key, lane_bias=None,
-                        lane_u=None, lane_limit=None, tables=None,
-                        lane_n2v=None) -> _Carry:
-    """O(W) counting regroup with carried permutation (DESIGN.md §10).
+def _hop_grouped_bucket(index, scfg, sched_cfg, carry: _Carry, step, draws,
+                        tables=None) -> _Carry:
+    """Node-sort regroup with carried permutation (DESIGN.md §10).
 
     Lanes stay in grouped order across hops — the regroup permutes the
     *previous* lane layout (walks keep near-sorted order naturally, since a
     segment's members scatter over one node's neighbor list) and composes
-    into ``carry.lane``; no inverse permutation is ever built.
+    into ``carry.lane``; no inverse permutation is ever built. It runs at
+    the carry's width, which the loop narrows in tiers.
     """
     lane, s_node, s_time, s_prev, s_alive = _bucket_prologue(
         index, sched_cfg, carry)
-
     with scope("pick"):
-        b, c = _segment_cutoff(index, s_node, s_time)
-        has_next_s = s_alive & (b - c > 0)
-        if lane_limit is not None:
-            has_next_s = has_next_s & lane_limit[lane]
-
-        k = _draw_pick(index, scfg, hop_key, c, b, s_node, s_prev, lane,
-                       lane_bias=lane_bias, lane_u=lane_u, tables=tables,
-                       lane_n2v=lane_n2v)
+        nn, nt, has_next_s = _sample_hop(
+            index, scfg, s_node, s_time, s_prev, s_alive, draws(lane), tables)
         return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
-                              index.ns_dst[k], index.ns_ts[k], has_next_s)
+                              nn, nt, has_next_s)
 
 
-def _hop_tiled(index, scfg, sched_cfg, carry: _Carry, step, hop_key) -> _Carry:
+def _hop_tiled(index, scfg, sched_cfg, carry: _Carry, step, draws,
+               tables=None) -> _Carry:
     """Lexsort layout with the Pallas kernel executing search+sample."""
     from repro.kernels import ops as kops
-    W = carry.cur_node.shape[0]
-    with scope("regroup"):
-        node_key = jnp.where(carry.alive, carry.cur_node,
-                             index.node_capacity + 1)
-        perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
-        s_node = carry.cur_node[perm]
-        s_time = carry.cur_time[perm]
-        s_alive = carry.alive[perm]
-
+    perm, s_node, s_time, _, s_alive = _lexsort_prologue(index, carry)
     with scope("pick"):
-        u = jax.random.uniform(hop_key, (W,))[perm]
-        k, n = kops.walk_step(index, s_node, s_time, u, scfg, sched_cfg)
+        k, n = kops.walk_step(index, s_node, s_time, draws(perm).u, scfg,
+                              sched_cfg)
         has_next_s = s_alive & (n > 0)
         k = jnp.clip(k, 0, index.edge_capacity - 1)
-        nn_s = index.ns_dst[k]
-        nt_s = index.ns_ts[k]
-        inv = jnp.zeros((W,), jnp.int32).at[perm].set(
-            jnp.arange(W, dtype=jnp.int32))
-        return _advance(carry, step, nn_s[inv], nt_s[inv], has_next_s[inv])
+        return _advance_unsorted(carry, step, perm, index.ns_dst[k],
+                                 index.ns_ts[k], has_next_s)
 
 
-def _hop_tiled_bucket(index, scfg, sched_cfg, carry: _Carry, step,
-                      hop_key) -> _Carry:
+def _hop_tiled_bucket(index, scfg, sched_cfg, carry: _Carry, step, draws,
+                      tables=None) -> _Carry:
     """Bucket-regrouped layout feeding the Pallas kernel (DESIGN.md §10).
 
     The regroup yields an exact node sort, which is all the tile/task-table
@@ -744,80 +685,74 @@ def _hop_tiled_bucket(index, scfg, sched_cfg, carry: _Carry, step,
     lane, s_node, s_time, s_prev, s_alive = _bucket_prologue(
         index, sched_cfg, carry)
     with scope("pick"):
-        u = jax.random.uniform(hop_key, (carry.cur_node.shape[0],))[lane]
-        k, n = kops.walk_step(index, s_node, s_time, u, scfg, sched_cfg)
+        k, n = kops.walk_step(index, s_node, s_time, draws(lane).u, scfg,
+                              sched_cfg)
         has_next_s = s_alive & (n > 0)
         k = jnp.clip(k, 0, index.edge_capacity - 1)
         return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
                               index.ns_dst[k], index.ns_ts[k], has_next_s)
 
 
-def _fused_draws(index, scfg, hop_key, order, lane_bias, lane_u):
-    """Per-lane (bias code, uniform) for the fused kernel, in lane order.
-
-    Draws are generated in walk order and indexed through ``order`` —
-    the same layout-independence rule as ``_draw_pick``.
-    """
+def _fused_code(scfg: SamplerConfig, d: _Draws) -> jax.Array:
+    """Per-lane bias codes for the fused kernel: the lane batch's own, else
+    the config bias on every lane."""
     from repro.core.samplers import bias_code
-    W = order.shape[0]
-    if lane_u is not None:
-        return lane_bias[order], lane_u[order]
-    code = jnp.full((W,), bias_code(scfg.bias), jnp.int32)
-    return code, jax.random.uniform(hop_key, (W,))[order]
+    if d.bias is not None:
+        return d.bias
+    return jnp.full(d.u.shape, bias_code(scfg.bias), jnp.int32)
 
 
-def _hop_fused(index, scfg, sched_cfg, carry: _Carry, step, hop_key,
-               lane_bias=None, lane_u=None, lane_limit=None, tables=None,
-               lane_n2v=None) -> _Carry:
+def _hop_fused(index, scfg, sched_cfg, carry: _Carry, step, draws,
+               tables=None) -> _Carry:
     """Lexsort layout feeding the fused convergence-tiered kernel.
 
-    ``tables``/``lane_n2v`` are always None here — check_capabilities
+    ``tables`` and second-order draws never reach it — check_capabilities
     refuses table-bias and second-order batches on the fused path.
     """
     from repro.kernels import fused_step as kfused
-    W = carry.cur_node.shape[0]
-    with scope("regroup"):
-        node_key = jnp.where(carry.alive, carry.cur_node,
-                             index.node_capacity + 1)
-        perm = jnp.lexsort((carry.cur_time, node_key)).astype(jnp.int32)
-        s_node = carry.cur_node[perm]
-        s_time = carry.cur_time[perm]
-        s_alive = carry.alive[perm]
-
+    perm, s_node, s_time, _, s_alive = _lexsort_prologue(index, carry)
     with scope("pick"):
-        code, u = _fused_draws(index, scfg, hop_key, perm, lane_bias, lane_u)
-        out = kfused.fused_walk_step(index, s_node, s_time, code, u,
-                                     scfg.mode, sched_cfg)
+        d = draws(perm)
+        out = kfused.fused_walk_step(index, s_node, s_time,
+                                     _fused_code(scfg, d), d.u, scfg.mode,
+                                     sched_cfg)
         has_next_s = s_alive & (out.n > 0)
-        if lane_limit is not None:
-            has_next_s = has_next_s & lane_limit[perm]
-        inv = jnp.zeros((W,), jnp.int32).at[perm].set(
-            jnp.arange(W, dtype=jnp.int32))
-        return _advance(carry, step, out.dst[inv], out.ts[inv],
-                        has_next_s[inv])
+        if d.limit is not None:
+            has_next_s = has_next_s & d.limit
+        return _advance_unsorted(carry, step, perm, out.dst, out.ts,
+                                 has_next_s)
 
 
-def _hop_fused_bucket(index, scfg, sched_cfg, carry: _Carry, step, hop_key,
-                      lane_bias=None, lane_u=None, lane_limit=None,
-                      tables=None, lane_n2v=None) -> _Carry:
+def _hop_fused_bucket(index, scfg, sched_cfg, carry: _Carry, step, draws,
+                      tables=None) -> _Carry:
     """Bucket-regrouped layout feeding the fused kernel (DESIGN.md §14).
 
     The kernel returns the gathered dst/ts directly — the hop issues no
     edge-array gathers at all, unlike ``_hop_tiled_bucket``.
-    ``tables``/``lane_n2v`` are always None here (see ``_hop_fused``).
+    ``tables`` is always None here (see ``_hop_fused``).
     """
     from repro.kernels import fused_step as kfused
     lane, s_node, s_time, s_prev, s_alive = _bucket_prologue(
         index, sched_cfg, carry)
     with scope("pick"):
-        code, u = _fused_draws(index, scfg, hop_key, lane, lane_bias, lane_u)
-        out = kfused.fused_walk_step(index, s_node, s_time, code, u,
-                                     scfg.mode, sched_cfg)
+        d = draws(lane)
+        out = kfused.fused_walk_step(index, s_node, s_time,
+                                     _fused_code(scfg, d), d.u, scfg.mode,
+                                     sched_cfg)
         has_next_s = s_alive & (out.n > 0)
-        if lane_limit is not None:
-            has_next_s = has_next_s & lane_limit[lane]
+        if d.limit is not None:
+            has_next_s = has_next_s & d.limit
         return _advance_lanes(carry, lane, step, s_node, s_time, s_prev,
                               out.dst, out.ts, has_next_s)
+
+
+# The hop variant of each path, by regroup: (lexsort, bucket).
+_HOPS = {
+    "fullwalk": (_hop_fullwalk, _hop_fullwalk),
+    "grouped": (_hop_grouped, _hop_grouped_bucket),
+    "tiled": (_hop_tiled, _hop_tiled_bucket),
+    "fused": (_hop_fused, _hop_fused_bucket),
+}
 
 
 def _advance(carry: _Carry, step, next_node, next_time, has_next) -> _Carry:
@@ -837,6 +772,17 @@ def _advance(carry: _Carry, step, next_node, next_time, has_next) -> _Carry:
         nodes=nodes, times=times,
         lengths=carry.lengths + has_next.astype(jnp.int32),
     )
+
+
+def _advance_unsorted(carry: _Carry, step, perm, next_node, next_time,
+                      has_next) -> _Carry:
+    """Advance lanes laid out by ``perm`` (lane -> walk id) back in walk
+    order, through the inverse permutation (lexsort paths)."""
+    W = perm.shape[0]
+    inv = jnp.zeros((W,), jnp.int32).at[perm].set(
+        jnp.arange(W, dtype=jnp.int32))
+    return _advance(carry, step, next_node[inv], next_time[inv],
+                    has_next[inv])
 
 
 def _advance_lanes(carry: _Carry, lane, step, s_node, s_time, s_prev,
@@ -865,6 +811,35 @@ def _advance_lanes(carry: _Carry, lane, step, s_node, s_time, s_prev,
 # ---------------------------------------------------------------------------
 
 
+def _tier_widths(num_walks: int) -> tuple:
+    """Loop widths of the grouped-bucket hop loop (DESIGN.md §10): W, W/4,
+    W/16 while the next width is a multiple of 128 and at least
+    max(W/32, 128), so at most three tiers; one tier, W, where W is not a
+    multiple of 512. Each tier is one more copy of the hop body to trace,
+    lower and load, so the ladder quarters rather than halves."""
+    widths = [num_walks]
+    floor = max(num_walks // 32, 128)
+    while widths[-1] % 512 == 0 and widths[-1] // 4 >= floor:
+        widths.append(widths[-1] // 4)
+    return tuple(widths)
+
+
+def _compact_lanes(carry: _Carry, width: int) -> _Carry:
+    """The tier handover: a stable partition of the lanes on ``alive``
+    (live lanes first, their grouped order kept), cut to ``width`` lanes.
+    The caller narrows only once the live lanes fit, and a dead lane never
+    revives, so the lanes cut off would only have written NODE_PAD."""
+    n = carry.alive.shape[0]
+    _, perm = jax.lax.sort(
+        ((~carry.alive).astype(jnp.int32), jnp.arange(n, dtype=jnp.int32)),
+        num_keys=1, is_stable=True)
+    perm = perm[:width]
+    return carry._replace(
+        cur_node=carry.cur_node[perm], cur_time=carry.cur_time[perm],
+        prev_node=carry.prev_node[perm], alive=carry.alive[perm],
+        lane=carry.lane[perm])
+
+
 def _generate_walks_impl(index: TemporalIndex, key: jax.Array,
                          wcfg: WalkConfig, scfg: SamplerConfig,
                          sched_cfg: SchedulerConfig,
@@ -884,6 +859,10 @@ def _generate_walks_impl(index: TemporalIndex, key: jax.Array,
     """
     with scope("walks"):
         path = sched_cfg.path
+        if path not in _HOPS:
+            raise ValueError(f"unknown scheduler path {path!r}")
+        if sched_cfg.regroup not in ("bucket", "lexsort"):
+            raise ValueError(f"unknown regroup {sched_cfg.regroup!r}")
         if lanes is not None:
             _check_lane_support(wcfg, scfg, sched_cfg, lanes,
                                 tables=tables, second_order=second_order)
@@ -899,103 +878,107 @@ def _generate_walks_impl(index: TemporalIndex, key: jax.Array,
             carry0 = start_walks(index, wcfg, scfg, start_key,
                                  walk_offset=walk_offset, buffers=buffers,
                                  lanes=lanes, lane_keys=lane_keys)
-        L = wcfg.max_length
+        W, L = wcfg.num_walks, wcfg.max_length
+        edges = wcfg.start_mode == "edges"
         # number of remaining hops: start already consumed 1 edge in edges-mode
-        hops = L - 1 if wcfg.start_mode == "edges" else L
+        hops = L - 1 if edges else L
 
         bucket = sched_cfg.regroup == "bucket"
-        if sched_cfg.regroup not in ("bucket", "lexsort"):
-            raise ValueError(f"unknown regroup {sched_cfg.regroup!r}")
+        hop_variant = _HOPS[path][bucket]
         pass_tables = tables if scfg.bias == "table" or lanes is not None \
             else None
+        use_n2v = (scfg.node2vec_p != 1.0) or (scfg.node2vec_q != 1.0)
 
-        def hop(carry, step):
+        def draws_at(step, write_pos):
+            """The hop's draws as a function of the lanes it processes:
+            ``draws(order)`` for lanes whose walk ids are ``order`` (None:
+            all W, in walk order). A config-level walk draws at full width
+            and indexes by walk id; a lane batch draws per lane from
+            (request seed, walk id, tag s+1), tag 0 being the start draw,
+            and column write_pos+1 is written only while it stays within
+            the lane's own max_len."""
             hop_key = jax.random.fold_in(walk_key, step)
-            write_pos = step + (1 if wcfg.start_mode == "edges" else 0)
-            if lanes is not None:
-                # per-lane draw for this hop (tag s+1; tag 0 is the start draw)
-                # and the per-lane budget: column write_pos+1 is written only
-                # while it stays within the lane's own max_len
-                lane_kw = dict(
-                    lane_bias=lanes.bias,
-                    lane_u=_lane_uniform(lane_keys, step + 1),
-                    lane_limit=(write_pos + 1) <= lanes.max_len,
-                    tables=pass_tables,
-                )
+
+            def draws(order):
+                def sel(x):
+                    return x if order is None else x[..., order]
+
+                if lanes is None:
+                    if use_n2v:
+                        return _Draws(us=sel(jax.random.uniform(
+                            hop_key, (N2V_ROUNDS, 2, W))))
+                    return _Draws(u=sel(jax.random.uniform(hop_key, (W,))))
+                keys = lane_keys if order is None else lane_keys[order]
+                d = _Draws(u=_lane_uniform(keys, step + 1),
+                           bias=sel(lanes.bias),
+                           limit=(write_pos + 1) <= sel(lanes.max_len))
                 if second_order:
                     # second-order rejection uniforms from the dedicated tag
                     # block (see N2V_TAG_BASE): 2 per round per lane
                     base = N2V_TAG_BASE + step * (2 * N2V_ROUNDS)
                     us2 = jnp.stack([
-                        jnp.stack([_lane_uniform(lane_keys, base + 2 * r),
-                                   _lane_uniform(lane_keys, base + 2 * r + 1)])
+                        jnp.stack([_lane_uniform(keys, base + 2 * r),
+                                   _lane_uniform(keys, base + 2 * r + 1)])
                         for r in range(N2V_ROUNDS)])
-                    lane_kw["lane_n2v"] = (lanes.n2v_p, lanes.n2v_q, us2)
-            elif scfg.bias == "table":
-                lane_kw = dict(tables=pass_tables)
-            else:
-                lane_kw = {}
-            if collect_stats:
-                st = sched.dispatch_stats(index, carry.cur_node, carry.alive,
-                                          sched_cfg)
-            else:
-                st = jnp.zeros((sched.NUM_STATS,), jnp.float32)
-            if path == "fullwalk":
-                carry = _hop_fullwalk(index, scfg, carry, write_pos, hop_key,
-                                      **lane_kw)
-            elif path == "grouped":
-                if bucket:
-                    carry = _hop_grouped_bucket(index, scfg, sched_cfg, carry,
-                                                write_pos, hop_key, **lane_kw)
-                else:
-                    carry = _hop_grouped(index, scfg, carry, write_pos,
-                                         hop_key, **lane_kw)
-            elif path == "tiled":
-                if bucket:
-                    carry = _hop_tiled_bucket(index, scfg, sched_cfg, carry,
-                                              write_pos, hop_key)
-                else:
-                    carry = _hop_tiled(index, scfg, sched_cfg, carry,
-                                       write_pos, hop_key)
-            elif path == "fused":
-                if bucket:
-                    carry = _hop_fused_bucket(index, scfg, sched_cfg, carry,
-                                              write_pos, hop_key, **lane_kw)
-                else:
-                    carry = _hop_fused(index, scfg, sched_cfg, carry,
-                                       write_pos, hop_key, **lane_kw)
-            else:
-                raise ValueError(f"unknown scheduler path {path!r}")
-            return carry, st
+                    d = d._replace(n2v=(sel(lanes.n2v_p), sel(lanes.n2v_q),
+                                        us2))
+                return d
 
-        # Hops run while any lane is alive: a dead lane stays dead, so every
-        # later hop would only write NODE_PAD (and all-zero dispatch stats) —
-        # the fill below. Temporal walks die fast (each hop moves forward in
-        # time), so this skips most of the max_length hops.
-        def cond(state):
-            step, carry, _ = state
-            return (step < hops) & jnp.any(carry.alive)
+            return draws
 
         def body(state):
             step, carry, stats = state
             with scope("hop"):
-                carry, st = hop(carry, step)
+                if collect_stats:
+                    st = sched.dispatch_stats(index, carry.cur_node,
+                                              carry.alive, sched_cfg)
+                else:
+                    st = jnp.zeros((sched.NUM_STATS,), jnp.float32)
+                write_pos = step + (1 if edges else 0)
+                carry = hop_variant(index, scfg, sched_cfg, carry, write_pos,
+                                    draws_at(step, write_pos), pass_tables)
             return step + 1, carry, stats.at[step].set(st, mode="drop")
 
-        stats0 = jnp.zeros((hops, sched.NUM_STATS), jnp.float32)
-        step, carry, stats = jax.lax.while_loop(
-            cond, body, (jnp.asarray(0, jnp.int32), carry0, stats0))
-        first_unwritten = step + (2 if wcfg.start_mode == "edges" else 1)
-        unwritten = jnp.arange(L + 1, dtype=jnp.int32) >= first_unwritten
-        # Every iteration processes all W lanes, live or not, so the loop's
-        # lane-steps are exactly W × steps and cost nothing to count. A loop
-        # that processes fewer lanes (one that compacts live lanes, say) must
-        # count the lanes it processes instead.
-        return WalkResult(nodes=jnp.where(unwritten, NODE_PAD, carry.nodes),
-                          times=jnp.where(unwritten, NODE_PAD, carry.times),
+        # Hops run while any lane is alive: a dead lane stays dead, so every
+        # later hop would only write NODE_PAD (and all-zero dispatch stats) —
+        # the length mask below. Temporal walks die fast (each hop moves
+        # forward in time), so this skips most of the max_length hops, but
+        # a call lasts as long as its longest walk. The grouped-bucket loop
+        # therefore narrows as its lanes die (DESIGN.md §10): its regroup
+        # leaves the live lanes as the prefix of the lane order, so tier j
+        # runs at width w_j (_tier_widths) while more than w_{j+1} lanes
+        # live, then hands its lanes over to the next, narrower loop. The
+        # loop counts the lanes it processes, Σ widths, as it goes.
+        widths = _tier_widths(W) if path == "grouped" and bucket else (W,)
+        step = jnp.asarray(0, jnp.int32)
+        lane_steps = jnp.asarray(0, jnp.int32)
+        carry = carry0
+        stats = jnp.zeros((hops, sched.NUM_STATS), jnp.float32)
+        for j, width in enumerate(widths):
+            if j:
+                with scope("hop"), scope("regroup"):
+                    carry = _compact_lanes(carry, width)
+            rest = widths[j + 1] if j + 1 < len(widths) else 0
+
+            def cond(state, rest=rest):
+                step, carry, _ = state
+                return (step < hops) & (
+                    jnp.sum(carry.alive, dtype=jnp.int32) > rest)
+
+            step_in = step
+            step, carry, stats = jax.lax.while_loop(
+                cond, body, (step, carry, stats))
+            lane_steps = lane_steps + (step - step_in) * width
+        # a walk's cells from its own length on are NODE_PAD: lanes cut off
+        # by the ladder stop writing their columns, and donated buffers
+        # hold the previous round's walks there
+        written = (jnp.arange(L + 1, dtype=jnp.int32)[None, :]
+                   < carry.lengths[:, None])
+        return WalkResult(nodes=jnp.where(written, carry.nodes, NODE_PAD),
+                          times=jnp.where(written, carry.times, NODE_PAD),
                           lengths=carry.lengths,
                           stats=stats if collect_stats else None,
-                          steps=step)
+                          steps=step, lane_steps=lane_steps)
 
 
 def _check_lane_support(wcfg: WalkConfig, scfg: SamplerConfig,

@@ -39,8 +39,8 @@ from repro.obs.probes import (  # noqa: F401
     RP_EDGES_INGESTED,
     RP_EXCHANGE_DROPS,
     RP_HOPS,
+    RP_LANE_STEPS,
     RP_LATE_DROPS,
-    RP_LOOP_STEPS,
     RP_OVERFLOW_DROPS,
     RP_WALK_DROPS,
     RP_WALKS_EMITTED,

@@ -39,7 +39,7 @@ RP_EXCHANGE_DROPS = 4    # sharded only: ingest all_to_all bucket overflow
 RP_WALK_DROPS = 5        # sharded only: walk slot/bucket overflow
 RP_HOPS = 6              # hop cells executed (this shard's, when sharded)
 RP_WALKS_EMITTED = 7     # walks with >= 1 hop (single-device driver)
-RP_LOOP_STEPS = 8        # hop-loop iterations (single-device driver)
+RP_LANE_STEPS = 8        # lanes the hop loop processed (single-device driver)
 NUM_REPLAY_PROBES = 9
 
 # Serve probes: one int32[NUM_SERVE_PROBES] vector per shard of a
@@ -61,13 +61,15 @@ def serve_probe_zeros() -> jnp.ndarray:
 def replay_probe_update(vec, *, ingested_delta=None, late_delta=None,
                         overflow_delta=None, exchange_drops=None,
                         walk_drops=None, hops=None, lengths=None,
-                        loop_steps=None):
+                        lane_steps=None):
     """One batch's accumulation into a replay probe vector (device-side).
 
     All arguments are optional scalars (int32); ``lengths`` is the
     batch's [W] walk-length vector, from which the hop and emitted-walk
     counts derive when the caller doesn't track them separately;
-    ``loop_steps`` the iterations the batch's hop loop ran. Pure
+    ``lane_steps`` the lanes the batch's hop loop processed
+    (``WalkResult.lane_steps``), which bound its hops from above: like
+    ``RP_HOPS``, the slot holds one call's sum in int32. Pure
     ``at[].add`` arithmetic — no RNG, no data-dependent control flow —
     so threading it through a scan carry cannot perturb the walk math.
     """
@@ -84,8 +86,8 @@ def replay_probe_update(vec, *, ingested_delta=None, late_delta=None,
         vec = vec.at[RP_WALK_DROPS].add(walk_drops.astype(jnp.int32))
     if hops is not None:
         vec = vec.at[RP_HOPS].add(hops.astype(jnp.int32))
-    if loop_steps is not None:
-        vec = vec.at[RP_LOOP_STEPS].add(loop_steps.astype(jnp.int32))
+    if lane_steps is not None:
+        vec = vec.at[RP_LANE_STEPS].add(lane_steps.astype(jnp.int32))
     if lengths is not None:
         if hops is None:
             vec = vec.at[RP_HOPS].add(
@@ -108,18 +110,15 @@ def _shard_labels(shard: Optional[int], **extra) -> dict:
 
 
 def flush_replay_probes(registry: MetricsRegistry, vec, *,
-                        driver: str, shard: Optional[int] = None,
-                        lanes: int = 0) -> None:
+                        driver: str, shard: Optional[int] = None) -> None:
     """Publish one replay probe vector into the registry.
 
     ``driver`` labels the producing loop ("device" for the single-device
     scan, "sharded" for the node-partitioned one); ``shard`` adds the
     per-shard label for sharded flushes. Drop slots land in the
-    consolidated ``drops_total{kind=...}`` taxonomy. ``lanes`` is the
-    number of lanes every hop-loop iteration processes: the lane-steps
-    ``lanes × RP_LOOP_STEPS`` are counted in 64 bits here, where the
-    device vector's int32 slot would overflow long before the loop
-    count does.
+    consolidated ``drops_total{kind=...}`` taxonomy; the hop loop's
+    lane-steps go to ``walk_lane_steps_total`` where the driver counts
+    them.
     """
     v = np.asarray(vec, dtype=np.int64)
     if v.shape != (NUM_REPLAY_PROBES,):
@@ -136,10 +135,10 @@ def flush_replay_probes(registry: MetricsRegistry, vec, *,
                  help="hop cells executed")
     registry.inc("walks_emitted_total", int(v[RP_WALKS_EMITTED]), labels=lab,
                  help="walks with at least one hop")
-    if lanes and v[RP_LOOP_STEPS]:
-        registry.inc("walk_lane_steps_total", lanes * int(v[RP_LOOP_STEPS]),
-                      labels=_shard_labels(shard, source="replay"),
-                      help="lanes processed by the hop loop, live or not")
+    if v[RP_LANE_STEPS]:
+        registry.inc("walk_lane_steps_total", int(v[RP_LANE_STEPS]),
+                     labels=_shard_labels(shard, source="replay"),
+                     help="lanes processed by the hop loop, live or not")
     count_drop(registry, "ingest_late", int(v[RP_LATE_DROPS]))
     count_drop(registry, "window_overflow", int(v[RP_OVERFLOW_DROPS]))
     count_drop(registry, "exchange_clip", int(v[RP_EXCHANGE_DROPS]))

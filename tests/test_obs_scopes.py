@@ -1,7 +1,8 @@
 """Program-owned instrumentation (DESIGN.md §16): the device scopes that
 name the replay, advance, index and walk stages in the compiled HLO, the
-hop loop's iteration count (``WalkResult.steps``) and the lane-steps it
-implies, the replay probe that sums it over batches, the host stage spans
+hop loop's iteration count (``WalkResult.steps``) and the lanes it
+processed (``WalkResult.lane_steps``), the replay probe that sums those
+over batches, the host stage spans
 of ``replay_device`` and ``sample_walks_donated``, and the compile
 listener."""
 import re
@@ -31,7 +32,7 @@ from repro.core.walk_engine import (
 )
 from repro.data.synthetic import chronological_batches, powerlaw_temporal_graph
 from repro.obs import (
-    RP_LOOP_STEPS,
+    RP_LANE_STEPS,
     SCOPES,
     get_registry,
     new_registry,
@@ -149,11 +150,12 @@ def test_steps_match_lengths(small_index, path, regroup, start_mode):
 
 
 def test_replay_loop_steps_probe_sums_batches():
-    """The replay probe's loop-step slot is the sum of every batch's loop
-    iterations (the scan body replayed batch by batch with the same key
-    chain), and the flushed lane-steps are W times it."""
+    """The replay probe's lane-step slot is the sum of every batch's
+    lane-steps (the scan body replayed batch by batch with the same key
+    chain), and the flushed lane-steps are that sum. At 512 walks the
+    grouped-bucket loop narrows, so it is below W × the loop iterations."""
     cfg = _cfg()
-    wcfg = WalkConfig(num_walks=128, max_length=8, start_mode="nodes")
+    wcfg = WalkConfig(num_walks=512, max_length=8, start_mode="nodes")
     batches = list(chronological_batches(_graph(), 4))
 
     reg = new_registry()
@@ -165,7 +167,7 @@ def test_replay_loop_steps_probe_sums_batches():
     eng.replay_device(batches, wcfg)
 
     k = sub
-    expected, reported = 0, 0
+    expected, reported, lane_steps = 0, 0, 0
     for src, dst, ts in batches:
         k, s = jax.random.split(k)
         state, res = ingest_and_walk(state, make_batch(src, dst, ts, B), s,
@@ -173,23 +175,27 @@ def test_replay_loop_steps_probe_sums_batches():
         expected += _expected_loop_steps(res.lengths, "nodes",
                                          wcfg.max_length)
         reported += int(res.steps)
+        lane_steps += int(res.lane_steps)
     assert reported == expected > 0
+    assert 0 < lane_steps < wcfg.num_walks * expected
     np.testing.assert_array_equal(np.asarray(state.index.store.ts),
                                   np.asarray(eng.state.index.store.ts))
 
     pv = jax.device_get(replay_scan_probed(
         state0, stack_batches(batches, B), sub, N, wcfg, cfg.sampler,
         cfg.scheduler)[3])
-    assert int(pv[RP_LOOP_STEPS]) == expected
+    assert int(pv[RP_LANE_STEPS]) == lane_steps
     assert reg.value("walk_lane_steps_total",
-                     labels={"source": "replay"}) == wcfg.num_walks * expected
+                     labels={"source": "replay"}) == lane_steps
     assert reg.value("walk_hops_total", labels={"source": "replay"}) \
-        <= wcfg.num_walks * expected
+        <= lane_steps
 
 
 def test_sample_walks_counts_lane_steps():
+    """Each call adds its ``lane_steps``: at most W × its loop iterations,
+    less where the grouped-bucket loop narrowed (512 walks)."""
     cfg = _cfg()
-    wcfg = WalkConfig(num_walks=128, max_length=8, start_mode="edges")
+    wcfg = WalkConfig(num_walks=512, max_length=8, start_mode="edges")
     reg = new_registry()
     eng = StreamingEngine(cfg, batch_capacity=B, registry=reg)
     eng.replay_device(list(chronological_batches(_graph(), 4))[:2], wcfg)
@@ -197,7 +203,8 @@ def test_sample_walks_counts_lane_steps():
     for _ in range(2):
         res = eng.sample_walks_donated(wcfg)
         steps = int(res.steps)
-        total += wcfg.num_walks * steps
+        total += int(res.lane_steps)
+        assert 0 < int(res.lane_steps) <= wcfg.num_walks * steps
         assert steps == _expected_loop_steps(res.lengths, "edges",
                                              wcfg.max_length)
     assert reg.value("walk_lane_steps_total",
