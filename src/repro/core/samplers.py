@@ -9,13 +9,11 @@ node-ts view (or [0, n) of the timestamp view for start-edge selection):
     uniform      i = ⌊u·n⌋
     linear       weights w_i ∝ (i+1);   CDF(k) = (k+1)(k+2)/2 / (n(n+1)/2)
                  i = ⌊(−1 + sqrt(1 + 4·u·n·(n+1)))/2⌋
-    exponential  weights w_i ∝ e^i;     CDF(k) = (e^{k+1}−1)/(e^n−1)
-                 exact inverse: i = ⌈log(u·(e^n−1) + 1)⌉ − 1
-                 stable form for large n (e^n overflows):
-                 log(u·(e^n−1)+1) = n + log(u) + log1p((1−u)·e^{−n}/u·…) ≈ n + log(u)
-                 giving the paper's approximation i ≈ ⌊n + ln u − 1⌋… we use
-                 the exact form below a threshold and the log-domain
-                 asymptotic above it; both clamp into [0, n).
+    exponential  weights w_i ∝ e^i;     the offset k = n−1−i from the newest
+                 candidate is truncated geometric, P(k) ∝ e^{−k}, k ∈ [0, n):
+                 k = ⌈−log(1 − u·(1 − e^{−n}))⌉ − 1,   i = n − 1 − k
+                 exact at every n; n enters only through e^{−n} and the
+                 integer subtraction, so no float ever holds n + log u.
 
 * ``weight`` — exact inverse-transform over cumulative true-timestamp
   weights, served from the prefix arrays built at index time
@@ -39,8 +37,6 @@ from repro.core.temporal_index import (
     adjacency_contains,
     ranged_search,
 )
-
-_EXP_EXACT_MAX_N = 80.0   # e^n fits float32 comfortably up to ~88
 
 
 # ---------------------------------------------------------------------------
@@ -77,23 +73,24 @@ def index_linear(u: jax.Array, n: jax.Array) -> jax.Array:
 def index_exponential(u: jax.Array, n: jax.Array) -> jax.Array:
     """Inverse CDF for w_i ∝ e^i (most-recent position gets highest weight).
 
-    Exact: smallest k with (e^{k+1}−1)/(e^n−1) ≥ u  ⇒  k = ⌈log(u(e^n−1)+1)⌉−1.
-    For n above the float32 overflow threshold, e^n−1 → e^n and
-    log(u·e^n + 1) → n + log(u) (since u·e^n ≫ 1 for any representable u>0),
-    recovering the paper's eq. (3) asymptotic ⌊n + ln u − 1⌋ up to rounding.
+    Paper eq. (3), drawn as the offset k of the pick from the newest
+    candidate: P(offset ≤ k) = (1 − e^{−(k+1)})/(1 − e^{−n}), so the
+    smallest k with that ≥ u is k = ⌈−log(1 − u·(1 − e^{−n}))⌉ − 1. Every
+    float stays in [0, 1] or is a small offset (u < 1 keeps it below
+    −log(2^−24) ≈ 17), so the draw is exact at every n up to 2^31 − 1;
+    the pick i = n − 1 − k is taken in int32.
 
-    e^n − 1 is written ``exp(n) - 1`` rather than ``expm1``: n is an integer
-    count, so the cancellation ``expm1`` guards against never occurs
-    (n = 0 gives exactly 0), and the Pallas TPU lowering has no ``expm1``.
-    The fused kernel evaluates this same expression, which keeps its walks
+    One ``exp`` and one ``log``, and no ``expm1``, which the Pallas TPU
+    lowering lacks: e^{−n} of an integer n ≥ 1 is far from 1. The fused
+    kernel evaluates this same expression, which keeps its walks
     byte-identical to the jnp paths.
     """
     nf = n.astype(jnp.float32)
-    u = jnp.clip(u, 1e-30, 1.0)
-    exact = jnp.ceil(jnp.log(u * (jnp.exp(nf) - 1.0) + 1.0)) - 1.0
-    asymptotic = jnp.ceil(nf + jnp.log(u)) - 1.0
-    i = jnp.where(nf <= _EXP_EXACT_MAX_N, exact, asymptotic).astype(jnp.int32)
-    return jnp.clip(i, 0, jnp.maximum(n - 1, 0))
+    k = jnp.ceil(-jnp.log(1.0 - u * (1.0 - jnp.exp(-nf)))) - 1.0
+    # the float bound keeps the int32 cast defined where u = 1 makes k
+    # infinite; the integer bound is exact (and gives 0 for n = 0)
+    k = jnp.clip(k, 0.0, 2.0 ** 30).astype(jnp.int32)
+    return n - 1 - jnp.minimum(k, n - 1)
 
 
 _INDEX_SAMPLERS = {

@@ -190,7 +190,8 @@ def _replay_scan_impl(state: WindowState, batches: EdgeBatch, key: jax.Array,
                 ingested_delta=st2.ingested - st.ingested,
                 late_delta=st2.late_drops - st.late_drops,
                 overflow_delta=st2.overflow_drops - st.overflow_drops,
-                lengths=res.lengths, lane_steps=res.lane_steps)
+                lengths=res.lengths, lane_steps=res.lane_steps,
+                loop_steps=res.steps)
             return (st2, k, nbufs, res.lengths, pv), stats
         return (st2, k, nbufs, res.lengths), stats
 
@@ -478,16 +479,16 @@ class StreamingEngine:
     def _finish_sample(self, res, t0: float, path: str,
                        args: dict) -> float:
         """Shared stats tail of every sample_walks* entry point: sync,
-        fetch the lengths and the hop loop's lane-steps in one
-        transfer, record wall time, publish into the registry, return the
+        fetch the lengths and the hop loop's iterations and lane-steps in
+        one transfer, record wall time, publish into the registry, return the
         elapsed seconds."""
         reg = self.registry
         with span("walks.sync", reg, args=args):
             jax.block_until_ready(res.nodes)
         elapsed = time.perf_counter() - t0
         with span("walks.fetch", reg, args=args):
-            lengths, lane_steps = jax.device_get(
-                (res.lengths, res.lane_steps))
+            lengths, steps, lane_steps = jax.device_get(
+                (res.lengths, res.steps, res.lane_steps))
         with span("walks.publish", reg, args=args):
             self.stats.sample_s.append(elapsed)
             emitted = int(np.sum(lengths >= 2))
@@ -504,6 +505,9 @@ class StreamingEngine:
                 reg.inc("walk_lane_steps_total", int(lane_steps),
                         labels={"source": path},
                         help="lanes processed by the hop loop, live or not")
+            if steps is not None:
+                reg.observe("walk_loop_steps", int(steps),
+                            help="hop-loop iterations per walk call")
         return elapsed
 
     def replay(self, batches: Iterable, wcfg: WalkConfig,

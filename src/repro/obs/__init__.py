@@ -41,6 +41,7 @@ from repro.obs.probes import (  # noqa: F401
     RP_HOPS,
     RP_LANE_STEPS,
     RP_LATE_DROPS,
+    RP_LOOP_STEPS,
     RP_OVERFLOW_DROPS,
     RP_WALK_DROPS,
     RP_WALKS_EMITTED,

@@ -40,7 +40,8 @@ RP_WALK_DROPS = 5        # sharded only: walk slot/bucket overflow
 RP_HOPS = 6              # hop cells executed (this shard's, when sharded)
 RP_WALKS_EMITTED = 7     # walks with >= 1 hop (single-device driver)
 RP_LANE_STEPS = 8        # lanes the hop loop processed (single-device driver)
-NUM_REPLAY_PROBES = 9
+RP_LOOP_STEPS = 9        # iterations the hop loop ran (single-device driver)
+NUM_REPLAY_PROBES = 10
 
 # Serve probes: one int32[NUM_SERVE_PROBES] vector per shard of a
 # ``serve_lanes_sharded`` dispatch.
@@ -61,7 +62,7 @@ def serve_probe_zeros() -> jnp.ndarray:
 def replay_probe_update(vec, *, ingested_delta=None, late_delta=None,
                         overflow_delta=None, exchange_drops=None,
                         walk_drops=None, hops=None, lengths=None,
-                        lane_steps=None):
+                        lane_steps=None, loop_steps=None):
     """One batch's accumulation into a replay probe vector (device-side).
 
     All arguments are optional scalars (int32); ``lengths`` is the
@@ -69,7 +70,8 @@ def replay_probe_update(vec, *, ingested_delta=None, late_delta=None,
     counts derive when the caller doesn't track them separately;
     ``lane_steps`` the lanes the batch's hop loop processed
     (``WalkResult.lane_steps``), which bound its hops from above: like
-    ``RP_HOPS``, the slot holds one call's sum in int32. Pure
+    ``RP_HOPS``, the slot holds one call's sum in int32; ``loop_steps``
+    the iterations it ran (``WalkResult.steps``), summed the same way. Pure
     ``at[].add`` arithmetic — no RNG, no data-dependent control flow —
     so threading it through a scan carry cannot perturb the walk math.
     """
@@ -88,6 +90,8 @@ def replay_probe_update(vec, *, ingested_delta=None, late_delta=None,
         vec = vec.at[RP_HOPS].add(hops.astype(jnp.int32))
     if lane_steps is not None:
         vec = vec.at[RP_LANE_STEPS].add(lane_steps.astype(jnp.int32))
+    if loop_steps is not None:
+        vec = vec.at[RP_LOOP_STEPS].add(loop_steps.astype(jnp.int32))
     if lengths is not None:
         if hops is None:
             vec = vec.at[RP_HOPS].add(
@@ -118,7 +122,8 @@ def flush_replay_probes(registry: MetricsRegistry, vec, *,
     per-shard label for sharded flushes. Drop slots land in the
     consolidated ``drops_total{kind=...}`` taxonomy; the hop loop's
     lane-steps go to ``walk_lane_steps_total`` where the driver counts
-    them.
+    them, and the single-device driver's loop iterations, summed over the
+    call's batches, are one observation of ``walk_loop_steps``.
     """
     v = np.asarray(vec, dtype=np.int64)
     if v.shape != (NUM_REPLAY_PROBES,):
@@ -139,6 +144,9 @@ def flush_replay_probes(registry: MetricsRegistry, vec, *,
         registry.inc("walk_lane_steps_total", int(v[RP_LANE_STEPS]),
                      labels=_shard_labels(shard, source="replay"),
                      help="lanes processed by the hop loop, live or not")
+    if shard is None:
+        registry.observe("walk_loop_steps", int(v[RP_LOOP_STEPS]),
+                         help="hop-loop iterations per walk call")
     count_drop(registry, "ingest_late", int(v[RP_LATE_DROPS]))
     count_drop(registry, "window_overflow", int(v[RP_OVERFLOW_DROPS]))
     count_drop(registry, "exchange_clip", int(v[RP_EXCHANGE_DROPS]))

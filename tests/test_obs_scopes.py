@@ -5,7 +5,10 @@ processed (``WalkResult.lane_steps``), the replay probe that sums those
 over batches, the host stage spans
 of ``replay_device`` and ``sample_walks_donated``, and the compile
 listener."""
+import importlib.util
 import re
+from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +36,7 @@ from repro.core.walk_engine import (
 from repro.data.synthetic import chronological_batches, powerlaw_temporal_graph
 from repro.obs import (
     RP_LANE_STEPS,
+    RP_LOOP_STEPS,
     SCOPES,
     get_registry,
     new_registry,
@@ -150,10 +154,12 @@ def test_steps_match_lengths(small_index, path, regroup, start_mode):
 
 
 def test_replay_loop_steps_probe_sums_batches():
-    """The replay probe's lane-step slot is the sum of every batch's
-    lane-steps (the scan body replayed batch by batch with the same key
-    chain), and the flushed lane-steps are that sum. At 512 walks the
-    grouped-bucket loop narrows, so it is below W × the loop iterations."""
+    """The replay probe's lane-step and loop-step slots are the sums of
+    every batch's lane-steps and loop iterations (the scan body replayed
+    batch by batch with the same key chain); the flushed lane-steps are
+    that sum, and the call is one ``walk_loop_steps`` observation of its
+    iterations. At 512 walks the grouped-bucket loop narrows, so the
+    lane-steps are below W × the loop iterations."""
     cfg = _cfg()
     wcfg = WalkConfig(num_walks=512, max_length=8, start_mode="nodes")
     batches = list(chronological_batches(_graph(), 4))
@@ -185,6 +191,8 @@ def test_replay_loop_steps_probe_sums_batches():
         state0, stack_batches(batches, B), sub, N, wcfg, cfg.sampler,
         cfg.scheduler)[3])
     assert int(pv[RP_LANE_STEPS]) == lane_steps
+    assert int(pv[RP_LOOP_STEPS]) == reported
+    assert reg.histogram("walk_loop_steps").reservoir.values() == [reported]
     assert reg.value("walk_lane_steps_total",
                      labels={"source": "replay"}) == lane_steps
     assert reg.value("walk_hops_total", labels={"source": "replay"}) \
@@ -211,6 +219,47 @@ def test_sample_walks_counts_lane_steps():
                      labels={"source": "donated"}) == total
     assert 0 < reg.value("walk_hops_total",
                          labels={"source": "donated"}) <= total
+
+
+def test_sample_walks_observes_loop_steps():
+    """Each ``sample_walks`` call is one ``walk_loop_steps`` observation:
+    its ``WalkResult.steps``."""
+    cfg = _cfg()
+    wcfg = WalkConfig(num_walks=256, max_length=8, start_mode="edges")
+    reg = new_registry()
+    eng = StreamingEngine(cfg, batch_capacity=B, registry=reg, probes=False)
+    eng.replay_device(list(chronological_batches(_graph(), 4))[:2], wcfg)
+    assert reg.get_family("walk_loop_steps") is None
+    steps = [int(eng.sample_walks(wcfg).steps) for _ in range(3)]
+    assert min(steps) > 0
+    assert reg.histogram("walk_loop_steps").reservoir.values() == steps
+
+
+READERS = Path(__file__).resolve().parents[1] / "bench" / "metrics"
+
+
+@pytest.mark.parametrize("cell", ("replay", "walks"))
+def test_ms_per_step_readers(monkeypatch, cell):
+    """The benchmark's ``walk_ms_per_step.*`` readers: the walks layer's
+    device time over the newest ``calls`` observations of
+    ``walk_loop_steps``; nothing where the program keeps no such
+    histogram, or fewer observations than calls."""
+    path = READERS / f"walk_ms_per_step.{cell}.py"
+    spec = importlib.util.spec_from_file_location(f"reader_{cell}", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    reg = new_registry()
+    monkeypatch.setattr("repro.obs.get_registry", lambda: reg)
+    reading = SimpleNamespace(trace=SimpleNamespace(layers={"walks": 0.6}),
+                              counts={"calls": 2})
+    assert reader.read(reading) is None
+    reg.inc("walk_hops_total", 5)
+    assert reader.read(reading) is None
+    for steps in (1000, 10, 20):      # the warm-up call, then the window's
+        reg.observe("walk_loop_steps", steps)
+    assert reader.read(reading) == pytest.approx(0.6 / 30 * 1e3)
+    reading.counts["calls"] = 4
+    assert reader.read(reading) is None
 
 
 # ---------------------------------------------------------------------------

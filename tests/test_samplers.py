@@ -4,6 +4,8 @@ The closed-form inverse CDFs must reproduce their target categorical
 distributions exactly (up to Monte-Carlo noise), and the weight-based
 samplers must match softmax/linear weights over real timestamps.
 """
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,10 +13,14 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.configs.base import SamplerConfig
 from repro.core.samplers import (
+    BIAS_EXPONENTIAL,
     index_exponential,
     index_linear,
+    index_pick_lanes,
     index_uniform,
+    pick_start_edges,
     weighted_pick_exp,
     weighted_pick_linear,
 )
@@ -79,16 +85,59 @@ def test_index_exponential_law(n):
     assert _chi2_ok(_hist(picks, n), w / w.sum(), NDRAWS)
 
 
+# candidate counts far past float32's exact integers: a start-edge draw over
+# a 2^25-edge window has n ≈ 3·10^7, a hub's neighbourhood millions
+LARGE_N = [81, 2 ** 20 + 1, 2 ** 22 + 1, 2 ** 24, 30_000_000, 2 ** 31 - 1]
+LARGE_DRAWS = 1 << 20
+OFFSET_BINS = 16
+
+
+def _offset_law(n: int) -> np.ndarray:
+    """P(offset k from the newest) for k < OFFSET_BINS, then the tail."""
+    k = np.arange(OFFSET_BINS, dtype=np.float64)
+    head = np.exp(-k) * -np.expm1(-1.0) / -np.expm1(-float(n))
+    return np.append(head, max(1.0 - head.sum(), 0.0))
+
+
+def _offset_hist(picks, n: int) -> np.ndarray:
+    off = n - 1 - np.asarray(picks, np.int64)
+    return np.bincount(np.minimum(off, OFFSET_BINS),
+                       minlength=OFFSET_BINS + 1) / off.size
+
+
+def _large_u():
+    return jax.random.uniform(jax.random.PRNGKey(3), (LARGE_DRAWS,))
+
+
+@pytest.mark.parametrize("n", LARGE_N)
 @pytest.mark.statistical
-def test_index_exponential_large_n_asymptotic():
-    """Above the float32 e^n threshold the log-domain form takes over and
-    must still concentrate on the most recent positions."""
-    n = 500
-    u = jax.random.uniform(jax.random.PRNGKey(3), (NDRAWS,))
-    picks = np.asarray(index_exponential(u, jnp.full((NDRAWS,), n, jnp.int32)))
+def test_index_exponential_exact_at_large_n(n):
+    """The exact law at every n: a chi-square over the offsets 0..15 from
+    the newest candidate and a tail bin, against
+    e^{−k}(1 − e^{−1})/(1 − e^{−n}). A form that adds log u to n in
+    float32 rounds the sum from n ≈ 2^20 on and skips candidates from
+    2^24 on: it fails every case here from 2^20 + 1 up."""
+    picks = np.asarray(index_exponential(
+        _large_u(), jnp.full((LARGE_DRAWS,), n, jnp.int32)))
     assert picks.min() >= 0 and picks.max() <= n - 1
-    # P(i >= n-5) = (e^5-1+...)/... ~ 1 - e^-5 ≈ 0.993
-    assert (picks >= n - 5).mean() > 0.98
+    assert _chi2_ok(_offset_hist(picks, n), _offset_law(n), LARGE_DRAWS)
+
+
+@pytest.mark.parametrize("n", LARGE_N)
+def test_exponential_callers_follow_index_exponential(n):
+    """The per-lane dispatch and the start-edge draw give the same picks
+    as ``index_exponential`` (the fused and tiled kernels and the sharded
+    start draw call the per-lane dispatch)."""
+    u = _large_u()[:4096]
+    nn = jnp.full(u.shape, n, jnp.int32)
+    want = np.asarray(index_exponential(u, nn))
+    code = jnp.full(u.shape, BIAS_EXPONENTIAL, jnp.int32)
+    np.testing.assert_array_equal(np.asarray(index_pick_lanes(code, u, nn)),
+                                  want)
+    index = SimpleNamespace(num_edges=jnp.asarray(n, jnp.int32))
+    cfg = SamplerConfig(mode="index", start_bias="exponential")
+    np.testing.assert_array_equal(np.asarray(pick_start_edges(index, cfg, u)),
+                                  want)
 
 
 @pytest.mark.statistical
