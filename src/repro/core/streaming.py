@@ -19,6 +19,10 @@ Two drivers over the same merge-based ingest (DESIGN.md §4):
 
 `ingest_and_walk` is the shared fused step: one jitted program covering
 merge-ingest + index rebuild + walk generation, donating the old state.
+Each takes the static ``views`` (``temporal_index.IndexViews``) that the
+rebuild makes beside the index's core; ``StreamingEngine`` derives them
+from its sampler and path (``walk_engine.index_views``), so an index-mode
+engine on the grouped path rebuilds the node-ts view alone (DESIGN.md §3).
 `ingest_and_walk_donated` additionally consumes the previous round's walk
 buffers, and `replay_scan` carries them through the scan, so steady-state
 replay reallocates nothing on the walk side either (DESIGN.md §10).
@@ -41,6 +45,7 @@ from repro.configs.base import (
     WalkConfig,
 )
 from repro.core.edge_store import EdgeBatch, make_batch, stack_batches
+from repro.core.temporal_index import FULL_VIEWS, IndexViews
 from repro.core.walk_engine import (
     WalkBuffers,
     WalkResult,
@@ -48,9 +53,11 @@ from repro.core.walk_engine import (
     alloc_walk_buffers,
     generate_walks,
     generate_walks_donated,
+    index_views,
 )
 from repro.core.window import (
     WindowState,
+    fit_window,
     ingest,
     ingest_impl,
     ingest_sort,
@@ -106,9 +113,9 @@ def _ingest_and_walk_impl(state: WindowState, batch: EdgeBatch,
                           sched_cfg: SchedulerConfig,
                           bias_scale: float = 1.0,
                           walk_bufs: Optional[WalkBuffers] = None,
-                          table=None):
+                          table=None, views: IndexViews = FULL_VIEWS):
     state = ingest_impl(state, batch, node_capacity, bias_scale,
-                        table=table)
+                        table=table, views=views)
     res = _generate_walks_impl(state.index, key, wcfg, scfg, sched_cfg,
                                buffers=walk_bufs, tables=state.tables)
     return state, res
@@ -122,7 +129,7 @@ def _ingest_and_walk_impl(state: WindowState, batch: EdgeBatch,
 ingest_and_walk = partial(
     jax.jit,
     static_argnames=("node_capacity", "wcfg", "scfg", "sched_cfg",
-                     "bias_scale", "table"),
+                     "bias_scale", "table", "views"),
     donate_argnums=(0,),
 )(_ingest_and_walk_impl)
 
@@ -132,10 +139,12 @@ def _ingest_and_walk_donated_impl(state: WindowState, batch: EdgeBatch,
                                   node_capacity: int, wcfg: WalkConfig,
                                   scfg: SamplerConfig,
                                   sched_cfg: SchedulerConfig,
-                                  bias_scale: float = 1.0, table=None):
+                                  bias_scale: float = 1.0, table=None,
+                                  views: IndexViews = FULL_VIEWS):
     return _ingest_and_walk_impl(state, batch, key, node_capacity, wcfg,
                                  scfg, sched_cfg, bias_scale,
-                                 walk_bufs=walk_bufs, table=table)
+                                 walk_bufs=walk_bufs, table=table,
+                                 views=views)
 
 
 # Fully donated fused step (DESIGN.md §10): both the window state AND the
@@ -145,7 +154,7 @@ def _ingest_and_walk_donated_impl(state: WindowState, batch: EdgeBatch,
 ingest_and_walk_donated = partial(
     jax.jit,
     static_argnames=("node_capacity", "wcfg", "scfg", "sched_cfg",
-                     "bias_scale", "table"),
+                     "bias_scale", "table", "views"),
     donate_argnums=(0, 2),
 )(_ingest_and_walk_donated_impl)
 
@@ -154,7 +163,7 @@ def _replay_scan_impl(state: WindowState, batches: EdgeBatch, key: jax.Array,
                       node_capacity: int, wcfg: WalkConfig,
                       scfg: SamplerConfig, sched_cfg: SchedulerConfig,
                       bias_scale: float = 1.0, with_probes: bool = False,
-                      table=None):
+                      table=None, views: IndexViews = FULL_VIEWS):
     """Shared body of ``replay_scan`` / ``replay_scan_probed``.
 
     ``with_probes`` threads an obs probe vector (obs/probes.py) through
@@ -162,7 +171,12 @@ def _replay_scan_impl(state: WindowState, batches: EdgeBatch, key: jax.Array,
     (probe updates are pure ``at[].add`` on counters the stats already
     compute), and when False the traced program is exactly the historical
     one — no probe leaf exists to be DCE'd.
+
+    The carried index holds the core and ``views`` and nothing else: the
+    state is fitted to them first (``fit_window``; the engine has already
+    done so, which makes this a no-op), so every batch rebuilds only them.
     """
+    state = fit_window(state, views)
 
     def step(carry, batch):
         if with_probes:
@@ -172,7 +186,8 @@ def _replay_scan_impl(state: WindowState, batches: EdgeBatch, key: jax.Array,
         k, sub = jax.random.split(k)
         st2, res = _ingest_and_walk_impl(st, batch, sub, node_capacity,
                                          wcfg, scfg, sched_cfg, bias_scale,
-                                         walk_bufs=bufs, table=table)
+                                         walk_bufs=bufs, table=table,
+                                         views=views)
         stats = ReplayStats(
             edges_active=st2.index.num_edges,
             t_now=st2.t_now,
@@ -210,12 +225,12 @@ def _replay_scan_impl(state: WindowState, batches: EdgeBatch, key: jax.Array,
 
 @partial(jax.jit,
          static_argnames=("node_capacity", "wcfg", "scfg", "sched_cfg",
-                          "bias_scale", "table"),
+                          "bias_scale", "table", "views"),
          donate_argnums=(0,))
 def replay_scan(state: WindowState, batches: EdgeBatch, key: jax.Array,
                 node_capacity: int, wcfg: WalkConfig, scfg: SamplerConfig,
                 sched_cfg: SchedulerConfig, bias_scale: float = 1.0,
-                table=None):
+                table=None, views: IndexViews = FULL_VIEWS):
     """Replay K stacked batches fully on device under `jax.lax.scan`.
 
     ``batches`` holds [K, B_cap] arrays (see edge_store.stack_batches).
@@ -229,17 +244,18 @@ def replay_scan(state: WindowState, batches: EdgeBatch, key: jax.Array,
     """
     return _replay_scan_impl(state, batches, key, node_capacity, wcfg,
                              scfg, sched_cfg, bias_scale, with_probes=False,
-                             table=table)
+                             table=table, views=views)
 
 
 @partial(jax.jit,
          static_argnames=("node_capacity", "wcfg", "scfg", "sched_cfg",
-                          "bias_scale", "table"),
+                          "bias_scale", "table", "views"),
          donate_argnums=(0,))
 def replay_scan_probed(state: WindowState, batches: EdgeBatch,
                        key: jax.Array, node_capacity: int, wcfg: WalkConfig,
                        scfg: SamplerConfig, sched_cfg: SchedulerConfig,
-                       bias_scale: float = 1.0, table=None):
+                       bias_scale: float = 1.0, table=None,
+                       views: IndexViews = FULL_VIEWS):
     """``replay_scan`` plus an obs probe vector (DESIGN.md §16).
 
     Returns ``(final_state, ReplayStats, final_walks, probes)`` with
@@ -252,7 +268,7 @@ def replay_scan_probed(state: WindowState, batches: EdgeBatch,
     """
     return _replay_scan_impl(state, batches, key, node_capacity, wcfg,
                              scfg, sched_cfg, bias_scale, with_probes=True,
-                             table=table)
+                             table=table, views=views)
 
 
 class StreamingEngine:
@@ -261,6 +277,11 @@ class StreamingEngine:
     ``ingest_impl`` selects the window-advance algorithm: ``"merge"`` (the
     rank-based two-run merge, default) or ``"sort"`` (the seed's global
     argsort, kept as the equivalence/benchmark reference).
+
+    The window's index holds only the views the engine's walks can read
+    (``walk_engine.index_views`` of its sampler and path). ``state`` may be
+    assigned from outside (a bulk load built with every view, say): each
+    entry point fits it to the engine's views before any program sees it.
     """
 
     def __init__(self, cfg: EngineConfig, batch_capacity: int,
@@ -281,9 +302,10 @@ class StreamingEngine:
                 "alias-table maintenance (bias='table') requires the merge "
                 "ingest path; the 'sort' reference path does not thread "
                 "table state")
+        self._views = index_views(cfg.sampler, cfg.scheduler.path)
         self.state: WindowState = init_window(
             cfg.window.edge_capacity, cfg.window.node_capacity,
-            int(cfg.window.duration), table=self._table)
+            int(cfg.window.duration), table=self._table, views=self._views)
         self.key = jax.random.PRNGKey(cfg.seed)
         self.stats = StreamStats()
         # obs integration (DESIGN.md §16): every driver publishes into the
@@ -304,6 +326,17 @@ class StreamingEngine:
         self._seq = 0
         self._warned_replicated_index = False
 
+    def _fit_state(self) -> WindowState:
+        """Fit ``state`` to the engine's index views (``fit_window``): free
+        when it already holds them, as every state the engine made does."""
+        self.state = fit_window(self.state, self._views)
+        return self.state
+
+    def _publish_index_columns(self) -> None:
+        self.registry.set_gauge(
+            "index_edge_columns", self.state.index.views.edge_columns,
+            help="edge-sized columns the window's index rebuild writes")
+
     def _publish_window(self) -> None:
         """Refresh window gauges + drop deltas from the synced state."""
         from repro.obs.registry import count_drop
@@ -311,6 +344,7 @@ class StreamingEngine:
         num_edges = int(self.state.index.num_edges)
         reg.set_gauge("window_edges_active", num_edges,
                       help="edges resident in the temporal window")
+        self._publish_index_columns()
         reg.set_gauge("window_t_now", int(self.state.t_now),
                       help="watermark timestamp of the window")
         reg.set_gauge("window_occupancy",
@@ -355,6 +389,7 @@ class StreamingEngine:
         edges = int(last[-1])
         reg.set_gauge("window_edges_active", edges,
                       help="edges resident in the temporal window")
+        self._publish_index_columns()
         reg.set_gauge("window_t_now", int(np.asarray(stats.t_now)[-1]),
                       help="watermark timestamp of the window")
         reg.set_gauge("window_occupancy",
@@ -369,13 +404,16 @@ class StreamingEngine:
         batch = make_batch(src, dst, ts, capacity=self.batch_capacity)
         t0 = time.perf_counter()
         with span("ingest_merge", self.registry):
+            state = self._fit_state()
             if self._table is not None:
-                self.state = self._ingest(self.state, batch,
+                self.state = self._ingest(state, batch,
                                           self.cfg.window.node_capacity,
-                                          table=self._table)
+                                          table=self._table,
+                                          views=self._views)
             else:
-                self.state = self._ingest(self.state, batch,
-                                          self.cfg.window.node_capacity)
+                self.state = self._ingest(state, batch,
+                                          self.cfg.window.node_capacity,
+                                          views=self._views)
             jax.block_until_ready(self.state.index.ns_order)
         self.stats.ingest_s.append(time.perf_counter() - t0)
         self.stats.edges_active.append(int(self.state.index.num_edges))
@@ -396,7 +434,7 @@ class StreamingEngine:
         with span("walks.dispatch", self.registry, args=args):
             self.key, sub = jax.random.split(self.key)
             t0 = time.perf_counter()
-            res = generate_walks(self.state.index, sub, wcfg,
+            res = generate_walks(self._fit_state().index, sub, wcfg,
                                  self.cfg.sampler, self.cfg.scheduler,
                                  collect_stats=collect_stats,
                                  tables=self.state.tables)
@@ -420,7 +458,8 @@ class StreamingEngine:
                 bufs = alloc_walk_buffers(wcfg)
             self.key, sub = jax.random.split(self.key)
             t0 = time.perf_counter()
-            res = generate_walks_donated(self.state.index, sub, bufs, wcfg,
+            res = generate_walks_donated(self._fit_state().index, sub, bufs,
+                                         wcfg,
                                          self.cfg.sampler,
                                          self.cfg.scheduler,
                                          tables=self.state.tables)
@@ -450,7 +489,7 @@ class StreamingEngine:
         with span("walks.dispatch", self.registry, args=args):
             self.key, sub = jax.random.split(self.key)
             t0 = time.perf_counter()
-            res = generate_walks_sharded(self.state.index, sub, wcfg,
+            res = generate_walks_sharded(self._fit_state().index, sub, wcfg,
                                          self.cfg.sampler,
                                          self.cfg.scheduler, mesh=mesh)
         self._finish_sample(res, t0, path="sharded", args=args)
@@ -536,16 +575,17 @@ class StreamingEngine:
         with span("replay.dispatch", reg, args=args):
             self.key, sub = jax.random.split(self.key)
             t0 = time.perf_counter()
+            state = self._fit_state()
             if self.probes:
                 self.state, stats, walks, pv = replay_scan_probed(
-                    self.state, stacked, sub, self.cfg.window.node_capacity,
+                    state, stacked, sub, self.cfg.window.node_capacity,
                     wcfg, self.cfg.sampler, self.cfg.scheduler,
-                    table=self._table)
+                    table=self._table, views=self._views)
             else:
                 self.state, stats, walks = replay_scan(
-                    self.state, stacked, sub, self.cfg.window.node_capacity,
+                    state, stacked, sub, self.cfg.window.node_capacity,
                     wcfg, self.cfg.sampler, self.cfg.scheduler,
-                    table=self._table)
+                    table=self._table, views=self._views)
                 pv = None
         with span("replay.sync", reg, args=args):
             # the single sync point — probes ride the same materialization

@@ -36,11 +36,27 @@ hop suffix [c, b):
   neighborhood weight w_i = ts_i − ts_c + 1 = elem_i − δ with
   δ = ts_c − t_base[v]; cumulative S[k] = (P[k+1] − P[c]) − (k+1−c)·δ is
   O(1) per probe, so inverse-CDF stays a binary search.
+
+Which views are built when (``IndexViews``, DESIGN.md §3). The **core** —
+the store, the node-ts view and the node-sized ``node_starts``,
+``node_group_counts``, ``node_tref``, ``node_tbase`` — is always built:
+every walk, the alias tables and the dispatch stats read only it. The
+**weight prefixes** (``pexp``, ``plin``, ``pexp_store``, ``plin_store``:
+two ``exp`` passes, two spreads, four cumsums) and the **adjacency view**
+(``adj_order``, ``adj_dst``: the second sort) are optional, ``None`` when
+unbuilt. A bare ``build_index(store, node_capacity)`` builds all of them;
+an engine builds only what its walks can read
+(``walk_engine.index_views``): the prefixes for ``mode="weight"`` or a
+Pallas path, the adjacency view where a second-order draw can reach the
+index. ``fit_index`` narrows an index to a view set for free and builds a
+missing view from the core, bit for bit as ``build_index`` would; the
+validator builds its own adjacency view (``adjacency_view``) when the
+index has none.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,27 +65,59 @@ from repro.core.edge_store import EdgeStore
 from repro.obs.tracing import scope
 
 
+class IndexViews(NamedTuple):
+    """The optional views an index holds beside its core (module docstring).
+
+    Hashable, so it travels as a static argument: ``build_index``,
+    ``init_window``, the ingests and the replay drivers compile one program
+    per value. The default is every view.
+    """
+
+    prefixes: bool = True     # pexp, plin, pexp_store, plin_store
+    adjacency: bool = True    # adj_order, adj_dst
+
+    @property
+    def edge_columns(self) -> int:
+        """Edge-sized columns an index of these views holds, and so writes
+        at every rebuild: the node-ts view's four, four prefixes, two
+        adjacency columns."""
+        return 4 + 4 * self.prefixes + 2 * self.adjacency
+
+    def holds(self, other: "IndexViews") -> bool:
+        """Whether an index of these views holds every view of ``other``."""
+        return ((self.prefixes or not other.prefixes)
+                and (self.adjacency or not other.adjacency))
+
+
+FULL_VIEWS = IndexViews()
+_PREFIX_FIELDS = ("pexp", "plin", "pexp_store", "plin_store")
+_ADJACENCY_FIELDS = ("adj_order", "adj_dst")
+
+
 class TemporalIndex(NamedTuple):
+    # ---- core: always built ----
     # shared edge store (timestamp-grouped view == physical layout)
     store: EdgeStore
-    # ---- node-and-timestamp-grouped view ----
+    # node-and-timestamp-grouped view
     ns_order: jax.Array      # int32[E] permutation: position -> store index
     ns_src: jax.Array        # int32[E] src gathered through ns_order
     ns_dst: jax.Array        # int32[E]
     ns_ts: jax.Array         # int32[E]
     node_starts: jax.Array   # int32[N+2] region of node v = [ns[v], ns[v+1])
     node_group_counts: jax.Array  # int32[N] distinct-timestamp count (the G axis)
-    # weight-sampler prefix arrays (exclusive; length E+1)
-    pexp: jax.Array          # float32[E+1]
-    plin: jax.Array          # float32[E+1]
     node_tref: jax.Array     # int32[N] max ts per node (exp reference)
     node_tbase: jax.Array    # int32[N] min ts per node (linear reference)
+    # ---- optional: weight prefixes (IndexViews.prefixes), else None ----
+    # weight-sampler prefix arrays over the node-ts view (exclusive; E+1)
+    pexp: Optional[jax.Array] = None        # float32[E+1]
+    plin: Optional[jax.Array] = None        # float32[E+1]
     # store-level prefixes for start-edge selection over the timestamp view
-    pexp_store: jax.Array    # float32[E+1]
-    plin_store: jax.Array    # float32[E+1]
-    # ---- adjacency view (node2vec β probe + validation) ----
-    adj_order: jax.Array     # int32[E] permutation sorted by (src, dst, ts)
-    adj_dst: jax.Array       # int32[E]
+    pexp_store: Optional[jax.Array] = None  # float32[E+1]
+    plin_store: Optional[jax.Array] = None  # float32[E+1]
+    # ---- optional: adjacency view (IndexViews.adjacency), else None ----
+    # node2vec β probe + validation
+    adj_order: Optional[jax.Array] = None   # int32[E] sorted by (src, dst, ts)
+    adj_dst: Optional[jax.Array] = None     # int32[E]
 
     @property
     def num_edges(self) -> jax.Array:
@@ -82,6 +130,12 @@ class TemporalIndex(NamedTuple):
     @property
     def edge_capacity(self) -> int:
         return self.ns_order.shape[0]
+
+    @property
+    def views(self) -> IndexViews:
+        """The optional views this index holds."""
+        return IndexViews(prefixes=self.pexp is not None,
+                          adjacency=self.adj_order is not None)
 
 
 def _spread(node_vals: jax.Array, lo: jax.Array, nonempty: jax.Array,
@@ -100,15 +154,60 @@ def _spread(node_vals: jax.Array, lo: jax.Array, nonempty: jax.Array,
     return jnp.cumsum(jnp.zeros((E,), jnp.int32).at[lo].add(steps))
 
 
+def _prefix_views(index: TemporalIndex, bias_scale: float) -> dict:
+    """The four weight prefixes (linear passes over the core)."""
+    store, ns_src, ns_ts = index.store, index.ns_src, index.ns_ts
+    E, nc = index.edge_capacity, index.node_capacity
+    n_valid = store.num_edges
+    valid = jnp.arange(E, dtype=jnp.int32) < n_valid
+    lo = index.node_starts[:nc]
+    nonempty = index.node_starts[1:nc + 1] > lo
+
+    in_range = ns_src < nc
+    dt_exp = (ns_ts - _spread(index.node_tref, lo, nonempty, E)).astype(
+        jnp.float32)
+    w_exp = jnp.where(in_range, jnp.exp(bias_scale * dt_exp), 0.0)
+    elem_lin = (ns_ts - _spread(index.node_tbase, lo, nonempty, E) + 1
+                ).astype(jnp.float32)
+    w_lin = jnp.where(in_range, elem_lin, 0.0)
+    zero = jnp.zeros((1,), jnp.float32)
+    pexp = jnp.concatenate([zero, jnp.cumsum(w_exp)])
+    plin = jnp.concatenate([zero, jnp.cumsum(w_lin)])
+
+    # store-level prefixes (start-edge selection over the whole window)
+    t_hi = jnp.where(n_valid > 0, store.ts[jnp.maximum(n_valid - 1, 0)], 0)
+    t_lo = store.ts[0]
+    w_exp_s = jnp.where(valid, jnp.exp(
+        bias_scale * (store.ts - t_hi).astype(jnp.float32)), 0.0)
+    w_lin_s = jnp.where(valid, (store.ts - t_lo + 1).astype(jnp.float32),
+                        0.0)
+    return dict(pexp=pexp, plin=plin,
+                pexp_store=jnp.concatenate([zero, jnp.cumsum(w_exp_s)]),
+                plin_store=jnp.concatenate([zero, jnp.cumsum(w_lin_s)]))
+
+
+def _adjacency_view_impl(index: TemporalIndex):
+    """Sort 2: (adj_order, adj_dst), the (src, dst, ts) adjacency view.
+
+    A stable (src, dst) sort of the ns view: equal (src, dst) runs keep the
+    ns view's (ts, store position) order, so this is the stable (src, dst,
+    ts) order of the store. Two keys, not three: a three-key sort costs the
+    TPU compiler ~1 min more per program that rebuilds the index."""
+    _, adj_dst, adj_order = jax.lax.sort(
+        (index.ns_src, index.ns_dst, index.ns_order), num_keys=2,
+        is_stable=True)
+    return adj_order, adj_dst
+
+
 def _build_index_impl(store: EdgeStore, node_capacity: int,
-                      bias_scale: float = 1.0) -> TemporalIndex:
-    """Bulk dual-index reconstruction (paper §2.6: two sorts + linear
-    passes); its device work carries the ``index`` scope."""
+                      bias_scale: float = 1.0,
+                      views: IndexViews = FULL_VIEWS) -> TemporalIndex:
+    """Bulk dual-index reconstruction (paper §2.6: a sort per view + linear
+    passes) of the core and ``views``; its device work carries the
+    ``index`` scope."""
     with scope("index"):
         E = store.capacity
-        n_valid = store.num_edges
         iota = jnp.arange(E, dtype=jnp.int32)
-        valid = iota < n_valid
 
         # ---- sort 1: (src, ts) — the node-and-timestamp-grouped view ----
         # The store is ts-sorted, so a stable sort by src alone yields the
@@ -145,54 +244,64 @@ def _build_index_impl(store: EdgeStore, node_capacity: int,
         node_tbase = jnp.where(nonempty, ns_ts[jnp.clip(lo, 0, E - 1)], 0)
         node_tref = jnp.where(nonempty, ns_ts[jnp.clip(hi - 1, 0, E - 1)], 0)
 
-        # ---- weight prefix arrays (linear passes) ------------------------
-        in_range = ns_src < node_capacity
-        dt_exp = (ns_ts - _spread(node_tref, lo, nonempty, E)).astype(
-            jnp.float32)
-        w_exp = jnp.where(in_range, jnp.exp(bias_scale * dt_exp), 0.0)
-        elem_lin = (ns_ts - _spread(node_tbase, lo, nonempty, E) + 1).astype(
-            jnp.float32)
-        w_lin = jnp.where(in_range, elem_lin, 0.0)
-        zero = jnp.zeros((1,), jnp.float32)
-        pexp = jnp.concatenate([zero, jnp.cumsum(w_exp)])
-        plin = jnp.concatenate([zero, jnp.cumsum(w_lin)])
-
-        # store-level prefixes (start-edge selection over the whole window)
-        t_hi = jnp.where(n_valid > 0, store.ts[jnp.maximum(n_valid - 1, 0)], 0)
-        t_lo = store.ts[0]
-        w_exp_s = jnp.where(valid, jnp.exp(
-            bias_scale * (store.ts - t_hi).astype(jnp.float32)), 0.0)
-        w_lin_s = jnp.where(valid, (store.ts - t_lo + 1).astype(jnp.float32),
-                            0.0)
-        pexp_store = jnp.concatenate([zero, jnp.cumsum(w_exp_s)])
-        plin_store = jnp.concatenate([zero, jnp.cumsum(w_lin_s)])
-
-        # ---- sort 2: (src, dst, ts) — adjacency view ---------------------
-        # A stable (src, dst) sort of the ns view: equal (src, dst) runs
-        # keep the ns view's (ts, store position) order, so this is the
-        # stable (src, dst, ts) order of the store. Two keys, not three: a
-        # three-key sort costs the TPU compiler ~1 min more per program
-        # that rebuilds the index.
-        _, adj_dst, adj_order = jax.lax.sort((ns_src, ns_dst, ns_order),
-                                             num_keys=2, is_stable=True)
-
-        return TemporalIndex(
+        index = TemporalIndex(
             store=store,
             ns_order=ns_order, ns_src=ns_src, ns_dst=ns_dst, ns_ts=ns_ts,
             node_starts=node_starts, node_group_counts=node_group_counts,
-            pexp=pexp, plin=plin,
-            node_tref=node_tref, node_tbase=node_tbase,
-            pexp_store=pexp_store, plin_store=plin_store,
-            adj_order=adj_order, adj_dst=adj_dst,
-        )
+            node_tref=node_tref, node_tbase=node_tbase)
+        return _with_views(index, views, bias_scale)
+
+
+def _with_views(index: TemporalIndex, views: IndexViews,
+                bias_scale: float) -> TemporalIndex:
+    """``index`` plus the views of ``views`` it lacks, built from its core."""
+    if views.prefixes and index.pexp is None:
+        index = index._replace(**_prefix_views(index, bias_scale))
+    if views.adjacency and index.adj_order is None:
+        adj_order, adj_dst = _adjacency_view_impl(index)
+        index = index._replace(adj_order=adj_order, adj_dst=adj_dst)
+    return index
 
 
 build_index = partial(jax.jit, static_argnames=("node_capacity",
-                                                "bias_scale"))(
+                                                "bias_scale", "views"))(
     _build_index_impl)
 
+# the adjacency view of an index that has none (the validator's)
+adjacency_view = jax.jit(_adjacency_view_impl)
 
-def empty_index(edge_capacity: int, node_capacity: int) -> TemporalIndex:
+
+@partial(jax.jit, static_argnames=("views", "bias_scale"))
+def _complete(index: TemporalIndex, views: IndexViews,
+              bias_scale: float) -> TemporalIndex:
+    with scope("index"):
+        return _with_views(index, views, bias_scale)
+
+
+def fit_index(index: TemporalIndex, views: IndexViews,
+              bias_scale: float = 1.0) -> TemporalIndex:
+    """``index`` holding exactly the optional ``views``.
+
+    Views it holds beyond them are dropped, at no cost: their arrays are
+    simply not passed on. Views it lacks are built once from its core, bit
+    for bit as ``build_index(..., views=)`` builds them. A jitted program
+    whose arguments or carry hold an index compiles for one view set, so
+    the engines fit a state handed to them (a bulk load, say) before any of
+    their programs sees it.
+    """
+    have = index.views
+    if have == views:
+        return index
+    drop = ((() if views.prefixes else _PREFIX_FIELDS)
+            + (() if views.adjacency else _ADJACENCY_FIELDS))
+    index = index._replace(**{f: None for f in drop})
+    if not have.holds(views):
+        index = _complete(index, views, bias_scale)
+    return index
+
+
+def empty_index(edge_capacity: int, node_capacity: int,
+                views: IndexViews = FULL_VIEWS) -> TemporalIndex:
     """``build_index`` of an empty store, written out: every edge slot is
     padding, so each view is the identity order and every prefix is 0. No
     sort is compiled (at a 2^27-edge capacity they take a v5e's compiler
@@ -203,15 +312,18 @@ def empty_index(edge_capacity: int, node_capacity: int) -> TemporalIndex:
     iota = jnp.arange(E, dtype=jnp.int32)
     store = empty_store(E, nc)
     zeros_v = jnp.zeros((nc,), jnp.int32)
-    zeros_p = jnp.zeros((E + 1,), jnp.float32)
-    return TemporalIndex(
+    index = TemporalIndex(
         store=store, ns_order=iota, ns_src=store.src, ns_dst=store.dst,
         ns_ts=store.ts,
         node_starts=jnp.zeros((nc + 2,), jnp.int32).at[nc + 1].set(E),
-        node_group_counts=zeros_v, pexp=zeros_p, plin=zeros_p,
-        node_tref=zeros_v, node_tbase=zeros_v, pexp_store=zeros_p,
-        plin_store=zeros_p, adj_order=iota, adj_dst=store.dst)
-
+        node_group_counts=zeros_v, node_tref=zeros_v, node_tbase=zeros_v)
+    if views.prefixes:
+        zeros_p = jnp.zeros((E + 1,), jnp.float32)
+        index = index._replace(pexp=zeros_p, plin=zeros_p,
+                               pexp_store=zeros_p, plin_store=zeros_p)
+    if views.adjacency:
+        index = index._replace(adj_order=iota, adj_dst=store.dst)
+    return index
 
 # ---------------------------------------------------------------------------
 # Ranged searches (branch-free, fixed trip count)

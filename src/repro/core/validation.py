@@ -17,7 +17,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.temporal_index import TemporalIndex, ranged_search
+from repro.core.temporal_index import (
+    TemporalIndex,
+    adjacency_view,
+    ranged_search,
+)
 from repro.core.walk_engine import NODE_PAD, WalkResult
 
 
@@ -49,6 +53,12 @@ def _edge_exists(index: TemporalIndex, u, v, t):
 
 @jax.jit
 def validate_walks(index: TemporalIndex, result: WalkResult) -> ValidityReport:
+    """Hop and walk validity of ``result`` against ``index``'s window. An
+    index without the adjacency view (built for walks that never read it)
+    gets its own here."""
+    if index.adj_order is None:
+        adj_order, adj_dst = adjacency_view(index)
+        index = index._replace(adj_order=adj_order, adj_dst=adj_dst)
     nodes, times, lengths = result.nodes, result.times, result.lengths
     W, Lp1 = nodes.shape
     pos = jnp.arange(Lp1 - 1)

@@ -90,6 +90,7 @@ from repro.core.samplers import (
     pick_start_edges_lanes,
 )
 from repro.core.temporal_index import (
+    IndexViews,
     TemporalIndex,
     node_range,
     ranged_search,
@@ -241,6 +242,26 @@ def check_capabilities(scfg: SamplerConfig, path: str,
                     _CAP + "path='fused' does not support node2vec "
                     "second-order lanes (the rejection loop re-draws "
                     "outside the kernel); use 'fullwalk'|'grouped'")
+
+
+def index_views(scfg: SamplerConfig, path: str, *,
+                second_order: bool = False) -> IndexViews:
+    """The optional index views a walk under (sampler config, path) can
+    read, beside the core that every walk reads (temporal_index.py).
+
+    * the weight prefixes: ``mode="weight"`` draws and the Pallas paths
+      (``tiled``, ``fused``) stage them;
+    * the adjacency view: the node2vec β probe, where a second-order draw
+      can reach the index — config-level node2vec, or ``second_order``
+      lanes (a ``WalkService`` may admit them with any query).
+
+    The engines build, carry and rebuild only these (DESIGN.md §3); this is
+    derived, never a user option.
+    """
+    return IndexViews(
+        prefixes=scfg.mode == "weight" or path in ("tiled", "fused"),
+        adjacency=(second_order or scfg.node2vec_p != 1.0
+                   or scfg.node2vec_q != 1.0))
 
 
 class WalkResult(NamedTuple):
@@ -863,6 +884,12 @@ def _generate_walks_impl(index: TemporalIndex, key: jax.Array,
             raise ValueError(f"unknown scheduler path {path!r}")
         if sched_cfg.regroup not in ("bucket", "lexsort"):
             raise ValueError(f"unknown regroup {sched_cfg.regroup!r}")
+        need = index_views(scfg, path, second_order=second_order)
+        if not index.views.holds(need):
+            raise ValueError(
+                f"the walk reads index views {need}, but the index holds "
+                f"{index.views}: build it with build_index(..., views=) or "
+                "fit it with temporal_index.fit_index")
         if lanes is not None:
             _check_lane_support(wcfg, scfg, sched_cfg, lanes,
                                 tables=tables, second_order=second_order)

@@ -54,7 +54,14 @@ import jax.numpy as jnp
 
 from repro.core.alias import AliasTables, TableSpec, build_tables, update_tables
 from repro.core.edge_store import TS_PAD, EdgeBatch, EdgeStore
-from repro.core.temporal_index import TemporalIndex, build_index, empty_index
+from repro.core.temporal_index import (
+    FULL_VIEWS,
+    IndexViews,
+    TemporalIndex,
+    build_index,
+    empty_index,
+    fit_index,
+)
 from repro.obs.tracing import scope
 
 
@@ -72,13 +79,14 @@ class WindowState(NamedTuple):
 
 
 @partial(jax.jit, static_argnames=("edge_capacity", "node_capacity",
-                                   "window", "bias_scale", "table"))
+                                   "window", "bias_scale", "table", "views"))
 def init_window(edge_capacity: int, node_capacity: int, window: int,
                 bias_scale: float = 1.0,
-                table: Optional[TableSpec] = None) -> WindowState:
-    """An empty window (the index of an empty store is written out, so no
-    sort is compiled)."""
-    index = empty_index(edge_capacity, node_capacity)
+                table: Optional[TableSpec] = None,
+                views: IndexViews = FULL_VIEWS) -> WindowState:
+    """An empty window whose index holds ``views`` (the index of an empty
+    store is written out, so no sort is compiled)."""
+    index = empty_index(edge_capacity, node_capacity, views)
     tables = build_tables(index, table) if table is not None else None
     # distinct scalar buffers: donation (ingest donate_argnums) rejects a
     # state whose fields alias one another
@@ -88,6 +96,12 @@ def init_window(edge_capacity: int, node_capacity: int, window: int,
                        window=jnp.asarray(window, jnp.int32),
                        ingested=z(), late_drops=z(), overflow_drops=z(),
                        tables=tables)
+
+
+def fit_window(state: WindowState, views: IndexViews) -> WindowState:
+    """``state`` with its index fitted to ``views`` (``fit_index``): what
+    an engine does to a state handed to it before its programs see it."""
+    return state._replace(index=fit_index(state.index, views))
 
 
 # ---------------------------------------------------------------------------
@@ -177,9 +191,10 @@ def _advance_store(store: EdgeStore, t_prev, window, batch: EdgeBatch,
 
 def _finalize(state: WindowState, new_store: EdgeStore, t_now, late,
               overflow, batch_count, node_capacity: int,
-              bias_scale: float) -> WindowState:
-    """Rebuild the dual index over the advanced store; bump the counters."""
-    index = build_index(new_store, node_capacity, bias_scale)
+              bias_scale: float, views: IndexViews) -> WindowState:
+    """Rebuild the dual index's core and ``views`` over the advanced store;
+    bump the counters."""
+    index = build_index(new_store, node_capacity, bias_scale, views)
     return WindowState(
         index=index, t_now=t_now, window=state.window,
         ingested=state.ingested + batch_count,
@@ -218,7 +233,8 @@ def _dirty_nodes(state: WindowState, batch: EdgeBatch, adv: _Advance,
 
 def ingest_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
                 bias_scale: float = 1.0, watermark=None,
-                table: Optional[TableSpec] = None) -> WindowState:
+                table: Optional[TableSpec] = None,
+                views: IndexViews = FULL_VIEWS) -> WindowState:
     """Window advance + index rebuild (unjitted body; see ``ingest``).
 
     ``watermark`` is the sharded-window eviction hook (see
@@ -230,6 +246,9 @@ def ingest_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
     The spec must be passed on *every* ingest of a table-carrying state —
     omitting it drops the tables from the returned state.
 
+    ``views`` (static ``IndexViews``) names the optional index views the
+    rebuild makes beside the core; the default is all of them.
+
     The store advance and the table update carry the ``advance`` scope,
     the rebuild ``index`` (obs/tracing.py).
     """
@@ -237,7 +256,7 @@ def ingest_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
         adv = _advance_store(state.index.store, state.t_now, state.window,
                              batch, node_capacity, watermark=watermark)
     new = _finalize(state, adv.store, adv.t_now, adv.late, adv.overflow,
-                    batch.count, node_capacity, bias_scale)
+                    batch.count, node_capacity, bias_scale, views)
     if table is None:
         return new
     with scope("advance"):
@@ -252,7 +271,8 @@ def ingest_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
 
 
 def _ingest_sort_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
-                      bias_scale: float = 1.0) -> WindowState:
+                      bias_scale: float = 1.0,
+                      views: IndexViews = FULL_VIEWS) -> WindowState:
     """Seed reference path, written independently of ``_advance_store``:
     sort the batch, drop late edges, shift out the evicted store prefix,
     then concat + global stable argsort + gathers, and clip to capacity."""
@@ -301,7 +321,7 @@ def _ingest_sort_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
         ts=jnp.where(live2, mts[idx2], TS_PAD),
         num_edges=jnp.minimum(total, E).astype(jnp.int32))
     return _finalize(state, new_store, t_now, jnp.sum(blate.astype(jnp.int32)),
-                     overflow, batch.count, node_capacity, bias_scale)
+                     overflow, batch.count, node_capacity, bias_scale, views)
 
 
 # Public entry points. ``ingest`` (merge path) donates the old WindowState so
@@ -309,11 +329,13 @@ def _ingest_sort_impl(state: WindowState, batch: EdgeBatch, node_capacity: int,
 # ``ingest_sort`` is the non-donating seed reference kept for equivalence
 # tests and old-vs-new benchmarking.
 ingest = partial(jax.jit,
-                 static_argnames=("node_capacity", "bias_scale", "table"),
+                 static_argnames=("node_capacity", "bias_scale", "table",
+                                  "views"),
                  donate_argnums=(0,))(ingest_impl)
 ingest_merge = ingest
 ingest_sort = partial(jax.jit,
-                      static_argnames=("node_capacity", "bias_scale"))(
+                      static_argnames=("node_capacity", "bias_scale",
+                                       "views"))(
     _ingest_sort_impl)
 
 # Non-donating merge ingest for the serving snapshot double-buffer
@@ -322,7 +344,8 @@ ingest_sort = partial(jax.jit,
 # concurrently, so the input cannot be donated. Same math as ``ingest``,
 # byte-identical output; costs one fresh store+index allocation per call.
 ingest_nodonate = partial(
-    jax.jit, static_argnames=("node_capacity", "bias_scale", "table"))(
+    jax.jit, static_argnames=("node_capacity", "bias_scale", "table",
+                              "views"))(
     ingest_impl)
 
 

@@ -62,6 +62,7 @@ from repro.core.walk_engine import (
     LaneParams,
     check_capabilities,
     generate_walk_lanes,
+    index_views,
 )
 from repro.core.window import WindowState, init_window
 from repro.serve.coalescer import (
@@ -284,12 +285,17 @@ class WalkService:
                                  "(num_shards > 0 or mesh=)")
             self.batch_capacity = batch_capacity
             self.num_shards = 0
+            # any query may carry second-order lanes, so every snapshot
+            # holds the adjacency view their β probe reads
+            views = index_views(cfg.sampler, self.sched_cfg.path,
+                                second_order=True)
             self.snapshots = SnapshotManager(
                 state if state is not None else init_window(
                     cfg.window.edge_capacity, cfg.window.node_capacity,
-                    int(cfg.window.duration), table=self._table),
+                    int(cfg.window.duration), table=self._table,
+                    views=views),
                 cfg.window.node_capacity, registry=self.registry,
-                table=self._table)
+                table=self._table, views=views)
         # NOT split per call: lane RNG identity lives in (seed, walk, step)
         # folds, and solo/coalesced bit-equality needs a stable base.
         self.base_key = jax.random.PRNGKey(cfg.seed)
@@ -331,7 +337,12 @@ class WalkService:
                                 help="published serving snapshot version")
         if self.sharded:
             self._refresh_exchange_drops()
-        elif self.snapshots.current.tables is not None:
+            return
+        self.registry.set_gauge(
+            "index_edge_columns",
+            self.snapshots.current.index.views.edge_columns,
+            help="edge-sized columns the window's index rebuild writes")
+        if self.snapshots.current.tables is not None:
             # same counter the streaming engine publishes (§17): incremental
             # maintenance work per advance, against a full-rebuild baseline
             rebuilt = int(self.snapshots.current.tables.rebuilt)

@@ -40,10 +40,12 @@ import jax
 
 from repro.configs.base import EngineConfig
 from repro.core.edge_store import EdgeBatch
+from repro.core.temporal_index import FULL_VIEWS, IndexViews
 from repro.core.window import (
     TsView,
     WindowState,
     advance_view,
+    fit_window,
     ingest_nodonate,
     init_view,
 )
@@ -86,14 +88,19 @@ class SnapshotManager:
     table-bias lane batches can draw O(1) against ``current.tables``.
     The spec must be fixed for the life of the manager — incremental
     maintenance is only valid against tables built under the same spec.
+
+    ``views`` (``temporal_index.IndexViews``) are the optional index views
+    every snapshot holds: the initial ``state`` is fitted to them and each
+    ingest rebuilds them beside the core.
     """
 
     def __init__(self, state: WindowState, node_capacity: int,
                  registry: Optional[MetricsRegistry] = None,
-                 table=None):
-        self.current = state
+                 table=None, views: IndexViews = FULL_VIEWS):
+        self.current = fit_window(state, views)
         self.node_capacity = node_capacity
         self.table = table
+        self.views = views
         self.registry = registry if registry is not None else get_registry()
         self.version = 0          # bumped at every publish
         self._next: Optional[WindowState] = None
@@ -108,7 +115,7 @@ class SnapshotManager:
             raise RuntimeError("an ingest is already in flight; publish() "
                                "or discard() it first")
         self._next = ingest_nodonate(self.current, batch, self.node_capacity,
-                                     table=self.table)
+                                     table=self.table, views=self.views)
 
     def publish(self) -> WindowState:
         """Wait for the in-flight ingest and swap it in as ``current``."""

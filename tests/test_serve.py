@@ -463,15 +463,17 @@ def test_shape_buckets():
 
 def test_snapshot_double_buffer_consistency():
     """begin_ingest keeps the current snapshot serveable; publish swaps in
-    a window byte-identical to the donating ingest path."""
+    a window byte-identical to the donating ingest path building the same
+    index views."""
     g = powerlaw_temporal_graph(100, 2000, seed=5)
     batches = list(chronological_batches(g, 4))
     svc = WalkService(_engine_cfg(), _serve_cfg())
-    ref = init_window(4096, NC, 4000)
+    views = svc.snapshots.views
+    ref = init_window(4096, NC, 4000, views=views)
     for bs, bd, bt in batches[:-1]:
         svc.ingest(bs, bd, bt)
         ref = ingest(ref, make_batch(bs, bd, bt, capacity=svc.batch_capacity),
-                     NC)
+                     NC, views=views)
     bs, bd, bt = batches[-1]
     svc.begin_ingest(bs, bd, bt)
     assert svc.snapshots.ingest_in_flight
@@ -485,8 +487,10 @@ def test_snapshot_double_buffer_consistency():
     before = [np.asarray(x) for x in jax.tree.leaves(svc.snapshots.current)]
     svc.publish()
     assert svc.snapshots.version == v0 + 1
-    ref = ingest(ref, make_batch(bs, bd, bt, capacity=svc.batch_capacity), NC)
+    ref = ingest(ref, make_batch(bs, bd, bt, capacity=svc.batch_capacity), NC,
+                 views=views)
     after = jax.tree.leaves(svc.snapshots.current)
+    assert len(after) == len(jax.tree.leaves(ref))
     for got, want in zip(after, jax.tree.leaves(ref)):
         assert np.array_equal(np.asarray(got), np.asarray(want))
     # the published window is a different state than the served snapshot

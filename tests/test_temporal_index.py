@@ -1,5 +1,6 @@
 """Dual-index invariants (paper §2.3): both views index the same edge
 multiset; node regions and temporal cutoffs match a numpy oracle."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,8 +9,14 @@ from hypothesis import strategies as st
 
 from repro.core.edge_store import TS_PAD, store_from_arrays
 from repro.core.temporal_index import (
+    FULL_VIEWS,
+    IndexViews,
+    TemporalIndex,
     adjacency_contains,
+    adjacency_view,
     build_index,
+    empty_index,
+    fit_index,
     node_range,
     ranged_search,
     temporal_cutoff,
@@ -173,3 +180,88 @@ def test_build_index_arbitrary_ts(base_ts, n):
     idx = build_index(store, 8)
     assert int(idx.num_edges) == n
     assert np.all(np.isfinite(np.asarray(idx.pexp)))
+
+
+# ---------------------------------------------------------------------------
+# View sets: the core alone, or with the prefixes, the adjacency view, both
+# ---------------------------------------------------------------------------
+
+VIEW_SETS = {
+    "core": IndexViews(prefixes=False, adjacency=False),
+    "prefixes": IndexViews(prefixes=True, adjacency=False),
+    "adjacency": IndexViews(prefixes=False, adjacency=True),
+    "full": FULL_VIEWS,
+}
+_GROUPS = {"prefixes": ("pexp", "plin", "pexp_store", "plin_store"),
+           "adjacency": ("adj_order", "adj_dst")}
+
+
+def _same_bits(a, b) -> bool:
+    a, b = np.asarray(a), np.asarray(b)
+    return a.dtype == b.dtype and a.shape == b.shape \
+        and a.tobytes() == b.tobytes()
+
+
+def assert_index_matches_full(index, full, views):
+    """Every field ``index`` holds is ``full``'s bit for bit; it holds
+    exactly the core and ``views``."""
+    assert index.views == views
+    for name in TemporalIndex._fields:
+        got, want = getattr(index, name), getattr(full, name)
+        group = next((g for g, fs in _GROUPS.items() if name in fs), None)
+        if group is not None and not getattr(views, group):
+            assert got is None, name
+            continue
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert _same_bits(g, w), name
+    E = index.edge_capacity
+    edge_sized = [f for f in TemporalIndex._fields if f != "store"
+                  and getattr(index, f) is not None
+                  and getattr(index, f).shape[0] >= E]
+    assert len(edge_sized) == views.edge_columns
+
+
+@pytest.fixture(scope="module")
+def small_store(small_graph):
+    g = small_graph
+    return store_from_arrays(g.src, g.dst, g.ts, edge_capacity=4096,
+                             node_capacity=256)
+
+
+@pytest.mark.parametrize("name", sorted(VIEW_SETS))
+def test_view_set_build_matches_full_build(small_store, name):
+    views = VIEW_SETS[name]
+    full = build_index(small_store, 256)
+    assert full.views == FULL_VIEWS and full.views.edge_columns == 10
+    assert_index_matches_full(build_index(small_store, 256, views=views),
+                              full, views)
+
+
+@pytest.mark.parametrize("name", sorted(VIEW_SETS))
+def test_fit_index_narrows_and_completes(small_store, name):
+    """Fitting a full index drops views; fitting a core index builds them,
+    bit for bit as the full build does; fitting to its own views is the
+    identity."""
+    views = VIEW_SETS[name]
+    full = build_index(small_store, 256)
+    core = build_index(small_store, 256, views=VIEW_SETS["core"])
+    assert_index_matches_full(fit_index(full, views), full, views)
+    assert_index_matches_full(fit_index(core, views), full, views)
+    part = build_index(small_store, 256, views=views)
+    assert fit_index(part, views) is part
+
+
+@pytest.mark.parametrize("name", sorted(VIEW_SETS))
+def test_empty_index_view_sets(name):
+    views = VIEW_SETS[name]
+    assert_index_matches_full(empty_index(512, 16, views),
+                              empty_index(512, 16), views)
+
+
+def test_adjacency_view_of_core_index(small_store):
+    full = build_index(small_store, 256)
+    core = build_index(small_store, 256, views=VIEW_SETS["core"])
+    adj_order, adj_dst = adjacency_view(core)
+    assert _same_bits(adj_order, full.adj_order)
+    assert _same_bits(adj_dst, full.adj_dst)
